@@ -27,6 +27,7 @@ def run(quick: bool = False) -> dict:
     out = {}
 
     # edge_motion: 720p-ish segment through the oracle + kernel validation
+    from repro import kernels
     from repro.kernels.edge_motion import ops as em
     frames = jnp.asarray(rng.uniform(0, 1, (5, 192, 320)).astype(np.float32))
     t_ref = _time(lambda f: em.segment_motion(f, use_kernel=False), frames)
@@ -43,7 +44,7 @@ def run(quick: bool = False) -> dict:
     # passes per pallas_call, bit-identical scores.  Interpret-mode only:
     # on compiled backends tile_rows=None resolves back to the same 32-row
     # program and the "comparison" would time one executable twice.
-    if em.INTERPRET:
+    if kernels.pallas_interpret():
         cams = jnp.asarray(rng.uniform(0, 1, (4, 5, 96, 160))
                            .astype(np.float32))
         t_banded = _time(lambda f: em.segment_motion_fleet(f, tile_rows=32),

@@ -57,6 +57,8 @@ def _env_metadata() -> dict:
 
 
 def main() -> None:
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="reduced slot/sample counts")

@@ -5,6 +5,10 @@ Checks (see the package docstring for the full catalog):
 * no-host-callback: no ``*_callback`` / ``debug_*`` / infeed / outfeed /
   host-memory ``device_put`` primitive anywhere in a timed program's
   ClosedJaxpr (recursing into scan/while/cond/pjit sub-jaxprs);
+* no-device-constant: no device array captured as a constant in a timed
+  program — lowering fetches its value to the host, a device-to-host
+  transfer inside the guarded first call (enforced on a TPU, silent on
+  the CPU backend);
 * donation: ``lowered.args_info`` marks exactly the claimed donated
   leaves (slot-step: frames/gt_boxes/gt_valid; everything else: none);
 * two-harvest: every episode jaxpr emits exactly TWO slot-stacked
@@ -79,6 +83,25 @@ def forbidden_primitives(jaxpr) -> List[str]:
                 bad.append(name)
             stack.extend(_sub_jaxprs(eqn.params))
     return bad
+
+
+def device_constants(jaxpr) -> List[str]:
+    """Shape/dtype of every device array captured as a constant by a
+    (Closed)Jaxpr or any of its closed sub-jaxprs."""
+    import jax
+
+    found: List[str] = []
+    stack = [jaxpr]
+    while stack:
+        jx = stack.pop()
+        found.extend(f"{c.dtype}{list(c.shape)}" for c in
+                     getattr(jx, "consts", ()) if isinstance(c, jax.Array))
+        for eqn in getattr(jx, "jaxpr", jx).eqns:
+            for v in eqn.params.values():
+                items = v if isinstance(v, (list, tuple)) else (v,)
+                stack.extend(i for i in items if hasattr(i, "eqns")
+                             or hasattr(i, "consts"))
+    return found
 
 
 def trace_program(prog: Program):
@@ -158,6 +181,13 @@ def audit(programs: Optional[Sequence[Program]] = None,
                     f"in timed scope: {sorted(set(bad))}")
             else:
                 ok(f"no-host-callback[{prog.name}]")
+            consts = device_constants(jx)
+            if consts:
+                failures.append(
+                    f"no-device-constant[{prog.name}]: device arrays "
+                    f"captured as constants: {consts}")
+            else:
+                ok(f"no-device-constant[{prog.name}]")
         got = donated_indices(prog)
         if got != prog.donated:
             failures.append(

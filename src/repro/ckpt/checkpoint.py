@@ -62,21 +62,14 @@ import json
 import os
 import shutil
 import threading
+import zlib
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-import zlib
-
-try:
-    import zstandard as zstd
-    HAVE_ZSTD = True
-except ImportError:          # container without zstandard: fall back to zlib
-    zstd = None
-    HAVE_ZSTD = False
+import zstandard as zstd
 
 COMMIT_MARKER = "COMMITTED"
 
@@ -92,18 +85,13 @@ class CheckpointCorruptError(RuntimeError):
 
 
 def _compress(data: bytes) -> Tuple[bytes, str]:
-    if HAVE_ZSTD:
-        return zstd.ZstdCompressor(level=3).compress(data), "zstd"
-    return zlib.compress(data, 3), "zlib"
+    return zstd.ZstdCompressor(level=3).compress(data), "zstd"
 
 
 def _decompress(blob: bytes, codec: str) -> bytes:
     if codec == "zstd":
-        if not HAVE_ZSTD:
-            raise RuntimeError("checkpoint was written with zstd but "
-                               "zstandard is not installed")
         return zstd.ZstdDecompressor().decompress(blob)
-    if codec == "zlib":
+    if codec == "zlib":     # the committed detector checkpoints are zlib
         return zlib.decompress(blob)
     raise ValueError(f"unknown checkpoint codec {codec!r}")
 

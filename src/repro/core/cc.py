@@ -18,8 +18,13 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-INF = jnp.int32(2 ** 30)
+# a host scalar, not a device array: traced code embeds it as a literal,
+# where a captured device array would be fetched to the host when the
+# program is lowered — a device-to-host transfer the episode's transfer
+# guard rejects on a TPU
+INF = np.int32(2 ** 30)
 
 
 @functools.partial(jax.jit, static_argnames=("max_boxes", "bounded"))

@@ -46,9 +46,22 @@ def effective_pixels(cfg: CodecConfig, roi_pixels: float, num_frames: int,
 
 
 def _avg_pool(frames: jax.Array, k: int) -> jax.Array:
+    """(k x k) average pool over H/W, cropping the non-divisible tail.
+
+    The window sum has a FIXED association — rows i = 0..k-1 left to right,
+    then columns j = 0..k-1 left to right — so every implementation that
+    adds the same selected elements in this order (the ``tx_codec`` kernel
+    selects them with exact 0/1 matmuls) is bit-identical to this one on
+    any backend; a reshape-reduce leaves the order to the compiler."""
     N, H, W = frames.shape
-    x = frames[:, :H // k * k, :W // k * k].reshape(N, H // k, k, W // k, k)
-    return x.mean(axis=(2, 4))
+    x = frames[:, :H // k * k, :W // k * k]
+    rows = x[:, 0::k]
+    for i in range(1, k):
+        rows = rows + x[:, i::k]
+    out = rows[:, :, 0::k]
+    for j in range(1, k):
+        out = out + rows[:, :, j::k]
+    return out / (k * k)
 
 
 def _resolution_blur(frames: jax.Array, res: float) -> jax.Array:

@@ -132,7 +132,7 @@ Mesh & donation
 ---------------
 The camera axis is the leading axis of every per-camera operand, and the
 executable is built per (mesh, codec-config, statics) via
-``shard_map_compat`` on a 1-D ("camera",) mesh (``sharding.rules.camera_mesh``):
+``jax.shard_map`` on a 1-D ("camera",) mesh (``sharding.rules.camera_mesh``):
 each device runs the identical per-camera program on its C/D-camera shard, so
 results are bit-stable vs the single-device path and multi-host scaling is a
 mesh-shape change.  C is padded up to a multiple of the device count

@@ -11,7 +11,15 @@ row bands of shape (TH+2, W+2) — the +2 halo makes every tile's Sobel stencil
 self-contained, so kernel output is bit-identical to the global oracle.
 Grid = (num_pairs, num_row_tiles); each program consumes one band of one
 frame pair.  VMEM per program: 2 x (TH+2) x (W+2) x 4B  (~0.5 MB for TH=32,
-W=1920) — well inside the ~16 MB budget, MXU-free (pure VPU stencil work).
+W=1920) — well inside the ~16 MB budget.
+
+The block sum runs rows then columns: the row sum splits only the sublane
+axis ((TH, W) -> (TH/bs, bs, W), tile-aligned for bs a multiple of 8) and
+the column sum is a matmul with a 0/1 pooling matrix ``(TH/bs, W) @ Pw (W,
+W/bs)`` — Mosaic cannot lower the (TH, W) -> (TH/bs, bs, W/bs, bs) reshape
+that splits the lane axis.  ``d`` is 0/1 and every block sum is an integer
+<= bs*bs, so both are exact in any MXU pass precision and accumulation
+order.
 """
 from __future__ import annotations
 
@@ -20,6 +28,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import f32_dot
+
+
+def pool_matrix(n_out: int, n_in: int, k: int) -> jax.Array:
+    """(n_out, n_in) 0/1 matrix summing each run of ``k`` consecutive input
+    rows: P[a, r] = 1 iff a*k <= r < a*k + k.  Built from 2-D iotas so it
+    lowers inside a Mosaic kernel."""
+    a = jax.lax.broadcasted_iota(jnp.int32, (n_out, n_in), 0) * k
+    r = jax.lax.broadcasted_iota(jnp.int32, (n_out, n_in), 1)
+    return ((r >= a) & (r < a + k)).astype(jnp.float32)
 
 
 def _edge_motion_kernel(f0_ref, f1_ref, out_ref, *, block_size: int,
@@ -41,13 +60,13 @@ def _edge_motion_kernel(f0_ref, f1_ref, out_ref, *, block_size: int,
     d = jnp.logical_xor(e0, e1).astype(jnp.float32)   # (TH, W)
     th, w = d.shape
     bs = block_size
-    scores = d.reshape(th // bs, bs, w // bs, bs).sum(axis=(1, 3))
-    out_ref[0, 0] = scores
+    rows = d.reshape(th // bs, bs, w).sum(axis=1)       # (TH/bs, W)
+    out_ref[0, 0] = f32_dot(rows, pool_matrix(w // bs, w, bs).T)
 
 
 def edge_motion_pallas(f0_tiles: jax.Array, f1_tiles: jax.Array, *,
                        block_size: int, edge_thresh: float,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool) -> jax.Array:
     """f*_tiles: (P, T, TH+2, W+2) pre-haloed row bands for P frame pairs.
     Returns (P, T, TH/bs, W/bs) block scores."""
     P, T, THp2, Wp2 = f0_tiles.shape

@@ -8,23 +8,22 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.kernels import pallas_interpret_default
+from repro import kernels
 from repro.kernels.edge_motion import ref
 from repro.kernels.edge_motion.edge_motion import edge_motion_pallas
 from repro.sharding.rules import cached_sharded_jit, pad_cameras, pad_leading
 
-INTERPRET = pallas_interpret_default()
-
 
 def _resolve_tile_rows(tile_rows: Optional[int], H: int) -> int:
     """Default row-band height: 32 compiled (VMEM-bounded), FULL frame in
-    interpret mode.  Interpret-mode pallas unrolls one kernel body per grid
-    program at trace time, so a (P, T) grid costs P*T interpreter passes —
+    interpret mode (``kernels.pallas_interpret``, read at trace time).
+    Interpret-mode pallas unrolls one kernel body per grid program at
+    trace time, so a (P, T) grid costs P*T interpreter passes —
     collapsing the tile axis (T=1) cuts them H/32-fold per frame pair with
     bit-identical output (tiling is halo-exact by construction), which is
     what bounds the fleet motion path on one device."""
     if tile_rows is None:
-        tile_rows = H if INTERPRET else 32
+        tile_rows = H if kernels.pallas_interpret() else 32
     return min(tile_rows, H)
 
 
@@ -55,7 +54,8 @@ def segment_motion(frames: jax.Array, *, block_size: int = 8,
                                       edge_thresh=edge_thresh)
     tiles = _make_tiles(frames, tile_rows)                       # (N,T,TH+2,W+2)
     out = edge_motion_pallas(tiles[:-1], tiles[1:], block_size=block_size,
-                             edge_thresh=edge_thresh, interpret=INTERPRET)
+                             edge_thresh=edge_thresh,
+                             interpret=kernels.pallas_interpret())
     P, T, th_b, w_b = out.shape
     return out.reshape(P, T * th_b, w_b)
 
@@ -74,7 +74,7 @@ def _segment_motion_fleet_impl(frames: jax.Array, *, block_size: int,
     out = edge_motion_pallas(tiles[:, :-1].reshape(pair_shape),
                              tiles[:, 1:].reshape(pair_shape),
                              block_size=block_size, edge_thresh=edge_thresh,
-                             interpret=INTERPRET)
+                             interpret=kernels.pallas_interpret())
     n_pairs, T, th_b, w_b = out.shape
     return out.reshape(C, N - 1, T * th_b, w_b)
 

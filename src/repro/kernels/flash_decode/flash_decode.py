@@ -65,7 +65,7 @@ def _fd_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, mo_ref, lo_ref,
 
 def flash_decode_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         kv_valid_len, block_s: int = 512,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: bool) -> jax.Array:
     """q (B,1,H,hd); k,v (B,S,KV,hd) -> (B,1,H,hd)."""
     B, _, H, hd = q.shape
     S, KV = k.shape[1], k.shape[2]

@@ -7,11 +7,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import pallas_interpret_default
+from repro import kernels
 from repro.kernels.flash_decode import ref
 from repro.kernels.flash_decode.flash_decode import flash_decode_pallas
-
-INTERPRET = pallas_interpret_default()
 
 
 def _kernel_ok(q, k, block_s):
@@ -29,9 +27,10 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """Dispatch: kernel when the GQA group is MXU-worthy and S blocks evenly;
     oracle otherwise (small G is VPU-bound — see kernel docstring)."""
     if force_kernel or _kernel_ok(q, k, block_s):
-        out, _, _ = flash_decode_pallas(q, k, v, kv_valid_len=kv_valid_len,
-                                        block_s=min(block_s, k.shape[1]),
-                                        interpret=INTERPRET)
+        out, _, _ = flash_decode_pallas(
+            q, k, v, kv_valid_len=kv_valid_len,
+            block_s=min(block_s, k.shape[1]),
+            interpret=kernels.pallas_interpret())
         return out
     return ref.flash_decode_ref(q, k, v, kv_valid_len=kv_valid_len)
 
@@ -54,7 +53,7 @@ def flash_decode_with_new(q: jax.Array, k: jax.Array, v: jax.Array,
                                          kv_valid_len=kv_valid_len)
     out_old, m_old, l_old = flash_decode_pallas(
         q, k, v, kv_valid_len=kv_valid_len, block_s=min(block_s, k.shape[1]),
-        interpret=INTERPRET)
+        interpret=kernels.pallas_interpret())
     qg = q.reshape(B, KV, G, hd).astype(jnp.float32)
     scale = 1.0 / np.sqrt(hd)
     s_new = jnp.einsum("bkgd,bkd->bkg", qg,

@@ -8,11 +8,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import pallas_interpret_default
+from repro import kernels
 from repro.kernels.knapsack_dp import ref
 from repro.kernels.knapsack_dp.knapsack_dp import knapsack_dp_pallas
-
-INTERPRET = pallas_interpret_default()
 
 
 def bucket_capacity(Wg: int) -> int:
@@ -27,7 +25,8 @@ def bucket_capacity(Wg: int) -> int:
 def solve_values(util: jax.Array, costs: jax.Array, W: int,
                  use_kernel: bool = True) -> Tuple[jax.Array, jax.Array]:
     if use_kernel:
-        return knapsack_dp_pallas(util, costs, W, interpret=INTERPRET)
+        return knapsack_dp_pallas(util, costs, W,
+                                  interpret=kernels.pallas_interpret())
     return ref.knapsack_dp_ref(util, costs, W)
 
 

@@ -20,11 +20,9 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import pallas_interpret_default
+from repro import kernels
 from repro.kernels.tx_codec import ref
 from repro.kernels.tx_codec.tx_codec import tx_codec_pallas
-
-INTERPRET = pallas_interpret_default()
 
 
 def _noise(keys: jax.Array, shape) -> jax.Array:
@@ -63,7 +61,8 @@ def encode_fleet(cfg, frames: jax.Array, roi_pixels: jax.Array,
     sigma = cfg.sigma0 * jnp.exp(-bpp / cfg.beta)
     dec = tx_codec_pallas(frames, _noise(keys, frames.shape[1:]), levels,
                           sigma, _nearest_resolution(cfg.resolutions, res),
-                          resolutions=cfg.resolutions, interpret=INTERPRET)
+                          resolutions=cfg.resolutions,
+                          interpret=kernels.pallas_interpret())
     return dec, bits / 8.0
 
 
@@ -95,5 +94,5 @@ def encode_fleet_crf(cfg, frames: jax.Array, roi_pixels: jax.Array,
     resolutions = cfg.resolutions if blur else (1.0,)
     dec = tx_codec_pallas(frames, _noise(keys, frames.shape[1:]), levels,
                           sigma, ridx, resolutions=resolutions,
-                          interpret=INTERPRET)
+                          interpret=kernels.pallas_interpret())
     return dec, pix * bpp / 8.0

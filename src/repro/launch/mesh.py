@@ -6,30 +6,17 @@ touches jax device state — the dry-run sets XLA_FLAGS before first jax init.
 from __future__ import annotations
 
 import jax
-
-
-def mesh_with_auto_axes(devices, axes) -> jax.sharding.Mesh:
-    """Mesh with all-Auto axis types across jax versions: newer jax takes a
-    tuple ``axis_types``; older jax (no ``jax.sharding.AxisType``) defaults
-    every axis to Auto, so omitting the argument is equivalent."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.sharding.Mesh(
-            devices, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.sharding.Mesh(devices, axes)
+import numpy as np
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh() -> jax.sharding.Mesh:
     """Single-device mesh for CPU smoke/bench runs."""
     dev = jax.devices()[:1]
-    return mesh_with_auto_axes(
-        __import__("numpy").asarray(dev).reshape(1, 1), ("data", "model"))
+    return jax.sharding.Mesh(np.asarray(dev).reshape(1, 1), ("data", "model"))

@@ -22,26 +22,36 @@ expects one ``"<t> <kbps> <live-bits>"`` record per slot.
 from __future__ import annotations
 
 import argparse
+from typing import Optional
 
 import jax
 import numpy as np
 
 
-def run_fleet_stream(args) -> None:
-    """Windowed fleet serving over a soak stream: build the episode-mode
-    system (harness-default control artifacts), offer the diurnal stream
-    window by window, checkpoint at boundaries, print the SLO stats."""
+def build_fleet_runner(method: str, *, window_slots: int = 8,
+                       ckpt_dir: Optional[str] = None,
+                       ckpt_keep: Optional[int] = None, shard: str = "auto",
+                       use_kernels: bool = True,
+                       episode_pipelined: bool = True):
+    """The served fleet: the episode-mode system over ``SceneConfig``
+    defaults (5 cameras, 96x160, 10 fps) with the committed light/server
+    detectors (trained only when no checkpoint is committed) and
+    harness-default control artifacts, wrapped in a windowed
+    ``StreamingFleetRunner``.  ``shard="off"`` keeps the fleet on
+    ``jax.devices()[0]``; ``"auto"`` shards cameras over every visible
+    device.  ``use_kernels=False, episode_pipelined=False`` builds the
+    kernel-free reference body the served one is compared against."""
     from repro.core import utility as util_mod
     from repro.core.scheduler import DeepStreamSystem, SystemConfig
-    from repro.data.scenarios import make_soak_stream
     from repro.data.synthetic import DeviceScene, SceneConfig
-    from repro.serve import ingest as ingest_mod
     from repro.serve.stream import StreamConfig, StreamingFleetRunner
     from repro.train.detector_train import train_detector
 
     scene_cfg = SceneConfig(seed=33)
     sys_cfg = SystemConfig(scene=scene_cfg, episode=True, eval_frames=3,
-                           w_cap_kbps=8000.0)
+                           w_cap_kbps=8000.0, shard=shard,
+                           use_kernels=use_kernels,
+                           episode_pipelined=episode_pipelined)
     system = DeepStreamSystem(
         sys_cfg, train_detector("light", steps=300, batch=12, cache=True),
         train_detector("server", steps=600, batch=12, cache=True))
@@ -49,13 +59,39 @@ def run_fleet_stream(args) -> None:
     system.tau_wl, system.tau_wh = 10.0, 50.0
     system.jcab_table = np.linspace(0.2, 0.8, 18).reshape(6, 3).astype(
         np.float32)
-    trace, live = make_soak_stream(args.stream_slots,
-                                   num_cams=scene_cfg.num_cameras)
-    runner = StreamingFleetRunner(
-        system, DeviceScene(scene_cfg), method=args.method,
-        cfg=StreamConfig(window_slots=args.window_slots,
-                         ckpt_dir=args.ckpt_dir, ckpt_keep=args.ckpt_keep,
-                         install_signal=args.ckpt_dir is not None))
+    return StreamingFleetRunner(
+        system, DeviceScene(scene_cfg), method=method,
+        cfg=StreamConfig(window_slots=window_slots, ckpt_dir=ckpt_dir,
+                         ckpt_keep=ckpt_keep,
+                         install_signal=ckpt_dir is not None))
+
+
+def serve_soak_stream(runner, stream_slots: int) -> None:
+    """Offer the seeded diurnal + camera-churn soak stream
+    (``make_soak_stream``) from the runner's cursor, window by window,
+    serving every full window and flushing the tail."""
+    from repro.data.scenarios import make_soak_stream
+
+    trace, live = make_soak_stream(
+        stream_slots, num_cams=runner.system.cfg.scene.num_cameras)
+    step = runner.cfg.window_slots
+    t = runner.t_next
+    while t < len(trace):
+        t += runner.offer(trace[t:t + step], faults=live[t:t + step])
+        runner.serve()
+    runner.serve(flush=True)
+
+
+def run_fleet_stream(args) -> None:
+    """Windowed fleet serving: restore the latest checkpoint if any, feed
+    the soak stream (or a hardened ``--source``), print the SLO stats."""
+    from repro.serve import ingest as ingest_mod
+
+    runner = build_fleet_runner(args.method, window_slots=args.window_slots,
+                                ckpt_dir=args.ckpt_dir,
+                                ckpt_keep=args.ckpt_keep)
+    print(f"# fleet on {jax.device_count()} {jax.default_backend()} "
+          f"device(s), camera mesh: {runner.system.mesh is not None}")
     with runner:
         if runner.restore():
             print(f"# restored window={runner.window} t_next={runner.t_next}")
@@ -70,17 +106,14 @@ def run_fleet_stream(args) -> None:
             ing = ingest_mod.StreamIngestor(runner, src)
             ing.pump(until_t=args.stream_slots, flush=True)
         else:
-            t = runner.t_next
-            while t < len(trace):
-                t += runner.offer(trace[t:t + args.window_slots],
-                                  faults=live[t:t + args.window_slots])
-                runner.serve()
-            runner.serve(flush=True)
+            serve_soak_stream(runner, args.stream_slots)
         print({k: round(v, 4) if isinstance(v, float) else v
                for k, v in runner.stats().items()})
 
 
 def main() -> None:
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--smoke", action="store_true")

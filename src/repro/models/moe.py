@@ -154,11 +154,11 @@ def apply_moe(cfg: ModelConfig, params, x: jax.Array, *,
             drops = jax.lax.psum(drops, "model")
             return out.reshape(bl, sl, d), aux, drops
 
-        from repro.sharding.rules import shard_map_compat
-        out, aux, drops = shard_map_compat(
+        out, aux, drops = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(batch_spec, None, None), pspec),
             out_specs=(P(batch_spec, None, None), P(), P()),
+            check_vma=False,
         )(x, wp)
         y = out.astype(dt)
 

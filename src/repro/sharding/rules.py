@@ -24,17 +24,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.common.params import ParamDef, is_def
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, check: bool = False):
-    """jax.shard_map across jax versions: new API (jax>=0.6, ``check_vma``)
-    vs jax.experimental.shard_map (``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check)
-
-
 def camera_mesh(min_devices: int = 2) -> Optional[Mesh]:
     """1-D ("camera",) mesh over every local device, or None below
     ``min_devices`` (single-device runs skip shard_map entirely).
@@ -71,7 +60,8 @@ def sharded_jit(impl, mesh: Optional[Mesh], in_specs, out_specs,
     fleet motion) goes through: shard_map over the camera mesh when one is
     given, then jit with optional buffer donation."""
     if mesh is not None:
-        impl = shard_map_compat(impl, mesh, in_specs, out_specs, check)
+        impl = jax.shard_map(impl, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=check)
     return jax.jit(impl, donate_argnums=donate_argnums)
 
 

@@ -50,11 +50,11 @@ def compressed_psum(grads: Any, residuals: Any, mesh: Mesh, axis: str = "data"
             mean = total.astype(jnp.float32) * smax / n
             return mean, err
 
-        from repro.sharding.rules import shard_map_compat
-        return shard_map_compat(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(*([None] * g.ndim)), P(*([None] * g.ndim))),
             out_specs=(P(*([None] * g.ndim)), P(*([None] * g.ndim))),
+            check_vma=False,
         )(g, r)
 
     flat_g, tdef = jax.tree.flatten(grads)

@@ -242,6 +242,24 @@ def test_jaxpr_audit_all_invariants_hold():
     assert failures == [], "\n".join(failures)
 
 
+def test_captured_device_constant_is_flagged():
+    """A device array closed over by traced code is a constant the first
+    (guarded) call would fetch to the host while lowering; a host scalar
+    is a literal and is not flagged."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.analysis.jaxpr_audit import device_constants
+
+    dev = jnp.int32(2 ** 30)
+    inner = jax.jit(lambda x: jnp.minimum(x, dev))
+    bad = jax.make_jaxpr(lambda x: inner(x) + 1)(jnp.int32(0))
+    assert device_constants(bad) == ["int32[]"]
+    host = np.int32(2 ** 30)
+    good = jax.make_jaxpr(lambda x: jnp.minimum(x, host))(jnp.int32(0))
+    assert device_constants(good) == []
+
+
 # -- executable manifest golden regression ------------------------------------
 
 GOLDEN = ROOT / "tests" / "golden" / "executable_manifest.json"

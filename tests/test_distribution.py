@@ -69,9 +69,8 @@ def test_sharding_rules_divisibility_guard():
 def test_fit_batch_axes_prefix():
     from repro.sharding import rules as R
     devs = jax.devices()
-    from repro.launch.mesh import mesh_with_auto_axes
-    mesh = mesh_with_auto_axes(np.array(devs[:1]).reshape(1, 1),
-                               ("data", "model"))
+    mesh = jax.sharding.Mesh(np.array(devs[:1]).reshape(1, 1),
+                             ("data", "model"))
     assert R.fit_batch_axes(mesh, 8) == ("data",)
     assert R.fit_batch_axes(mesh, 7) == ("data",)  # 1 divides everything
 
@@ -105,9 +104,8 @@ def test_ckpt_elastic_restore_different_mesh(tmp_path):
     from jax.sharding import NamedSharding, PartitionSpec as P
     tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
     ckpt.save(tree, tmp_path / "c", step=1)
-    from repro.launch.mesh import mesh_with_auto_axes
-    mesh = mesh_with_auto_axes(np.array(jax.devices()[:1]).reshape(1, 1),
-                               ("data", "model"))
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
+                             ("data", "model"))
     sh = {"w": NamedSharding(mesh, P("data", "model"))}
     restored, _ = ckpt.restore(tmp_path / "c", tree, shardings=sh)
     assert restored["w"].sharding == sh["w"]
@@ -129,9 +127,8 @@ def test_ckpt_async_save(tmp_path):
 
 def test_compressed_psum_error_feedback():
     from repro.train.compression import compressed_psum, init_residuals
-    from repro.launch.mesh import mesh_with_auto_axes
-    mesh = mesh_with_auto_axes(np.array(jax.devices()[:1]).reshape(1,),
-                               ("data",))
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]).reshape(1,),
+                             ("data",))
     g = {"w": jnp.asarray(np.random.default_rng(0).normal(0, 1, (64,)), jnp.float32)}
     r = init_residuals(g)
     # single device: mean == value up to int8 quantization; residual carries

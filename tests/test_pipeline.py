@@ -130,19 +130,48 @@ def test_masked_tail_slots_cost_zero_flops():
         assert len(set(per_bucket.values())) == 1, (method, per_bucket)
 
 
+def _server_detector_rows(method: str, bucket: int = 8) -> int:
+    """Largest batch the server detector's first conv sees anywhere in the
+    method's episode program (sub-jaxprs included) — identified by the
+    server variant's first-layer kernel shape."""
+    from repro.analysis import jaxpr_audit, programs
+    from repro.models.detector import detector_defs
+
+    c1 = tuple(detector_defs("server")["c1"].shape)
+    prog, = (p for p in programs.get_programs(kinds=["episode"])
+             if p.name == f"episode/{method}/b{bucket}")
+    rows, stack = [], [jaxpr_audit.trace_program(prog).jaxpr]
+    while stack:
+        jx = stack.pop()
+        for eqn in jx.eqns:
+            if (eqn.primitive.name == "conv_general_dilated"
+                    and tuple(eqn.invars[1].aval.shape) == c1):
+                rows.append(eqn.invars[0].aval.shape[0])
+            stack.extend(jaxpr_audit._sub_jaxprs(eqn.params))
+    return max(rows)
+
+
 def test_dropped_reuse_arm_costs_zero_flops():
     """Only reducto consumes the keep-mask reuse arm, so PR 10 drops that
     arm STATICALLY (``with_reuse = method == "reducto"``) instead of
     masking its outputs — the C extra detector rows must be absent from
-    the compiled program, i.e. every non-reducto method's episode flops
-    sit strictly below reducto's at the same bucket.  (``lax.cond``
-    branches are costed statically, so an output-masked arm would still
-    show up here — this asserts the compute is GONE, not hidden.)"""
+    the compiled program.  Structurally: every other method's server
+    detector batch is strictly smaller than reducto's.  In static flops:
+    jcab and static, which share reducto's full-frame front end, sit
+    strictly below it at every bucket.  (``lax.cond`` branches are costed
+    statically, so an output-masked arm would still show up here — this
+    asserts the compute is GONE, not hidden.)  Deepstream's flops are not
+    compared: its ROIDet front end alone out-costs the reuse arm in XLA's
+    count."""
+    rows = {m: _server_detector_rows(m) for m in METHODS}
     flops = _episode_flops()
-    for bucket in fleet_mod.EPISODE_BUCKETS:
-        for method in METHODS:
-            if method == "reducto":
-                continue
+    for method in METHODS:
+        if method == "reducto":
+            continue
+        assert rows[method] < rows["reducto"], (method, rows)
+        if method == "deepstream":
+            continue
+        for bucket in fleet_mod.EPISODE_BUCKETS:
             assert flops[(method, bucket)] < flops[("reducto", bucket)], \
                 (method, bucket, flops[(method, bucket)],
                  flops[("reducto", bucket)])

@@ -1,6 +1,8 @@
 """End-to-end behaviour tests for the paper's system: the DeepStream control
 loop against baselines at miniature scale, the serve engine, the data
 pipeline, and detector F1 plumbing."""
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -139,3 +141,25 @@ def test_f1_score_properties():
     assert f1_score(perfect, np.array([False, False]), gt) == 0.0
     assert f1_score(perfect, np.array([True, True]), []) == 0.0
     assert f1_score(np.zeros((0, 4)), np.zeros((0,), bool), []) == 1.0
+
+
+@pytest.mark.parametrize("placed", [False, True])
+def test_compile_cache_placement(monkeypatch, tmp_path, placed):
+    """The entry points' persistent compile cache: a deployment's
+    JAX_COMPILATION_CACHE_DIR is used as JAX read it; otherwise one fixed
+    directory inside the checkout."""
+    from repro.launch import cache
+
+    was = jax.config.jax_compilation_cache_dir
+    if placed:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = cache.enable_compile_cache()
+        want = str(tmp_path) if placed else str(cache.REPO_CACHE_DIR)
+        assert got == want == jax.config.jax_compilation_cache_dir
+        assert cache.REPO_CACHE_DIR.parent == Path(__file__).parents[1]
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
