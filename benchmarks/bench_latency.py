@@ -58,13 +58,6 @@ MODES = {
                    alloc="device"),
 }
 
-# per-mode host-side control-loop timers: "alloc" is the numpy utility+DP
-# time, "ctrl" the device control-step dispatch, "gather" the shard-boundary
-# (a, c) gather — on CPU it absorbs the wait for the in-flight ROIDet, the
-# same wait the host modes pay inside their untimed (a, c) fetch
-_CTRL_TIMERS = ("alloc", "ctrl", "gather")
-
-
 def _episode_compare(base, num_cameras: int, n_slots: int,
                      reps: int = 3) -> dict:
     """Whole-trace episode vs the pipelined device-alloc loop on IDENTICAL
@@ -400,7 +393,7 @@ def _compare_modes(base, num_cameras: int = 8, n_slots: int = 6,
     from repro.core import scheduler as sched_mod
     from repro.core.scheduler import DeepStreamSystem, SystemConfig
 
-    results, compiles, ctrl_ms, ctrl_fetches = {}, {}, {}, {}
+    results, compiles, ctrl_fetches = {}, {}, {}
     for name, kw in MODES.items():
         cfg = SystemConfig(scene=SceneConfig(seed=31, num_cameras=num_cameras),
                            eval_frames=base.cfg.eval_frames, **kw)
@@ -414,7 +407,6 @@ def _compare_modes(base, num_cameras: int = 8, n_slots: int = 6,
                  method="deepstream")
         n0 = fleet_mod.compile_count()
         f0 = sched_mod.d2h_fetch_counts().get("control", 0)
-        sysd.timers = {}
         scene = MultiCameraScene(SceneConfig(seed=13, num_cameras=num_cameras))
         trace = bandwidth_trace("medium", n_slots, seed=5)
         t0 = time.perf_counter()
@@ -423,9 +415,6 @@ def _compare_modes(base, num_cameras: int = 8, n_slots: int = 6,
         results[name] = (dt, logs)
         compiles[name] = fleet_mod.compile_count() - n0
         ctrl_fetches[name] = sched_mod.d2h_fetch_counts().get("control", 0) - f0
-        ctrl_ms[name] = {
-            k: float(np.mean(sysd.timers[k]) * 1e3)
-            for k in _CTRL_TIMERS if k in sysd.timers}
 
     t_seq, logs_seq = results["sequential"]
     t_bat, logs_bat = results["batched"]
@@ -452,10 +441,6 @@ def _compare_modes(base, num_cameras: int = 8, n_slots: int = 6,
         "max_utility_diff_sharded": udiff["sharded"],
         "max_utility_diff_device": udiff["device"],
         "fleet_compiles_during_run": compiles,
-        # host ms/slot spent in the control loop per mode: "alloc" = numpy
-        # elastic+table+DP (host placement), "ctrl" = traced-program dispatch
-        # (device placement) — the delta is the eliminated allocator host time
-        "control_host_ms_per_slot": ctrl_ms,
         # per-slot (a, c) D2H syncs during the timed run (0 proves the
         # device-resident loop never touches the host for allocation)
         "control_d2h_fetches_during_run": ctrl_fetches,
@@ -475,7 +460,6 @@ def _print_cmp(cmp: dict) -> None:
     print(f"  device     {cmp['device_ms_per_slot']:9.1f} ms/slot   "
           f"({cmp['speedup_device_vs_sharded']:.2f}x vs sharded, "
           f"udiff {cmp['max_utility_diff_device']:.1e})")
-    print(f"  control-loop host ms/slot: {cmp['control_host_ms_per_slot']}")
     print(f"  control D2H fetches during timed runs: "
           f"{cmp['control_d2h_fetches_during_run']}")
     print(f"  fleet compiles during timed runs: "
@@ -484,22 +468,14 @@ def _print_cmp(cmp: dict) -> None:
 
 def run(quick: bool = False) -> dict:
     sysd = profiled_system(quick)
-    sysd.timers = {}
     scene = MultiCameraScene(SceneConfig(seed=31))
     trace = bandwidth_trace("medium", 3 if quick else 8, seed=5)
     logs = sysd.run(scene, trace, method="deepstream")
 
     # transmission time = bytes / allocated bandwidth (the simulator's model)
     trans = logs["bytes"] / (logs["W"] * 1000 / 8)
-    stages = {}
-    for k, v in sysd.timers.items():
-        stages[k] = float(np.mean(v) * 1e3)
-    stages["transmission"] = float(np.mean(trans) * 1e3)
-
-    print("\n[Fig.6] per-stage latency (ms, host-relative; fleet/roidet/ctrl "
-          "are dispatch times in pipelined mode):")
-    for k, v in sorted(stages.items(), key=lambda kv: -kv[1]):
-        print(f"  {k:12s} {v:9.2f}")
+    stages = {"transmission": float(np.mean(trans) * 1e3)}
+    print(f"\n[Fig.6] transmission latency: {stages['transmission']:.2f} ms")
 
     cmp8 = _compare_modes(sysd, num_cameras=8, n_slots=4 if quick else 8)
     _print_cmp(cmp8)

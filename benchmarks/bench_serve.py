@@ -82,7 +82,7 @@ def run(quick: bool = False) -> dict:
 
     # SLO stats over the post-warmup windows only (the warmup window's
     # turnaround is compile time, not serving time)
-    walls = np.asarray(runner.window_walls[warmup_windows:], float)
+    walls = np.asarray(list(runner.window_walls)[warmup_windows:], float)
     served = len(runner.logs["W"]) - warmup_windows * WINDOW_SLOTS
     p50 = float(np.percentile(walls, 50)) if walls.size else 0.0
     p99 = float(np.percentile(walls, 99)) if walls.size else 0.0

@@ -142,7 +142,7 @@ def one_chip(clock) -> None:
         snap = clock.snapshot()
         runner, logs, probes = serve(method, shard="off", ckpt=True)
         spent = clock.since(snap)
-        walls = runner.window_walls
+        walls = list(runner.window_walls)
         print(f"# {method}: compile s {sum(spent.values())!r} {spent}")
         print(f"# {method}: first window s {walls[0]!r}, warm window s "
               f"(median of {len(walls) - 1}) {float(np.median(walls[1:]))!r}")

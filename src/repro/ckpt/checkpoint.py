@@ -55,6 +55,12 @@ protocol itself (that window is already covered by the crash-atomicity
 tests).  All of it is RECOVERABLE: the corruption battery in
 ``tests/test_ckpt.py`` asserts each fault fails verification with the
 leaf/field named and falls back a generation.
+
+**Accounting.**  ``AsyncSaver.save`` opens host spans (``common.obs``):
+``ckpt.wait`` (joining the previous writer), ``ckpt.fetch`` (the leaves to
+the host, each counted as a ``checkpoint`` D2H fetch) and, on the writer
+thread, ``ckpt.write``.  ``write_counts()`` gives the generations this
+process committed and the bytes their files hold.
 """
 from __future__ import annotations
 
@@ -70,6 +76,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import zstandard as zstd
+
+from repro.common import obs
 
 COMMIT_MARKER = "COMMITTED"
 
@@ -105,8 +113,20 @@ def _leaf_to_host(x) -> np.ndarray:
     if isinstance(x, jax.Array):
         if len(x.addressable_shards) < len(x.sharding.device_set):
             raise ValueError("multi-host leaf not fully addressable; shard-save path required")
+        obs.count_fetch("checkpoint")
         return np.asarray(x)
     return np.asarray(x)
+
+
+_writes = {"generations": 0, "bytes": 0}
+_writes_lock = threading.Lock()
+
+
+def write_counts() -> Dict[str, int]:
+    """Checkpoints committed by this process so far, and the bytes their
+    files hold (data, manifest and commit marker)."""
+    with _writes_lock:
+        return dict(_writes)
 
 
 class AsyncSaver:
@@ -132,23 +152,26 @@ class AsyncSaver:
 
     def save(self, tree: Any, path: str | Path, *, step: int = 0,
              metadata: Optional[Dict] = None, blocking: bool = False) -> None:
-        self.wait()  # only one outstanding save
-        host_leaves, treedef = _flatten(tree)
-        host_leaves = [(k, _leaf_to_host(v)) for k, v in host_leaves]
-        treedef_str = str(treedef)
+        with obs.span("ckpt.wait"):
+            self.wait()  # only one outstanding save
+        with obs.span("ckpt.fetch"):
+            host_leaves, treedef = _flatten(tree)
+            host_leaves = [(k, _leaf_to_host(v)) for k, v in host_leaves]
+            treedef_str = str(treedef)
 
         def _write():
             try:
-                if self.chaos is not None:
-                    self.chaos.on_save_start(step)
-                _write_checkpoint(host_leaves, treedef_str, Path(path),
-                                  step=step, metadata=metadata or {})
-                if self.chaos is not None:
-                    self.chaos.on_save_committed(Path(path), step)
-                if self.keep is not None:
-                    self.gc_removed.extend(
-                        str(p) for p in gc_generations(Path(path).parent,
-                                                       self.keep))
+                with obs.span("ckpt.write"):
+                    if self.chaos is not None:
+                        self.chaos.on_save_start(step)
+                    _write_checkpoint(host_leaves, treedef_str, Path(path),
+                                      step=step, metadata=metadata or {})
+                    if self.chaos is not None:
+                        self.chaos.on_save_committed(Path(path), step)
+                    if self.keep is not None:
+                        self.gc_removed.extend(
+                            str(p) for p in gc_generations(
+                                Path(path).parent, self.keep))
             except BaseException as e:  # surfaced on next wait()
                 self._error = e
 
@@ -172,12 +195,14 @@ def _write_checkpoint(host_leaves, treedef_str: str, path: Path, *,
                 "treedef": treedef_str, "leaves": {}}
     pid = jax.process_index() if jax.process_count() > 1 else 0
     data_path = tmp / f"data.{pid}.bin"
+    written = 0
     with open(data_path, "wb") as f:
         for key, arr in host_leaves:
             raw = np.ascontiguousarray(arr).tobytes()
             blob, codec = _compress(raw)
             off = f.tell()
             f.write(blob)
+            written += len(blob)
             manifest["leaves"][key] = {
                 "shape": list(arr.shape), "dtype": str(arr.dtype),
                 "offset": off, "nbytes": len(blob), "file": data_path.name,
@@ -195,6 +220,7 @@ def _write_checkpoint(host_leaves, treedef_str: str, path: Path, *,
             f.write(text)
             f.flush()
             os.fsync(f.fileno())
+        written += len(text)        # ASCII: json.dumps escapes the rest
     if path.exists():
         shutil.rmtree(path)
     os.rename(tmp, path)
@@ -204,6 +230,9 @@ def _write_checkpoint(host_leaves, treedef_str: str, path: Path, *,
         os.fsync(dfd)
     finally:
         os.close(dfd)
+    with _writes_lock:
+        _writes["generations"] += 1
+        _writes["bytes"] += written
 
 
 def save(tree: Any, path: str | Path, *, step: int = 0,

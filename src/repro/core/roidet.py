@@ -126,6 +126,7 @@ def roidet(frames: jax.Array, det_params: Any, *, block_size: int = 8,
                      det_boxes=dboxes, det_valid=dvalid)
 
 
+@jax.named_scope("roidet")
 def _roidet_fleet_impl(frames: jax.Array, det_params: Any, *, block_size: int,
                        motion_thresh: float, edge_thresh: float,
                        conf_thresh: float, use_kernel: bool,

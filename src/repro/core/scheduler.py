@@ -82,6 +82,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common import obs
+# the fetch counters stay readable here, where tests and the chip benchmark
+# read them
+from repro.common.obs import d2h_fetch_counts  # noqa: F401
 from repro.core import allocation as alloc
 from repro.core import codec as codec_mod
 from repro.core import elastic as elastic_mod
@@ -112,25 +116,14 @@ MOTION_KEEP_THRESH = fleet_mod.MOTION_KEEP_THRESH
 # scoped ``exempt`` (the pipelined log harvest; episode mode has NO per-slot
 # exemption at all — its one harvest happens after the trace); on the CPU
 # backend D2H is zero-copy and the guard never fires, so the per-category
-# counters below are the proof instead.  Categories: 'harvest' (packed log
-# fetches), 'keep' (reducto keep-flag fetches — sequential mode only since
-# the keep decision moved on device), 'control' (the host control path's
-# (a, c) sync).  Episode runs must leave 'keep' and 'control' at zero and
-# add exactly TWO 'harvest' fetches per run (the stacked F1/size pack and
-# the stacked control pack), independent of slot count.
-
-D2H_CATEGORIES = ("harvest", "keep", "control")
-_D2H_FETCHES: Dict[str, int] = {}
-
-
-def d2h_fetch_counts() -> Dict[str, int]:
-    """Snapshot of the per-category D2H fetch counters since process start
-    (every category always present, zero-initialized)."""
-    return {k: _D2H_FETCHES.get(k, 0) for k in D2H_CATEGORIES}
+# counters (``common.obs``, categories listed there) are the proof instead.
+# Episode runs must leave 'keep' and 'control' at zero and add exactly TWO
+# 'harvest' fetches per run (the stacked F1/size pack and the stacked
+# control pack), independent of slot count.
 
 
 def _d2h(x, kind: str, exempt: bool = False) -> np.ndarray:
-    _D2H_FETCHES[kind] = _D2H_FETCHES.get(kind, 0) + 1
+    obs.count_fetch(kind)
     if exempt:
         with jax.transfer_guard_device_to_host("allow"):
             return np.asarray(x)
@@ -269,7 +262,6 @@ class DeepStreamSystem:
         # post-run serving carry (EpisodeCarry) recorded by run_episode and
         # the carried pipelined loop — what serve.stream checkpoints
         self.last_carry: Optional[EpisodeCarry] = None
-        self.timers: Dict[str, List[float]] = {}
         self.mesh = (shard_rules.camera_mesh()
                      if cfg.batched and cfg.shard == "auto" else None)
         # GT padding capacity fixed ONCE per scene config: deriving it from
@@ -290,9 +282,6 @@ class DeepStreamSystem:
         self._key, subs = _key_chain(self._key, n)
         return subs
 
-    def _t(self, name: str, t0: float) -> None:
-        self.timers.setdefault(name, []).append(time.perf_counter() - t0)
-
     # -- camera side -----------------------------------------------------------
 
     def camera_features(self, frames_c: np.ndarray, block: bool = True):
@@ -300,13 +289,11 @@ class DeepStreamSystem:
         over the camera mesh when one exists).  ``block=False`` skips the
         device sync — the pipelined slot loop fetches only the packed (a, c)
         scalars it needs."""
-        t0 = time.perf_counter()
         res = roidet_mod.roidet_fleet(
             jnp.asarray(frames_c), self.light, block_size=self.cfg.block_size,
             use_kernel=self.cfg.use_kernels, mesh=self.mesh)
         if block:
             jax.block_until_ready(res.mask)
-        self._t("roidet", t0)
         return res
 
     # -- server-side evaluation: sequential path --------------------------------
@@ -317,11 +304,9 @@ class DeepStreamSystem:
         (Reducto's detection-reuse scoring lives in ``_reuse_f1``.)"""
         n = decoded.shape[0]
         idxs = fleet_mod.eval_indices(n, self.cfg.eval_frames)
-        t0 = time.perf_counter()
         grid = det.forward(self.server, decoded[idxs])
         boxes, scores, valid = det.decode_boxes(grid, conf_thresh=0.4)
         boxes, valid = np.asarray(boxes), np.asarray(valid)
-        self._t("server", t0)
         f1s = [det.f1_score(boxes[i], valid[i], gt_frames[j])
                for i, j in enumerate(idxs)]
         return float(np.mean(f1s))
@@ -336,18 +321,14 @@ class DeepStreamSystem:
         fr = jnp.asarray(frames)
         H, W = fr.shape[-2:]
         if mask is not None:
-            t0 = time.perf_counter()
             fr = roidet_mod.crop_to_mask(fr, mask, self.cfg.block_size)
             roi_pixels = float(jnp.sum(mask)) * self.cfg.block_size ** 2
-            self._t("crop", t0)
         else:
             roi_pixels = float(H * W)
-        t0 = time.perf_counter()
         decoded, size = codec_mod.encode_segment(
             self.cfg.codec, fr, jnp.float32(roi_pixels), jnp.float32(b),
             jnp.float32(r), self._nextkey() if key is None else key)
         jax.block_until_ready(decoded)
-        self._t("compress", t0)
         f1 = self.detect_f1(decoded, gt)
         return f1, float(size)
 
@@ -383,7 +364,6 @@ class DeepStreamSystem:
             gt_boxes, gt_valid = fleet_mod.pad_gt_all(gts, N, G=self._G)
         else:
             gt_boxes, gt_valid = gt_dev
-        t0 = time.perf_counter()
         out = fleet_mod.fleet_slot_step(
             self.cfg.codec, self.server, jnp.asarray(frames),
             jnp.asarray(masks), jnp.asarray(b, jnp.float32),
@@ -393,7 +373,6 @@ class DeepStreamSystem:
             mesh=self.mesh, donate=self.cfg.donate, with_reuse=with_reuse,
             use_kernel=self.cfg.use_kernels, live=live,
             checked=self.cfg.checked)
-        self._t("fleet", t0)
         return out
 
     def fleet_encode_eval(self, frames: np.ndarray, gts: List[List[List[Tuple]]],
@@ -405,9 +384,7 @@ class DeepStreamSystem:
         (per-frame F1s (C, F), sizes (C,), raw FleetSlotOut)."""
         out = self._slot_dispatch(frames, gts, masks, b, r, keys=keys,
                                   with_reuse=False)
-        t0 = time.perf_counter()
         jax.block_until_ready(out.host_pack)
-        self._t("fleet_sync", t0)
         return np.asarray(out.f1_frames), np.asarray(out.sizes), out
 
     # -- offline profiling (section 5.1 + 5.3.1b) --------------------------------
@@ -633,64 +610,69 @@ class DeepStreamSystem:
         C = self.cfg.scene.num_cameras
         lam = self.cfg.lam()
         t_begin = scene._t
-        # untimed prep: every operand device-resident before dispatch
-        ctx = self._control_context(method, trace_kbps, use_elastic)
-        if carry is not None:
-            ctx["est"] = carry.est
-        deep = method in ("deepstream", "deepstream_no_elastic")
-        t0 = time.perf_counter()
-        # fleet_episode preps/places inputs, then runs the whole trace under
-        # jax.transfer_guard("disallow") in BOTH directions with NO scoped
-        # exemptions and blocks — the structural zero-per-slot-transfer
-        # guarantee of episode mode
-        out = fleet_mod.fleet_episode(
-            method, codec_cfg=self.cfg.codec, scene_cfg=scene.cfg,
-            server_params=self.server, light_params=self.light,
-            mlp_params=self.mlp if deep else None,
-            jcab_util=ctx["jcab_util"], jcab_res=ctx["jcab_res"],
-            lam=ctx["lam"], scene_params=scene.params, trace=ctx["trace"],
-            key0=self._key, skey=scene.key, tau_wl=ctx["tau_wl"],
-            tau_wh=ctx["tau_wh"], est0=ctx["est"], ecfg=self.cfg.elastic,
-            bitrates=tuple(self.cfg.codec.bitrates_kbps),
-            resolutions=tuple(self.cfg.codec.resolutions),
-            use_elastic=use_elastic, w_cap=ctx["w_cap"], num_cams=C,
-            eval_frames=self.cfg.eval_frames, block_size=self.cfg.block_size,
-            use_kernel=self.cfg.use_kernels, gt_pad=self._G,
-            t_start=scene._t, mesh=self.mesh,
-            buckets=self.cfg.episode_buckets, faults=faults,
-            checked=self.cfg.checked,
-            pipelined=self.cfg.episode_pipelined,
-            ref0=None if carry is None else carry.ref,
-            live_prev0=None if carry is None else carry.live_prev,
-            t_first=None if carry is None else carry.t_first)
-        self._t("episode", t0)
-        # advance the scene cursor exactly like T pipelined segment() calls
-        # would — a reused scene continues, matching the pipelined reference
-        scene._t += len(trace_kbps)
-        self._key = out.key
-        self.last_carry = EpisodeCarry(
-            est=out.est, ref=out.ref,
-            # audit: allow(host-sync) host-input faults mask, after dispatch
-            live_prev=(np.asarray(faults[-1], bool) if faults is not None
-                       else np.ones(C, bool)),
-            t_first=(carry.t_first if carry is not None else t_begin))
-        t0 = time.perf_counter()
-        # the ONE whole-trace harvest — deliberately NOT transfer-guard
-        # exempted: it happens after the timed region, so episode runs need
-        # no scoped per-slot exemption anywhere
-        packs = _d2h(out.packs, "harvest")
-        cpacks = _d2h(out.cpacks, "harvest")
-        self._t("harvest", t0)
-        return {
-            "utility": packs[:, 0] @ lam,
-            "mean_f1": packs[:, 0].mean(axis=1),
-            "bytes": packs[:, 1].sum(axis=1),
-            # audit: allow(host-sync) host-input trace echo, post-harvest
-            "W": np.asarray(trace_kbps, float),
-            "extra": cpacks[:, 0].astype(float),
-            "area": cpacks[:, 1].astype(float),
-            "alloc_kbps": cpacks[:, 2].astype(float),
-        }
+        # three host spans per dispatch: serve.prep (control context,
+        # padding, placement, executable lookup), serve.device (the episode
+        # until its logs are ready) and serve.harvest (the two fetches and
+        # the log rows)
+        with obs.span("serve.prep"):
+            ctx = self._control_context(method, trace_kbps, use_elastic)
+            if carry is not None:
+                ctx["est"] = carry.est
+            deep = method in ("deepstream", "deepstream_no_elastic")
+            launch = fleet_mod.prepare_episode(
+                method, codec_cfg=self.cfg.codec, scene_cfg=scene.cfg,
+                server_params=self.server, light_params=self.light,
+                mlp_params=self.mlp if deep else None,
+                jcab_util=ctx["jcab_util"], jcab_res=ctx["jcab_res"],
+                lam=ctx["lam"], scene_params=scene.params,
+                trace=ctx["trace"], key0=self._key, skey=scene.key,
+                tau_wl=ctx["tau_wl"], tau_wh=ctx["tau_wh"], est0=ctx["est"],
+                ecfg=self.cfg.elastic,
+                bitrates=tuple(self.cfg.codec.bitrates_kbps),
+                resolutions=tuple(self.cfg.codec.resolutions),
+                use_elastic=use_elastic, w_cap=ctx["w_cap"], num_cams=C,
+                eval_frames=self.cfg.eval_frames,
+                block_size=self.cfg.block_size,
+                use_kernel=self.cfg.use_kernels, gt_pad=self._G,
+                t_start=scene._t, mesh=self.mesh,
+                buckets=self.cfg.episode_buckets, faults=faults,
+                checked=self.cfg.checked,
+                pipelined=self.cfg.episode_pipelined,
+                ref0=None if carry is None else carry.ref,
+                live_prev0=None if carry is None else carry.live_prev,
+                t_first=None if carry is None else carry.t_first)
+        # the whole trace runs under jax.transfer_guard("disallow") in BOTH
+        # directions with NO scoped exemptions, and blocks — the structural
+        # zero-per-slot-transfer guarantee of episode mode
+        with obs.span("serve.device"):
+            out = launch()
+        with obs.span("serve.harvest"):
+            # advance the scene cursor exactly like T pipelined segment()
+            # calls would — a reused scene continues, matching the
+            # pipelined reference
+            scene._t += len(trace_kbps)
+            self._key = out.key
+            self.last_carry = EpisodeCarry(
+                est=out.est, ref=out.ref,
+                # audit: allow(host-sync) host-input faults mask, after dispatch
+                live_prev=(np.asarray(faults[-1], bool) if faults is not None
+                           else np.ones(C, bool)),
+                t_first=(carry.t_first if carry is not None else t_begin))
+            # the ONE whole-trace harvest — deliberately NOT transfer-guard
+            # exempted: it happens after the timed region, so episode runs
+            # need no scoped per-slot exemption anywhere
+            packs = _d2h(out.packs, "harvest")
+            cpacks = _d2h(out.cpacks, "harvest")
+            return {
+                "utility": packs[:, 0] @ lam,
+                "mean_f1": packs[:, 0].mean(axis=1),
+                "bytes": packs[:, 1].sum(axis=1),
+                # audit: allow(host-sync) host-input trace echo, post-harvest
+                "W": np.asarray(trace_kbps, float),
+                "extra": cpacks[:, 0].astype(float),
+                "area": cpacks[:, 1].astype(float),
+                "alloc_kbps": cpacks[:, 2].astype(float),
+            }
 
     def _slot_allocation(self, method: str, frames: np.ndarray, W_t: float,
                          est: ElasticState, use_elastic: bool,
@@ -729,14 +711,12 @@ class DeepStreamSystem:
                     self.cfg.elastic, est, area, W_t,
                     self.tau_wl, self.tau_wh, reset_debt=bool(reconnect))
                 extra = extra_kbits / cfgc.slot_seconds   # Kbps-equivalent
-            t0 = time.perf_counter()
             util, best_res = alloc.build_utility_table(
                 self.mlp, a, c, bitrates, cfgc.resolutions, lam)
             al = alloc.allocate_dp(util, best_res, bitrates,
                                    max(W_t + extra, 0.0),
                                    use_kernel=self.cfg.use_kernels,
                                    live=live)
-            self._t("alloc", t0)
             b, r = al.bitrates_kbps, al.resolutions
             masks = roi.mask
             alloc_kbps = float(al.bitrates_kbps.sum())
@@ -807,14 +787,9 @@ class DeepStreamSystem:
         if method in ("deepstream", "deepstream_no_elastic"):
             roi = self.camera_features(frames, block=False)
             masks = roi.mask
-            # shard-boundary gather onto the control device; on CPU the
-            # device_put also absorbs the wait for the in-flight ROIDet, so
-            # time it apart from the control dispatch proper
-            t0 = time.perf_counter()
+            # shard-boundary gather onto the control device
             a = shard_rules.unshard(roi.area_ratio, self.mesh)
             c = shard_rules.unshard(roi.confidence, self.mesh)
-            self._t("gather", t0)
-        t0 = time.perf_counter()
         co = fleet_mod.fleet_control_step(
             method, self.mlp if a is not None else None,
             ctx["jcab_util"], ctx["jcab_res"], ctx["lam"], a, c,
@@ -829,7 +804,6 @@ class DeepStreamSystem:
             live=None if live is None else jnp.asarray(live, bool),
             reconnect=bool(reconnect), checked=self.cfg.checked)
         ctx["est"] = co.est
-        self._t("ctrl", t0)
         return co.b, co.r, masks, co.pack
 
     def _run_batched(self, scene: MultiCameraScene, trace_kbps: np.ndarray,
@@ -869,13 +843,11 @@ class DeepStreamSystem:
         def harvest(item: Tuple[fleet_mod.FleetSlotOut,
                                 Optional[jax.Array]]) -> None:
             out, cpack = item
-            t0 = time.perf_counter()
             # the per-slot log harvest: one (2, C) + one (4,) D2H transfer,
             # explicitly exempted from the loop's transfer-guard guarantee
             pack = _d2h(out.host_pack, "harvest", exempt=True)
             cp = (None if cpack is None
                   else _d2h(cpack, "harvest", exempt=True))
-            self._t("harvest", t0)
             logs["utility"].append(float(np.dot(lam, pack[0])))
             logs["mean_f1"].append(float(np.mean(pack[0])))
             logs["bytes"].append(float(np.sum(pack[1])))
@@ -1037,19 +1009,15 @@ class DeepStreamSystem:
                                      exempt=True), first)
             kept_idx, ev_idx = self._kept_eval_selection(keep)
             self._reducto_ref_host[i] = fr[kept_idx[-1]]
-            t0 = time.perf_counter()
             decoded, size = codec_mod.encode_segment(
                 self.cfg.codec, jnp.asarray(fr), jnp.float32(H * W),
                 jnp.float32(bs[i]), jnp.float32(1.0),
                 self._nextkey() if keys is None else keys[i],
                 num_frames=jnp.float32(len(kept_idx)))
             jax.block_until_ready(decoded)
-            self._t("compress", t0)
-            t0 = time.perf_counter()
             grid = det.forward(self.server, decoded[ev_idx])
             db, _, dv = det.decode_boxes(grid, conf_thresh=0.4)
             db, dv = np.asarray(db), np.asarray(dv)
-            self._t("server", t0)
             f1 = float(np.mean([det.f1_score(db[k], dv[k], gts[i][j])
                                 for k, j in enumerate(ev_idx)]))
             # filtered frames reuse the last kept RAW frame's detections

@@ -219,7 +219,7 @@ def make_scene(name: str, seed: int = 0) -> SceneConfig:
 # -- fault families -----------------------------------------------------------
 #
 # Camera liveness masks (T, C) bool, True = alive.  Contract (mirrored by
-# ``fleet.fleet_episode``'s docstring): a dead (camera, slot) cell sends zero
+# ``fleet.prepare_episode``'s docstring): a dead (camera, slot) cell sends zero
 # bits and zero bytes, is excluded from the bandwidth allocators, cannot
 # advance the reducto reference, and on reconnect is treated as a fresh
 # camera (reference re-seeded, elastic debt cleared).  Camera 0 stays alive

@@ -298,6 +298,7 @@ def pad_scene_params(params: DeviceSceneParams, c_pad: int
         objects=params.objects)
 
 
+@jax.named_scope("scene")
 def segments_device(cfg: SceneConfig, params: DeviceSceneParams,
                     key: jax.Array, t: jax.Array, *, gt_pad: int
                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:

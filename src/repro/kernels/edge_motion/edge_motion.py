@@ -87,4 +87,5 @@ def edge_motion_pallas(f0_tiles: jax.Array, f1_tiles: jax.Array, *,
                                lambda p, t: (p, t, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((P, T, TH // bs, W // bs), jnp.float32),
         interpret=interpret,
+        name="edge_motion",
     )(f0_tiles, f1_tiles)

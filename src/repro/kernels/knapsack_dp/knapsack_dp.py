@@ -65,5 +65,6 @@ def knapsack_dp_pallas(util: jax.Array, costs: jax.Array, W: int, *,
         out_shape=[jax.ShapeDtypeStruct((1, wpad), jnp.float32),
                    jax.ShapeDtypeStruct((I, wpad), jnp.int32)],
         interpret=interpret,
+        name="knapsack_dp",
     )(util.astype(jnp.float32), costs.astype(jnp.int32))
     return vals[0, :W + 1], choices[:, :W + 1]

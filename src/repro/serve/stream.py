@@ -71,6 +71,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.ckpt import checkpoint as ckpt
+from repro.common import obs
 from repro.core import elastic as elastic_mod
 from repro.core import fleet as fleet_mod
 from repro.core.scheduler import DeepStreamSystem, EpisodeCarry
@@ -84,6 +85,10 @@ LOG_KEYS = ("utility", "mean_f1", "bytes", "W", "extra", "area",
 # the degraded-mode ladder: every rung serves the same carry chain exactly
 # (see _dispatch_window), so a rung change is a latency decision only
 LADDER = ("episode", "episode_small", "pipelined")
+
+# window turnarounds and events a runner keeps: an always-on stream must not
+# grow per window
+HISTORY = 10_000
 
 
 @dataclass
@@ -166,8 +171,11 @@ class StreamingFleetRunner:
         self.duplicates = 0
         self.out_of_order = 0
         self.logs: Dict[str, List[float]] = {k: [] for k in LOG_KEYS}
-        self.window_walls: List[float] = []  # turnaround per served window
-        self.events: List[Dict[str, Any]] = []
+        # turnaround of the most recent windows, and their count and sum
+        self.window_walls: Deque[float] = deque(maxlen=HISTORY)
+        self.windows_served = 0
+        self.wall_s_total = 0.0
+        self.events: Deque[Dict[str, Any]] = deque(maxlen=HISTORY)
         self._queue: Deque[Tuple[float, np.ndarray]] = deque()
         self.watchdog = Watchdog(cfg.watchdog)
         self.saver = ckpt.AsyncSaver(keep=cfg.ckpt_keep, chaos=chaos)
@@ -276,13 +284,18 @@ class StreamingFleetRunner:
         if self.wall_hook is not None:
             wall = self.wall_hook(self.window, wall)
         self.carry = self.system.last_carry
-        for k in LOG_KEYS:
-            self.logs[k].extend(float(v) for v in logs[k])
+        with obs.span("serve.logs"):
+            for k in LOG_KEYS:
+                self.logs[k].extend(float(v) for v in logs[k])
         self.window += 1
         self.window_walls.append(wall)
-        self._supervise(wall)
+        self.windows_served += 1
+        self.wall_s_total += wall
+        with obs.span("serve.supervise"):
+            self._supervise(wall)
         if self.cfg.ckpt_dir is not None:
-            self.checkpointer.maybe_save(self.window)
+            with obs.span("serve.checkpoint"):
+                self.checkpointer.maybe_save(self.window)
 
     def _dispatch_window(self, W: np.ndarray, live: np.ndarray
                          ) -> Dict[str, np.ndarray]:
@@ -376,16 +389,18 @@ class StreamingFleetRunner:
         never corrupt one (uncommitted directories are never restored)."""
         if self.carry is None:
             return
-        meta = {"window": window, "t_next": int(self.t_next),
-                "t_first": int(self.carry.t_first), "rung": self.rung,
-                "ok_streak": self.ok_streak,
-                "dropped_slots": self.dropped_slots, "method": self.method,
-                "quarantined": dict(self.quarantined),
-                "quarantined_slots": self.quarantined_slots,
-                "gap_filled_slots": self.gap_filled_slots,
-                "duplicates": self.duplicates,
-                "out_of_order": self.out_of_order,
-                "logs": {k: list(v) for k, v in self.logs.items()}}
+        with obs.span("ckpt.meta"):
+            meta = {"window": window, "t_next": int(self.t_next),
+                    "t_first": int(self.carry.t_first), "rung": self.rung,
+                    "ok_streak": self.ok_streak,
+                    "dropped_slots": self.dropped_slots,
+                    "method": self.method,
+                    "quarantined": dict(self.quarantined),
+                    "quarantined_slots": self.quarantined_slots,
+                    "gap_filled_slots": self.gap_filled_slots,
+                    "duplicates": self.duplicates,
+                    "out_of_order": self.out_of_order,
+                    "logs": {k: list(v) for k, v in self.logs.items()}}
         self.saver.save(self._carry_tree(), self._ckpt_path(window),
                         step=window, metadata=meta,
                         blocking=self.checkpointer.preempted)
@@ -441,13 +456,16 @@ class StreamingFleetRunner:
 
     # -- stats / teardown ------------------------------------------------------
 
-    def stats(self) -> Dict[str, float]:
-        """Serving SLO summary over the windows served so far."""
+    def stats(self) -> Dict[str, Any]:
+        """Serving SLO summary over the windows served so far (the
+        percentiles over the most recent ``HISTORY``), and ``host_ms``: the
+        median milliseconds of each serving span over its most recent
+        ``obs.RECENT`` (where a window's host time goes)."""
         walls = np.asarray(self.window_walls, float)
         slots = len(self.logs["W"])
-        total = float(walls.sum()) if walls.size else 0.0
+        total = self.wall_s_total
         return {
-            "windows": int(walls.size),
+            "windows": self.windows_served,
             "slots": slots,
             "dropped_slots": self.dropped_slots,
             "quarantined_slots": self.quarantined_slots,
@@ -458,6 +476,8 @@ class StreamingFleetRunner:
             "p99_window_s": float(np.percentile(walls, 99)) if walls.size else 0.0,
             "slots_per_s": slots / total if total > 0 else 0.0,
             "rung": LADDER[self.rung],
+            "host_ms": {n: 1e3 * float(np.median(d)) for n in obs.SERVE_SPANS
+                        if (d := obs.recent(n))},
         }
 
     def close(self) -> None:
