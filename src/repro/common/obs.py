@@ -13,14 +13,17 @@ a window, and ``ckpt.*`` inside a checkpoint save.
 
 ``count_fetch(kind)`` counts device-to-host fetches per category
 (``D2H_CATEGORIES``), so the zero-per-slot-transfer contracts are checkable
-on backends where the transfer guard never fires.
+on backends where the transfer guard never fires.  ``count_put(kind)``
+counts host-to-device puts per category (``H2D_CATEGORIES``) and
+``count_operand_cache(kind)`` the episode operand cache's builds and reuses
+(``OPERAND_CACHE_EVENTS``); ``StreamingFleetRunner.stats()`` reports both.
 """
 from __future__ import annotations
 
 import time
 from collections import deque
 from contextlib import contextmanager
-from typing import Deque, Dict, Iterator, List
+from typing import Deque, Dict, Iterator, List, Tuple
 
 import jax
 
@@ -52,22 +55,60 @@ def recent(name: str) -> List[float]:
     return list(_recent.get(name, ()))
 
 
+class _Counts:
+    """Per-category event counts since process start."""
+
+    def __init__(self, categories: Tuple[str, ...], what: str):
+        self.categories = categories
+        self.what = what
+        self._n: Dict[str, int] = {}
+
+    def add(self, kind: str) -> None:
+        if kind not in self.categories:
+            raise ValueError(f"unknown {self.what} category {kind!r}")
+        self._n[kind] = self._n.get(kind, 0) + 1
+
+    def snapshot(self) -> Dict[str, int]:
+        """Every category, zero-initialized."""
+        return {k: self._n.get(k, 0) for k in self.categories}
+
+
 # Categories: 'harvest' (packed log fetches), 'keep' (reducto keep-flag
 # fetches, sequential mode only), 'control' (the host control path's (a, c)
 # sync), 'checkpoint' (one per carry leaf a checkpoint save copies to the
 # host).  Episode runs leave 'keep' and 'control' at zero and add exactly
 # two 'harvest' fetches per run.
 D2H_CATEGORIES = ("harvest", "keep", "control", "checkpoint")
-_fetches: Dict[str, int] = {}
-
-
-def count_fetch(kind: str) -> None:
-    if kind not in D2H_CATEGORIES:
-        raise ValueError(f"unknown fetch category {kind!r}")
-    _fetches[kind] = _fetches.get(kind, 0) + 1
+_fetches = _Counts(D2H_CATEGORIES, "fetch")
+count_fetch = _fetches.add
 
 
 def d2h_fetch_counts() -> Dict[str, int]:
     """Snapshot of the per-category D2H fetch counters since process start
     (every category always present, zero-initialized)."""
-    return {k: _fetches.get(k, 0) for k in D2H_CATEGORIES}
+    return _fetches.snapshot()
+
+
+# Categories: 'episode' (the one batched put of a window's operands, one
+# per prepared episode), 'invariants' (one per build of the operands every
+# window shares; see OPERAND_CACHE_EVENTS).
+H2D_CATEGORIES = ("episode", "invariants")
+_puts = _Counts(H2D_CATEGORIES, "put")
+count_put = _puts.add
+
+
+def h2d_put_counts() -> Dict[str, int]:
+    """Snapshot of the per-category H2D put counters since process start."""
+    return _puts.snapshot()
+
+
+# The episode operand cache ('built': the window invariants were placed
+# anew, 'reused': a window took the cached ones).
+OPERAND_CACHE_EVENTS = ("built", "reused")
+_operand_cache = _Counts(OPERAND_CACHE_EVENTS, "operand-cache")
+count_operand_cache = _operand_cache.add
+
+
+def operand_cache_counts() -> Dict[str, int]:
+    """Snapshot of the episode operand cache's builds and reuses."""
+    return _operand_cache.snapshot()

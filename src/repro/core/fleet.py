@@ -156,6 +156,7 @@ import numpy as np
 from jax.experimental import checkify
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.common import obs
 from repro.core import allocation as alloc_mod
 from repro.core import codec as codec_mod
 from repro.core import elastic as elastic_mod
@@ -1203,32 +1204,188 @@ def _get_episode_executable(mesh: Optional[Mesh], **statics):
     return fn
 
 
-def fleet_episode(method: str, **kw) -> EpisodeOut:
+class EpisodeInvariants(NamedTuple):
+    """The episode operands every window of a stream shares, placed once:
+    the detector and utility-MLP parameters (``{}`` for a method without
+    the MLP), the jcab tables (zeros/ones for every other method), the
+    camera weights, the camera-padded scene params and key, the elastic
+    thresholds, and the fresh carry a window without one starts from.
+    ``episode_invariants`` builds it; the scheduler caches it per method."""
+    server_params: Any
+    light_params: Any
+    mlp_params: Any
+    jcab_util: jax.Array       # (C, J) f32
+    jcab_res: jax.Array        # (C, J) f32
+    lam: jax.Array             # (C,) f32
+    scene_params: DeviceSceneParams
+    skey: jax.Array
+    tau_wl: jax.Array          # f32 scalar
+    tau_wh: jax.Array          # f32 scalar
+    est0: ElasticStateJax      # the fresh elastic state
+    ref0: jax.Array            # (C_pad, H, W) f32 zeros: the fresh reference
+
+
+class EpisodeArgs(NamedTuple):
+    """The episode executable's arguments, in ``_episode_impl`` order."""
+    server_params: Any
+    light_params: Any
+    mlp_params: Any
+    jcab_util: jax.Array
+    jcab_res: jax.Array
+    lam: jax.Array
+    scene_params: DeviceSceneParams
+    trace: jax.Array           # (T_b,) f32 Kbps, zero past the active T
+    live_tr: jax.Array         # (T_b, C) bool, all-live past T
+    active: jax.Array          # (T_b,) bool
+    t_idx: jax.Array           # (T_b,) int32 global slot indices
+    t_first: jax.Array         # int32 scalar: the stream's first slot
+    t_len: jax.Array           # int32 scalar: the active T
+    key0: jax.Array
+    skey: jax.Array
+    tau_wl: jax.Array
+    tau_wh: jax.Array
+    est0: ElasticStateJax
+    ref0: jax.Array            # (C_pad, H, W) f32
+    live_prev0: jax.Array      # (C,) bool
+
+
+def _mesh_shardings(mesh: Mesh) -> Tuple[NamedSharding, NamedSharding]:
+    """(replicated, camera-sharded) placements on ``mesh``."""
+    return NamedSharding(mesh, P()), NamedSharding(mesh, P("camera"))
+
+
+def episode_invariants(*, server_params, light_params, mlp_params,
+                       jcab_util: Optional[np.ndarray],
+                       jcab_res: Optional[np.ndarray], lam: np.ndarray,
+                       scene_params: DeviceSceneParams, skey: jax.Array,
+                       tau_wl: float, tau_wh: float, num_cams: int,
+                       num_bitrates: int, frame_hw: Tuple[int, int],
+                       mesh: Optional[Mesh] = None) -> EpisodeInvariants:
+    """Build the window invariants: the host values (``lam``, the
+    thresholds, the jcab tables or None, the fresh carry) in their final
+    dtypes in ONE ``device_put``; the device values (parameters, scene)
+    passed through, or, under a camera mesh, placed replicated or
+    camera-sharded in that same put.  The scene params are padded to the
+    mesh's camera multiple first (a few device ops, once per build)."""
+    C_pad = pad_cameras(num_cams, mesh)
+    shape = (num_cams, num_bitrates)
+    host = dict(
+        jcab_util=(np.zeros(shape, np.float32) if jcab_util is None
+                   else np.asarray(jcab_util, np.float32)),
+        jcab_res=(np.ones(shape, np.float32) if jcab_res is None
+                  else np.asarray(jcab_res, np.float32)),
+        lam=np.asarray(lam, np.float32),
+        tau_wl=np.float32(tau_wl), tau_wh=np.float32(tau_wh),
+        est0=ElasticStateJax(a_ema=np.float32(0.0), a_var=np.float32(0.0),
+                             debt_kbits=np.float32(0.0),
+                             initialized=np.bool_(False)),
+        ref0=np.zeros((C_pad,) + tuple(frame_hw), np.float32))
+    dev = dict(server_params=server_params, light_params=light_params,
+               mlp_params={} if mlp_params is None else mlp_params,
+               scene_params=synth_mod.pad_scene_params(scene_params, C_pad),
+               skey=skey)
+    obs.count_put("invariants")
+    if mesh is None:
+        host = jax.device_put(host)
+    else:
+        rep_sh, cam_sh = _mesh_shardings(mesh)
+        scene_sh = DeviceSceneParams(*(
+            cam_sh if s == P("camera") else rep_sh
+            for s in DeviceSceneParams.pspecs()))
+        host, dev = jax.device_put(
+            (host, dev),
+            ({k: cam_sh if k == "ref0" else rep_sh for k in host},
+             {k: scene_sh if k == "scene_params" else rep_sh for k in dev}))
+    return EpisodeInvariants(**host, **dev)
+
+
+def episode_args(inv: EpisodeInvariants, trace_kbps: np.ndarray, *,
+                 key0: jax.Array, num_cams: int, t_start: int,
+                 buckets: Optional[Sequence[int]] = EPISODE_BUCKETS,
+                 mesh: Optional[Mesh] = None,
+                 faults: Optional[np.ndarray] = None,
+                 est0: Optional[ElasticStateJax] = None,
+                 ref0: Optional[jax.Array] = None,
+                 live_prev0: Optional[np.ndarray] = None,
+                 t_first: Optional[int] = None) -> EpisodeArgs:
+    """One window's episode arguments: the invariants ``inv`` plus the
+    window's host values — the bandwidth trace, the liveness mask, the
+    active-slot mask, the slot indices, the previous liveness row and the
+    ``t_first``/``t_len`` scalars — built in numpy in their final dtypes
+    and shapes (padded to the trace bucket) and placed with ONE
+    ``device_put``.  The codec key and a carried elastic state and
+    reference are device arrays already and pass through; under a camera
+    mesh they join that put to take their placement (a no-op when they
+    have it), and a carried reference is padded to the mesh's camera
+    multiple.
+
+    ``faults`` is the optional (T, C) bool liveness mask (None: all live);
+    ``est0``/``ref0``/``live_prev0``/``t_first`` default to a fresh stream
+    (``inv``'s fresh carry, all-live, ``t_start``)."""
+    T = len(trace_kbps)
+    T_b = bucket_len(T, buckets)
+    live = np.ones((T_b, num_cams), bool)
+    if faults is not None:
+        faults = np.asarray(faults, bool)
+        if faults.shape != (T, num_cams):
+            raise ValueError(
+                f"faults mask must be (T={T}, C={num_cams}) bool, got "
+                f"{faults.shape}")
+        if not faults.any(axis=1).all():
+            raise ValueError("faults mask leaves a slot with zero live "
+                             "cameras — the control step needs >= 1")
+        live[:T] = faults
+    # zero-Kbps tail: padded slots run (and are discarded); zeros keep the
+    # traced DP's capacity clamp trivially satisfied there
+    trace = np.zeros(T_b, np.float32)
+    trace[:T] = np.asarray(trace_kbps, np.float32)
+    # slot indices continue from the scene's cursor (t_start) — data values,
+    # not statics, so resumed episodes reuse the same executable; t_first
+    # marks the STREAM's first slot (reducto's reference-reset rule) and
+    # t_len the ACTIVE prefix of a bucketed trace
+    window = (trace, live, np.arange(T_b) < T,
+              np.arange(T_b, dtype=np.int32) + np.int32(t_start),
+              np.int32(t_start if t_first is None else t_first), np.int32(T),
+              (np.ones(num_cams, bool) if live_prev0 is None
+               else np.asarray(live_prev0, bool)))
+    if ref0 is not None and ref0.shape[0] != inv.ref0.shape[0]:
+        ref0 = pad_leading(ref0, inv.ref0.shape[0])
+    obs.count_put("episode")
+    if mesh is None:
+        window = jax.device_put(window)
+    else:
+        rep_sh, cam_sh = _mesh_shardings(mesh)
+        window, key0, est0, ref0 = jax.device_put(
+            (window, key0, est0, ref0), (rep_sh, rep_sh, rep_sh, cam_sh))
+    trace, live_tr, active, t_idx, t_first, t_len, live_prev0 = window
+    return EpisodeArgs(
+        inv.server_params, inv.light_params, inv.mlp_params, inv.jcab_util,
+        inv.jcab_res, inv.lam, inv.scene_params, trace, live_tr, active,
+        t_idx, t_first, t_len, key0, inv.skey, inv.tau_wl, inv.tau_wh,
+        inv.est0 if est0 is None else est0,
+        inv.ref0 if ref0 is None else ref0, live_prev0)
+
+
+def fleet_episode(method: str, args: EpisodeArgs, T: int,
+                  **kw) -> EpisodeOut:
     """Prepare one episode (``prepare_episode``) and launch it."""
-    return prepare_episode(method, **kw)()
+    return prepare_episode(method, args, T, **kw)()
 
 
-def prepare_episode(method: str, *, codec_cfg: CodecConfig,
-                    scene_cfg: SceneConfig, server_params, light_params,
-                    mlp_params, jcab_util, jcab_res, lam,
-                    scene_params: DeviceSceneParams, trace: jax.Array,
-                    key0: jax.Array, skey: jax.Array, tau_wl, tau_wh,
-                    est0: ElasticStateJax, ecfg: ElasticConfig,
-                    bitrates: Sequence[int], resolutions: Sequence[float],
-                    use_elastic: bool, w_cap: int, num_cams: int,
-                    eval_frames: int, block_size: int, use_kernel: bool = True,
+def prepare_episode(method: str, args: EpisodeArgs, T: int, *,
+                    codec_cfg: CodecConfig, scene_cfg: SceneConfig,
+                    ecfg: ElasticConfig, bitrates: Sequence[int],
+                    resolutions: Sequence[float], use_elastic: bool,
+                    w_cap: int, num_cams: int, eval_frames: int,
+                    block_size: int, use_kernel: bool = True,
                     conf_thresh: float = 0.4, gt_pad: int = 16,
-                    t_start: int = 0, mesh: Optional[Mesh] = None,
-                    buckets: Optional[Sequence[int]] = EPISODE_BUCKETS,
-                    faults: Optional[np.ndarray] = None, checked: bool = False,
-                    ref0: Optional[jax.Array] = None,
-                    live_prev0: Optional[np.ndarray] = None,
-                    t_first: Optional[int] = None,
+                    mesh: Optional[Mesh] = None, checked: bool = False,
                     pipelined: bool = True) -> Callable[[], EpisodeOut]:
-    """Prepare a WHOLE bandwidth trace as one compiled episode: pad and
-    place the operands and look up (or compile) the executable.  Returns
-    ``launch()``, which runs the episode, blocks until its logs are ready
-    and returns them — the serving loop times the two apart.
+    """Prepare a WHOLE bandwidth trace as one compiled episode: look up (or
+    compile) the executable for ``args`` (``episode_args``: a T-slot trace
+    padded to its bucket).  Returns ``launch()``, which runs the episode,
+    blocks until its logs are ready and returns them — the serving loop
+    times the two apart.
 
     ``pipelined=True`` (the default, and the production program) runs the
     scan body as a 2-stage software pipeline: iteration i overlaps slot i's
@@ -1240,23 +1397,19 @@ def prepare_episode(method: str, *, codec_cfg: CodecConfig,
     always uses the reference body — the diagnostics lane instruments the
     simplest program.
 
-    ``faults`` is the optional (T, C) bool liveness mask (True = live;
-    None = all live).  It is ALWAYS scanned — as an all-True array when no
-    faults are injected — so faulty and fault-free episodes share one
-    executable signature: fault injection costs zero recompiles and zero
-    extra per-slot transfers.  Bucketing pads the mask's tail with
-    all-live rows (padded slots are discarded anyway).  ``checked=True``
-    dispatches the checkify-instrumented executable (unsharded) and throws
-    any violated invariant AFTER the transfer-guarded region.
+    The liveness mask is ALWAYS scanned — all-True when no faults are
+    injected — so faulty and fault-free episodes share one executable
+    signature: fault injection costs zero recompiles and zero extra
+    per-slot transfers.  ``checked=True`` dispatches the
+    checkify-instrumented executable (unsharded) and throws any violated
+    invariant AFTER the transfer-guarded region.
 
-    Every argument must already be device-resident (the scheduler's
-    ``run_episode`` prepares them before its timed region); this wrapper
-    only pads the camera axis AND the trace length, places sharded operands
-    with explicit ``device_put`` (allowed under
-    ``jax.transfer_guard("disallow")``, which blocks implicit transfers
-    only) and looks up the cached executable; ``launch()`` calls it and
-    returns stacked (T, 2, C) log packs + (T, 4) control packs as device
-    arrays — ONE harvest fetch at episode end is all the host ever does.
+    Operand contract: the invariants are cached across windows and each
+    window's host values arrive in one batched put (``episode_invariants``,
+    ``episode_args``), so every argument is device-resident and placed
+    before this is called; ``launch()`` calls the executable and returns
+    stacked (T, 2, C) log packs + (T, 4) control packs as device arrays —
+    ONE harvest fetch at episode end is all the host ever does.
 
     Trace-length bucketing: T is padded up to ``bucket_len(T, buckets)``
     with zero-bandwidth tail slots and the active length rides along as a
@@ -1266,17 +1419,14 @@ def prepare_episode(method: str, *, codec_cfg: CodecConfig,
     state advances; logs here are already sliced back to T).  ``w_cap``
     must be computed from the ACTIVE trace (``allocation.trace_capacity``
     on the unpadded array) — the zero-Kbps padding never widens it.
-    ``buckets=None`` disables padding (the unbucketed reference program the
-    equivalence tests diff against).
 
-    Streaming carry (windowed serving, ``serve.stream``): ``ref0`` ((C, H,
-    W) reducto reference), ``live_prev0`` ((C,) bool previous liveness row)
-    and ``t_first`` (the STREAM's first slot, distinct from this window's
-    ``t_start``) seed the episode carry from the previous window so a chain
-    of windows is slot-for-slot identical to one long episode; the final
-    carry comes back in ``EpisodeOut`` (``est``, ``ref``).  All three
-    default to the standalone-run behavior (zeros / all-live /
-    ``t_start``)."""
+    Streaming carry (windowed serving, ``serve.stream``): ``episode_args``'
+    ``ref0`` ((C, H, W) reducto reference), ``live_prev0`` ((C,) bool
+    previous liveness row) and ``t_first`` (the STREAM's first slot,
+    distinct from this window's ``t_start``) seed the episode carry from
+    the previous window so a chain of windows is slot-for-slot identical to
+    one long episode; the final carry comes back in ``EpisodeOut`` (``est``,
+    ``ref``)."""
     # the DP backtrack is only shard_map-scan-safe in its unrolled (<= 64
     # camera) form — fail loudly instead of hitting the XLA CHECK abort the
     # fori_loop fallback would trigger inside this scan (see backtrack_jax)
@@ -1285,48 +1435,12 @@ def prepare_episode(method: str, *, codec_cfg: CodecConfig,
         "knapsack backtrack must take its unrolled form inside the "
         "shard_map'd scan body")
     C_pad = pad_cameras(num_cams, mesh)
-    scene_params = synth_mod.pad_scene_params(scene_params, C_pad)
+    T_b = int(args.trace.shape[0])
     # the traced generator reads only shape-like SceneConfig fields (N, H,
     # W, noise_std) — the seed lives in the DEVICE params, so normalize it
     # out of the static cache key or every new scene would re-trace
     import dataclasses as _dc
     scene_cfg = _dc.replace(scene_cfg, seed=0)
-    T = int(trace.shape[0])
-    T_b = bucket_len(T, buckets)
-    if faults is None:
-        live_np = np.ones((T_b, num_cams), bool)
-    else:
-        live_np = np.asarray(faults, bool)
-        if live_np.shape != (T, num_cams):
-            raise ValueError(
-                f"faults mask must be (T={T}, C={num_cams}) bool, got "
-                f"{live_np.shape}")
-        if not live_np.any(axis=1).all():
-            raise ValueError("faults mask leaves a slot with zero live "
-                             "cameras — the control step needs >= 1")
-        if T_b != T:
-            live_np = np.concatenate(
-                [live_np, np.ones((T_b - T, num_cams), bool)])
-    live_tr = jnp.asarray(live_np)
-    if T_b != T:
-        # zero-Kbps tail: padded slots run (and are discarded); zeros keep
-        # the traced DP's capacity clamp trivially satisfied there
-        trace = jnp.concatenate(
-            [jnp.asarray(trace, jnp.float32), jnp.zeros(T_b - T, jnp.float32)])
-    active = jnp.arange(T_b) < T
-    if ref0 is None:
-        ref0 = jnp.zeros((C_pad, scene_cfg.height, scene_cfg.width),
-                         jnp.float32)
-    else:
-        ref0 = pad_leading(jnp.asarray(ref0, jnp.float32), C_pad)
-    live_prev0 = (jnp.ones((num_cams,), bool) if live_prev0 is None
-                  else jnp.asarray(live_prev0, bool))
-    J = len(bitrates)
-    if jcab_util is None:
-        jcab_util = jnp.zeros((num_cams, J), jnp.float32)
-        jcab_res = jnp.ones((num_cams, J), jnp.float32)
-    if mlp_params is None:
-        mlp_params = {}
     fn = _get_episode_executable(
         mesh, method=method, scfg=scene_cfg, ccfg=codec_cfg, ecfg=ecfg,
         bitrates=tuple(int(b) for b in bitrates),
@@ -1337,36 +1451,6 @@ def prepare_episode(method: str, *, codec_cfg: CodecConfig,
         conf_thresh=float(conf_thresh), gt_pad=int(gt_pad),
         sharded=mesh is not None, checked=bool(checked),
         pipelined=bool(pipelined) and not bool(checked))
-    # slot indices continue from the scene's cursor (t_start) — data values,
-    # not statics, so resumed episodes reuse the same executable; t_first
-    # marks the STREAM's first slot (reducto's reference-reset rule —
-    # defaults to this run's t_start for a standalone run) and t_len the
-    # ACTIVE prefix of a bucketed trace
-    t_idx = jnp.arange(T_b, dtype=jnp.int32) + jnp.int32(t_start)
-    t_first = jnp.int32(t_start if t_first is None else t_first)
-    t_len = jnp.int32(T)
-    if mesh is not None:
-        # EXPLICIT mesh placement of every operand (replicated params and
-        # camera-sharded scene state) — jit would otherwise reshard
-        # implicitly at arg-binding time, which the transfer guard below
-        # rightly rejects
-        cam_sh = NamedSharding(mesh, P("camera"))
-        rep_sh = NamedSharding(mesh, P())
-        rep = lambda tree: jax.tree.map(
-            lambda x: jax.device_put(x, rep_sh), tree)
-        scene_params = DeviceSceneParams(*(
-            jax.device_put(x, cam_sh if s == P("camera") else rep_sh)
-            for x, s in zip(scene_params, DeviceSceneParams.pspecs())))
-        ref0 = jax.device_put(ref0, cam_sh)
-        (server_params, light_params, mlp_params, jcab_util, jcab_res, lam,
-         trace, live_tr, active, t_idx, t_first, t_len, key0, skey, tau_wl,
-         tau_wh, est0, live_prev0) = rep(
-            (server_params, light_params, mlp_params, jcab_util, jcab_res,
-             lam, trace, live_tr, active, t_idx, t_first, t_len, key0, skey,
-             tau_wl, tau_wh, est0, live_prev0))
-    args = (server_params, light_params, mlp_params, jcab_util, jcab_res,
-            lam, scene_params, trace, live_tr, active, t_idx, t_first, t_len,
-            key0, skey, tau_wl, tau_wh, est0, ref0, live_prev0)
 
     # the timed episode proper: everything is device-resident by now, so the
     # whole T-slot trace executes under the transfer guard in BOTH

@@ -262,6 +262,10 @@ class DeepStreamSystem:
         # post-run serving carry (EpisodeCarry) recorded by run_episode and
         # the carried pipelined loop — what serve.stream checkpoints
         self.last_carry: Optional[EpisodeCarry] = None
+        # method -> (host values, device leaves, EpisodeInvariants) the
+        # episode windows reuse (see _episode_invariants)
+        self._invariants: Dict[str, Tuple[Any, List[Any],
+                                          fleet_mod.EpisodeInvariants]] = {}
         self.mesh = (shard_rules.camera_mesh()
                      if cfg.batched and cfg.shard == "auto" else None)
         # GT padding capacity fixed ONCE per scene config: deriving it from
@@ -610,37 +614,26 @@ class DeepStreamSystem:
         C = self.cfg.scene.num_cameras
         lam = self.cfg.lam()
         t_begin = scene._t
-        # three host spans per dispatch: serve.prep (control context,
-        # padding, placement, executable lookup), serve.device (the episode
-        # until its logs are ready) and serve.harvest (the two fetches and
-        # the log rows)
+        # three host spans per dispatch: serve.prep (the window's operands:
+        # the cached invariants plus ONE batched put of its host values,
+        # and the executable lookup), serve.device (the episode until its
+        # logs are ready) and serve.harvest (the two fetches and the log
+        # rows)
         with obs.span("serve.prep"):
-            ctx = self._control_context(method, trace_kbps, use_elastic)
-            if carry is not None:
-                ctx["est"] = carry.est
-            deep = method in ("deepstream", "deepstream_no_elastic")
+            args = self._episode_args(method, scene, trace_kbps,
+                                      faults=faults, carry=carry)
             launch = fleet_mod.prepare_episode(
-                method, codec_cfg=self.cfg.codec, scene_cfg=scene.cfg,
-                server_params=self.server, light_params=self.light,
-                mlp_params=self.mlp if deep else None,
-                jcab_util=ctx["jcab_util"], jcab_res=ctx["jcab_res"],
-                lam=ctx["lam"], scene_params=scene.params,
-                trace=ctx["trace"], key0=self._key, skey=scene.key,
-                tau_wl=ctx["tau_wl"], tau_wh=ctx["tau_wh"], est0=ctx["est"],
-                ecfg=self.cfg.elastic,
+                method, args, len(trace_kbps), codec_cfg=self.cfg.codec,
+                scene_cfg=scene.cfg, ecfg=self.cfg.elastic,
                 bitrates=tuple(self.cfg.codec.bitrates_kbps),
                 resolutions=tuple(self.cfg.codec.resolutions),
-                use_elastic=use_elastic, w_cap=ctx["w_cap"], num_cams=C,
+                use_elastic=use_elastic,
+                w_cap=self._w_cap(trace_kbps, use_elastic), num_cams=C,
                 eval_frames=self.cfg.eval_frames,
                 block_size=self.cfg.block_size,
                 use_kernel=self.cfg.use_kernels, gt_pad=self._G,
-                t_start=scene._t, mesh=self.mesh,
-                buckets=self.cfg.episode_buckets, faults=faults,
-                checked=self.cfg.checked,
-                pipelined=self.cfg.episode_pipelined,
-                ref0=None if carry is None else carry.ref,
-                live_prev0=None if carry is None else carry.live_prev,
-                t_first=None if carry is None else carry.t_first)
+                mesh=self.mesh, checked=self.cfg.checked,
+                pipelined=self.cfg.episode_pipelined)
         # the whole trace runs under jax.transfer_guard("disallow") in BOTH
         # directions with NO scoped exemptions, and blocks — the structural
         # zero-per-slot-transfer guarantee of episode mode
@@ -737,30 +730,34 @@ class DeepStreamSystem:
             raise ValueError(method)
         return b, r, masks, extra, area, alloc_kbps, est
 
-    def _control_context(self, method: str, trace_kbps: np.ndarray,
-                         use_elastic: bool) -> Dict[str, Any]:
-        """Per-run device uploads for the traced control loop: the prefetched
-        bandwidth trace, lambda weights, elastic thresholds, (for jcab) the
-        content-agnostic table, the fresh device elastic state, and the ONE
-        static DP capacity covering every slot (trace max plus the maximum
-        elastic borrow)."""
+    def _w_cap(self, trace_kbps: np.ndarray, use_elastic: bool) -> int:
+        """The ONE static DP capacity covering every slot of a run: the
+        trace max plus the maximum elastic borrow.  It comes from the
+        ACTIVE (unpadded) trace — episode bucketing appends zero-Kbps tail
+        slots after this runs, so a bucketed run solves the exact DP the
+        unbucketed program would.  ``cfg.w_cap_kbps`` optionally pins it so
+        different traces share one compiled control program (w_cap is a
+        jit static)."""
         cfgc = self.cfg.codec
-        bitrates = tuple(int(b) for b in cfgc.bitrates_kbps)
-        # the static DP capacity comes from the ACTIVE (unpadded) trace —
-        # episode bucketing appends zero-Kbps tail slots AFTER this runs, so
-        # a bucketed run solves the exact DP the unbucketed program would.
-        # cfg.w_cap_kbps optionally pins it so different traces share one
-        # compiled control program (w_cap is a jit static).
         borrow = (self.cfg.elastic.budget_kbits / cfgc.slot_seconds
                   if use_elastic else 0.0)
-        w_cap = alloc.trace_capacity(
-            bitrates, trace_kbps, self.cfg.scene.num_cameras,
-            elastic_borrow_kbps=borrow, pin_kbps=self.cfg.w_cap_kbps)
+        return alloc.trace_capacity(
+            tuple(int(b) for b in cfgc.bitrates_kbps), trace_kbps,
+            self.cfg.scene.num_cameras, elastic_borrow_kbps=borrow,
+            pin_kbps=self.cfg.w_cap_kbps)
+
+    def _control_context(self, method: str, trace_kbps: np.ndarray,
+                         use_elastic: bool) -> Dict[str, Any]:
+        """Per-run device uploads for the pipelined loop's traced control
+        step: the prefetched bandwidth trace (indexed slot by slot), lambda
+        weights, elastic thresholds, (for jcab) the content-agnostic table,
+        the fresh device elastic state, and the static DP capacity
+        (``_w_cap``)."""
         ctx: Dict[str, Any] = dict(
             trace=jnp.asarray(np.asarray(trace_kbps, np.float32)),
             lam=jnp.asarray(self.cfg.lam(), jnp.float32),
             tau_wl=jnp.float32(self.tau_wl), tau_wh=jnp.float32(self.tau_wh),
-            w_cap=w_cap,
+            w_cap=self._w_cap(trace_kbps, use_elastic),
             est=elastic_mod.init_state_jax(),
             jcab_util=None, jcab_res=None)
         if method == "jcab":
@@ -769,6 +766,64 @@ class DeepStreamSystem:
             ctx["jcab_util"] = jnp.asarray(util)
             ctx["jcab_res"] = jnp.asarray(best_res)
         return ctx
+
+    def _episode_invariants(self, method: str, scene: DeviceScene
+                            ) -> fleet_mod.EpisodeInvariants:
+        """The episode operands every window of a stream shares
+        (``fleet.EpisodeInvariants``), built once per method on this
+        system's device or mesh and reused while what they were built from
+        holds: the host values (camera weights, elastic thresholds, the
+        jcab table, the codec resolutions) compared by value, the device
+        ones (detector and MLP parameters, scene params and key) by
+        identity of the arrays, which are immutable and held by the cache,
+        so a rebuild follows any reassignment, e.g. ``tau_wl`` after
+        re-profiling or a new scene."""
+        deep = method in ("deepstream", "deepstream_no_elastic")
+        jcab = method == "jcab"
+        host_key = (self.cfg.lam().tobytes(), float(self.tau_wl),
+                    float(self.tau_wh), tuple(self.cfg.codec.resolutions),
+                    (self.jcab_table.shape, self.jcab_table.tobytes())
+                    if jcab else None)
+        mlp = self.mlp if deep else None
+        dev_key = jax.tree.leaves((self.server, self.light, mlp,
+                                   scene.params, scene.key))
+        hit = self._invariants.get(method)
+        if (hit is not None and hit[0] == host_key
+                and len(hit[1]) == len(dev_key)
+                and all(a is b for a, b in zip(hit[1], dev_key))):
+            obs.count_operand_cache("reused")
+            return hit[2]
+        util, best_res = self._jcab_utility_table() if jcab else (None, None)
+        inv = fleet_mod.episode_invariants(
+            server_params=self.server, light_params=self.light,
+            mlp_params=mlp, jcab_util=util, jcab_res=best_res,
+            lam=self.cfg.lam(), scene_params=scene.params, skey=scene.key,
+            tau_wl=self.tau_wl, tau_wh=self.tau_wh,
+            num_cams=self.cfg.scene.num_cameras,
+            num_bitrates=len(self.cfg.codec.bitrates_kbps),
+            frame_hw=(scene.cfg.height, scene.cfg.width), mesh=self.mesh)
+        self._invariants[method] = (host_key, dev_key, inv)
+        obs.count_operand_cache("built")
+        return inv
+
+    def _episode_args(self, method: str, scene: DeviceScene,
+                      trace_kbps: np.ndarray,
+                      faults: Optional[np.ndarray] = None,
+                      carry: Optional[EpisodeCarry] = None
+                      ) -> fleet_mod.EpisodeArgs:
+        """One window's episode arguments: the cached invariants plus ONE
+        batched put of the window's host values (``fleet.episode_args``);
+        the run key and a carried elastic state and reference pass
+        through."""
+        return fleet_mod.episode_args(
+            self._episode_invariants(method, scene), trace_kbps,
+            key0=self._key, num_cams=self.cfg.scene.num_cameras,
+            t_start=scene._t, buckets=self.cfg.episode_buckets,
+            mesh=self.mesh, faults=faults,
+            est0=None if carry is None else carry.est,
+            ref0=None if carry is None else carry.ref,
+            live_prev0=None if carry is None else carry.live_prev,
+            t_first=None if carry is None else carry.t_first)
 
     def _slot_control_device(self, method: str, frames: jax.Array, t: int,
                              ctx: Dict[str, Any], use_elastic: bool,

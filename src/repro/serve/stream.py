@@ -458,9 +458,12 @@ class StreamingFleetRunner:
 
     def stats(self) -> Dict[str, Any]:
         """Serving SLO summary over the windows served so far (the
-        percentiles over the most recent ``HISTORY``), and ``host_ms``: the
+        percentiles over the most recent ``HISTORY``); ``host_ms``: the
         median milliseconds of each serving span over its most recent
-        ``obs.RECENT`` (where a window's host time goes)."""
+        ``obs.RECENT`` (where a window's host time goes); ``h2d_puts`` and
+        ``operand_cache``: the process's host-to-device puts per category
+        (one 'episode' put per window) and the episode operand cache's
+        builds and reuses (``repro.common.obs``)."""
         walls = np.asarray(self.window_walls, float)
         slots = len(self.logs["W"])
         total = self.wall_s_total
@@ -478,6 +481,8 @@ class StreamingFleetRunner:
             "rung": LADDER[self.rung],
             "host_ms": {n: 1e3 * float(np.median(d)) for n in obs.SERVE_SPANS
                         if (d := obs.recent(n))},
+            "h2d_puts": obs.h2d_put_counts(),
+            "operand_cache": obs.operand_cache_counts(),
         }
 
     def close(self) -> None:

@@ -1,20 +1,32 @@
 """Whole-trace device-resident episodes: episode-vs-pipelined equivalence
 for every method, the zero-per-slot-transfer guarantee (fetch counters +
-transfer guard, no scoped exemptions), traced keep-selection math vs the
-host mirror, and device-side segment synthesis stats vs the host scene."""
+transfer guard, no scoped exemptions), the episode operands (cached
+invariants plus one batched put) against the eager construction they
+replace, traced keep-selection math vs the host mirror, and device-side
+segment synthesis stats vs the host scene."""
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.core import elastic as elastic_mod
 from repro.core import fleet as fleet_mod
 from repro.core import scheduler as sched_mod
 from repro.core import utility as util_mod
-from repro.core.scheduler import DeepStreamSystem, SystemConfig
+from repro.core.elastic import ElasticStateJax
+from repro.core.scheduler import DeepStreamSystem, EpisodeCarry, SystemConfig
+from repro.data import synthetic as synth_mod
 from repro.data.synthetic import (DeviceScene, MultiCameraScene, SceneConfig,
                                   bandwidth_trace)
 from repro.kernels.edge_motion import ops as em_ops
+from repro.models import detector as det
+from repro.sharding.rules import pad_cameras, pad_leading
 
 METHODS = ["deepstream", "jcab", "reducto", "static"]
 
@@ -82,6 +94,170 @@ def test_episode_zero_recompiles(episode_pair):
     ep.run(DeviceScene(SceneConfig(seed=22, num_cameras=3)), trace,
            method="deepstream")
     assert fleet_mod.episode_compile_count() == n0
+
+
+# ---------------------------------------------------------------------------
+# episode operands: cached invariants + one batched put vs the eager build
+# ---------------------------------------------------------------------------
+
+def _eager_episode_args(s, method, scene, trace_kbps, faults, carry):
+    """The episode executable's arguments as ``run_episode`` built them
+    before its operands were cached and batched — about eighteen eager
+    device operations per window.  Kept only as this test's reference."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    C, mesh = s.cfg.scene.num_cameras, s.mesh
+    # the control context
+    trace = jnp.asarray(np.asarray(trace_kbps, np.float32))
+    lam = jnp.asarray(s.cfg.lam(), jnp.float32)
+    tau_wl, tau_wh = jnp.float32(s.tau_wl), jnp.float32(s.tau_wh)
+    est0 = elastic_mod.init_state_jax()
+    jcab_util = jcab_res = None
+    if method == "jcab":
+        util, best_res = s._jcab_utility_table()
+        jcab_util, jcab_res = jnp.asarray(util), jnp.asarray(best_res)
+    if carry is not None:
+        est0 = carry.est
+    deep = method in ("deepstream", "deepstream_no_elastic")
+    mlp_params = s.mlp if deep else None
+    ref0 = None if carry is None else carry.ref
+    live_prev0 = None if carry is None else carry.live_prev
+    t_first = None if carry is None else carry.t_first
+    t_start, key0, skey = scene._t, s._key, scene.key
+    # the operand half of prepare_episode
+    C_pad = pad_cameras(C, mesh)
+    scene_params = synth_mod.pad_scene_params(scene.params, C_pad)
+    T = int(trace.shape[0])
+    T_b = fleet_mod.bucket_len(T, s.cfg.episode_buckets)
+    if faults is None:
+        live_np = np.ones((T_b, C), bool)
+    else:
+        live_np = np.asarray(faults, bool)
+        if T_b != T:
+            live_np = np.concatenate([live_np, np.ones((T_b - T, C), bool)])
+    live_tr = jnp.asarray(live_np)
+    if T_b != T:
+        trace = jnp.concatenate(
+            [jnp.asarray(trace, jnp.float32), jnp.zeros(T_b - T, jnp.float32)])
+    active = jnp.arange(T_b) < T
+    if ref0 is None:
+        ref0 = jnp.zeros((C_pad, scene.cfg.height, scene.cfg.width),
+                         jnp.float32)
+    else:
+        ref0 = pad_leading(jnp.asarray(ref0, jnp.float32), C_pad)
+    live_prev0 = (jnp.ones((C,), bool) if live_prev0 is None
+                  else jnp.asarray(live_prev0, bool))
+    J = len(s.cfg.codec.bitrates_kbps)
+    if jcab_util is None:
+        jcab_util = jnp.zeros((C, J), jnp.float32)
+        jcab_res = jnp.ones((C, J), jnp.float32)
+    if mlp_params is None:
+        mlp_params = {}
+    t_idx = jnp.arange(T_b, dtype=jnp.int32) + jnp.int32(t_start)
+    t_first = jnp.int32(t_start if t_first is None else t_first)
+    t_len = jnp.int32(T)
+    server_params, light_params = s.server, s.light
+    if mesh is not None:
+        cam_sh = NamedSharding(mesh, P("camera"))
+        rep_sh = NamedSharding(mesh, P())
+        rep = lambda tree: jax.tree.map(
+            lambda x: jax.device_put(x, rep_sh), tree)
+        scene_params = synth_mod.DeviceSceneParams(*(
+            jax.device_put(x, cam_sh if sp == P("camera") else rep_sh)
+            for x, sp in zip(scene_params,
+                             synth_mod.DeviceSceneParams.pspecs())))
+        ref0 = jax.device_put(ref0, cam_sh)
+        (server_params, light_params, mlp_params, jcab_util, jcab_res, lam,
+         trace, live_tr, active, t_idx, t_first, t_len, key0, skey, tau_wl,
+         tau_wh, est0, live_prev0) = rep(
+            (server_params, light_params, mlp_params, jcab_util, jcab_res,
+             lam, trace, live_tr, active, t_idx, t_first, t_len, key0, skey,
+             tau_wl, tau_wh, est0, live_prev0))
+    return (server_params, light_params, mlp_params, jcab_util, jcab_res,
+            lam, scene_params, trace, live_tr, active, t_idx, t_first, t_len,
+            key0, skey, tau_wl, tau_wh, est0, ref0, live_prev0)
+
+
+@pytest.fixture(scope="module")
+def operand_system():
+    """An episode system with untrained detectors: the operand tests build
+    arguments and never launch them."""
+    cfg = SystemConfig(scene=SceneConfig(seed=5, num_cameras=3),
+                       eval_frames=3, batched=True, episode=True)
+    s = DeepStreamSystem(cfg, det.init_detector(jax.random.PRNGKey(1), "light"),
+                         det.init_detector(jax.random.PRNGKey(2), "server"))
+    s.mlp = util_mod.init_utility_mlp(jax.random.PRNGKey(0))
+    s.tau_wl, s.tau_wh = 10.0, 50.0
+    s.jcab_table = np.linspace(0.2, 0.8, 18).reshape(6, 3).astype(np.float32)
+    return s
+
+
+@pytest.mark.parametrize("faulty", [False, True], ids=["live", "faults"])
+@pytest.mark.parametrize("carried", [False, True], ids=["fresh", "carry"])
+@pytest.mark.parametrize("T", [8, 5], ids=["full", "flush"])
+@pytest.mark.parametrize("method", ["deepstream", "reducto", "jcab"])
+def test_episode_args_match_eager_construction(operand_system, method, T,
+                                               carried, faulty):
+    """Every argument of the episode executable — tree structure, shape,
+    dtype and weak type, value bit for bit, and sharding — is what the
+    eager construction gave, for full and flush (T < bucket) windows, with
+    and without a carry and faults, on one device or (in the fake-device
+    lane) the camera mesh.  Built twice, so the second build reads the
+    cached invariants."""
+    s = operand_system
+    C = s.cfg.scene.num_cameras
+    fake = os.environ.get("REPRO_FAKE_DEVICES")
+    if fake:
+        assert jax.device_count() == int(fake) and s.mesh is not None
+    scene = DeviceScene(SceneConfig(seed=33, num_cameras=C))
+    rng = np.random.default_rng(T + 2 * carried + 4 * faulty)
+    trace = rng.uniform(100.0, 3000.0, T)
+    faults = None
+    if faulty:
+        faults = rng.uniform(size=(T, C)) < 0.7
+        faults[:, 0] = True
+    carry = None
+    if carried:
+        scene._t = 16
+        carry = EpisodeCarry(
+            est=ElasticStateJax(jnp.float32(0.3), jnp.float32(0.01),
+                                jnp.float32(120.0), jnp.asarray(True)),
+            ref=jax.random.uniform(jax.random.PRNGKey(3),
+                                   (C, scene.cfg.height, scene.cfg.width)),
+            live_prev=np.array([True, False, True]), t_first=3)
+    want = _eager_episode_args(s, method, scene, trace, faults, carry)
+    for _ in range(2):
+        got = s._episode_args(method, scene, trace, faults=faults,
+                              carry=carry)
+        assert isinstance(got, fleet_mod.EpisodeArgs)
+        for name, g, w in zip(fleet_mod.EpisodeArgs._fields, got, want):
+            gl, gdef = jax.tree.flatten(g)
+            wl, wdef = jax.tree.flatten(w)
+            assert gdef == wdef, name
+            for a, b in zip(gl, wl):
+                assert isinstance(a, jax.Array), name
+                assert jax.typeof(a) == jax.typeof(b), (name, jax.typeof(a),
+                                                        jax.typeof(b))
+                assert a.sharding == b.sharding, (name, a.sharding,
+                                                  b.sharding)
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                              err_msg=name)
+
+
+def test_episode_args_match_on_camera_mesh():
+    """The operand differential above on a camera mesh of four CPU host
+    devices (the suite's fake-device lane), where three cameras pad to
+    four: the invariants and each window's put are placed replicated or
+    camera-sharded exactly as the eager construction placed them."""
+    root = Path(__file__).resolve().parents[1]
+    env = dict(os.environ, REPRO_FAKE_DEVICES="4")
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "-p", "no:randomly", str(Path(__file__)),
+         "-k", "test_episode_args_match_eager_construction"],
+        capture_output=True, text=True, timeout=570, env=env, cwd=str(root))
+    assert proc.returncode == 0, proc.stdout[-4000:] + proc.stderr[-4000:]
+    assert "24 passed" in proc.stdout, proc.stdout[-4000:]
 
 
 # ---------------------------------------------------------------------------

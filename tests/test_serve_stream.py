@@ -10,12 +10,15 @@ recompiles after restore (the restored carry re-enters the executables the
 pre-crash process compiled) and the episode-mode D2H contract intact
 (exactly the 2 'harvest' fetches per episode dispatch, nothing else).
 
-Also here: windowed == continuous (no crash at all), the SLO watchdog
+Also here: windowed == continuous (no crash at all), a warm window's
+operands arriving in one put with the cached invariants (and the cache
+rebuilt when a threshold changes), the SLO watchdog
 ladder (degrade under injected stragglers -> pipelined, recover, logs STILL
 exact — every rung serves the same carry chain), bounded-queue load
 shedding with drop accounting that survives restore, a small soak, and the
 ServeEngine drain-budget starvation regression.
 """
+import contextlib
 import os
 import signal
 
@@ -24,8 +27,12 @@ import numpy as np
 import pytest
 
 import harness
+from repro.common import obs
+from repro.core import elastic as elastic_mod
 from repro.core import fleet as fleet_mod
 from repro.core import scheduler as sched_mod
+from repro.data import synthetic as synth_mod
+from repro.sharding import rules as shard_rules
 from repro.data.scenarios import make_faults, make_scene, make_soak_stream, \
     make_trace
 from repro.data.synthetic import DeviceScene
@@ -83,6 +90,130 @@ def test_windowed_matches_continuous(detectors, method):
     assert served == 2 and runner.t_next == len(trace)
     harness.assert_logs_match(ref, _logs(runner), keys=STREAM_KEYS,
                               ctx=f"stream {method}")
+
+
+# -- episode operands: one put per window, cached invariants ------------------
+
+# device-array constructors a warm window's prep must not call: its host
+# values arrive in ONE jax.device_put and everything else is cached
+_CONSTRUCTORS = frozenset({
+    "array", "asarray", "zeros", "ones", "full", "empty", "arange",
+    "zeros_like", "ones_like", "full_like", "concatenate", "stack",
+    "float32", "int32", "bool_", "uint32"})
+# the modules whose `jnp` the episode prep path reaches
+_PREP_MODULES = (sched_mod, fleet_mod, elastic_mod, synth_mod, shard_rules)
+
+
+class _NoConstructors:
+    """`jax.numpy` with its array constructors made to raise."""
+
+    def __init__(self, jnp):
+        self._jnp = jnp
+
+    def __getattr__(self, name):
+        if name in _CONSTRUCTORS:
+            raise AssertionError(f"warm prep called jnp.{name}")
+        return getattr(self._jnp, name)
+
+
+@contextlib.contextmanager
+def _guarded_prep(puts):
+    """Inside, every ``serve.prep`` span runs with the array constructors
+    of ``_PREP_MODULES`` and ``init_state_jax`` raising and implicit
+    transfers refused, and counts its ``jax.device_put`` calls into
+    ``puts``."""
+    span = obs.span
+
+    @contextlib.contextmanager
+    def guarded(name):
+        if name != "serve.prep":
+            with span(name):
+                yield
+            return
+        put = jax.device_put
+
+        def counted_put(*a, **k):
+            puts.append(1)
+            return put(*a, **k)
+
+        with span(name), pytest.MonkeyPatch.context() as m:
+            for mod in _PREP_MODULES:
+                m.setattr(mod, "jnp", _NoConstructors(mod.jnp))
+            m.setattr(elastic_mod, "init_state_jax", None)
+            m.setattr(jax, "device_put", counted_put)
+            with jax.transfer_guard("disallow"):
+                yield
+
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(obs, "span", guarded)
+        yield
+
+
+@pytest.mark.parametrize("method", harness.METHODS)
+def test_warm_window_prep_is_one_put(detectors, method):
+    """Each window's operands reach the device in ONE put: the put counter
+    reads one episode put per window, the invariants are built on the
+    first window and reused on every later one, and once the first window
+    is warm, prep calls no array constructor, makes no implicit transfer
+    and calls ``jax.device_put`` exactly once — on full windows and a
+    flushed partial one.  The logs still equal one uninterrupted episode."""
+    scfg, trace, faults = _stream_inputs(29, "camera_flap")
+    ref = _continuous_reference(detectors, scfg, trace, faults, method)
+    runner = _runner(detectors, scfg, method, StreamConfig(window_slots=8))
+    puts0, cache0 = obs.h2d_put_counts(), obs.operand_cache_counts()
+    runner.offer(trace[:8], faults=faults[:8])
+    assert runner.serve() == 1
+    puts = []
+    with _guarded_prep(puts):
+        runner.offer(trace[8:], faults=faults[8:])
+        assert runner.serve(flush=True) == 3      # two full + one partial
+    assert len(puts) == 3
+    st = runner.stats()
+    assert {k: st["h2d_puts"][k] - puts0[k] for k in puts0} == {
+        "episode": 4, "invariants": 1}
+    assert {k: st["operand_cache"][k] - cache0[k] for k in cache0} == {
+        "built": 1, "reused": 3}
+    harness.assert_logs_match(ref, _logs(runner), keys=STREAM_KEYS,
+                              ctx=f"one-put stream {method}")
+
+
+def test_tau_wl_change_rebuilds_invariants(detectors):
+    """A re-profiled ``tau_wl`` between windows rebuilds the cached
+    invariants, and the next window serves exactly what a fresh system
+    with that threshold serves from the same carry.  The new ``tau_wl``
+    lies above the whole trace, so elastic borrowing starts: the cache
+    cannot be stale without the logs showing it."""
+    scfg, trace, faults = _stream_inputs(16, "camera_flap")
+    tau_wl = 3000.0
+    runner = _runner(detectors, scfg, "deepstream",
+                     StreamConfig(window_slots=8))
+    cache0 = obs.operand_cache_counts()
+    runner.offer(trace[:8], faults=faults[:8])
+    runner.serve()
+    carry = runner.carry
+    runner.system.tau_wl = tau_wl
+    runner.offer(trace[8:], faults=faults[8:])
+    runner.serve()
+    cache1 = obs.operand_cache_counts()
+    assert cache1["built"] - cache0["built"] == 2
+    assert cache1["reused"] == cache0["reused"]
+
+    first = harness.build_system(detectors, "episode", scfg)
+    first._key = jax.random.PRNGKey(1234)
+    want0 = first.run_episode(synth_mod.DeviceScene(scfg), trace[:8],
+                              "deepstream", faults=faults[:8])
+    fresh = harness.build_system(detectors, "episode", scfg)
+    fresh.tau_wl = tau_wl
+    fresh._key = jax.random.PRNGKey(1234)
+    scene = synth_mod.DeviceScene(scfg)
+    scene._t = 8
+    want1 = fresh.run_episode(scene, trace[8:], "deepstream",
+                              faults=faults[8:], carry=carry)
+    got = _logs(runner)
+    for k in STREAM_KEYS:
+        np.testing.assert_array_equal(
+            got[k], np.concatenate([want0[k], want1[k]]), err_msg=k)
+    assert not got["extra"][:8].any() and got["extra"][8:].any()
 
 
 # -- kill-and-resume -----------------------------------------------------------
