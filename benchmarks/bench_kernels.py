@@ -27,7 +27,6 @@ def run(quick: bool = False) -> dict:
     out = {}
 
     # edge_motion: 720p-ish segment through the oracle + kernel validation
-    from repro import kernels
     from repro.kernels.edge_motion import ops as em
     frames = jnp.asarray(rng.uniform(0, 1, (5, 192, 320)).astype(np.float32))
     t_ref = _time(lambda f: em.segment_motion(f, use_kernel=False), frames)
@@ -38,27 +37,6 @@ def run(quick: bool = False) -> dict:
         "kernel_max_err": float(jnp.max(jnp.abs(a - b))),
         "vmem_per_program_kb": (2 * (32 + 2) * (320 + 2) * 4) / 1024,
     }
-
-    # fleet motion interpret-pass cut: full-height tiles collapse the
-    # (pairs, row-tiles) grid to (pairs, 1) — H/32x fewer interpreter
-    # passes per pallas_call, bit-identical scores.  Interpret-mode only:
-    # on compiled backends tile_rows=None resolves back to the same 32-row
-    # program and the "comparison" would time one executable twice.
-    if kernels.pallas_interpret():
-        cams = jnp.asarray(rng.uniform(0, 1, (4, 5, 96, 160))
-                           .astype(np.float32))
-        t_banded = _time(lambda f: em.segment_motion_fleet(f, tile_rows=32),
-                         cams)
-        t_full = _time(lambda f: em.segment_motion_fleet(f, tile_rows=None),
-                       cams)
-        fa = em.segment_motion_fleet(cams, tile_rows=32)
-        fb = em.segment_motion_fleet(cams, tile_rows=None)
-        out["edge_motion_fleet_interpret"] = {
-            "banded32_ms": t_banded,
-            "full_height_ms": t_full,
-            "passes_cut_speedup": t_banded / t_full,
-            "max_err": float(jnp.max(jnp.abs(fa - fb))),
-        }
 
     # knapsack_dp
     from repro.kernels.knapsack_dp import ops as dp
@@ -96,15 +74,7 @@ def run(quick: bool = False) -> dict:
             print(f"  {k_:14s} oracle={v_['oracle_ms']:.2f}ms "
                   f"err={v_['kernel_max_err']:.2e} "
                   f"vmem~{list(v_.values())[2]:.0f}KB")
-    fi = out.get("edge_motion_fleet_interpret")
-    if fi:
-        print(f"  fleet motion interpret passes: "
-              f"banded(32)={fi['banded32_ms']:.2f}ms"
-              f" -> full-height={fi['full_height_ms']:.2f}ms "
-              f"({fi['passes_cut_speedup']:.2f}x, err={fi['max_err']:.1e})")
     worst = max(v_["kernel_max_err"] for v_ in out.values()
                 if "kernel_max_err" in v_)
     headline = f"worst kernel err {worst:.2e}"
-    if fi:
-        headline += f"; fleet motion interpret {fi['passes_cut_speedup']:.2f}x"
     return {**out, "headline": headline}

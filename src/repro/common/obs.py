@@ -17,6 +17,9 @@ on backends where the transfer guard never fires.  ``count_put(kind)``
 counts host-to-device puts per category (``H2D_CATEGORIES``) and
 ``count_operand_cache(kind)`` the episode operand cache's builds and reuses
 (``OPERAND_CACHE_EVENTS``); ``StreamingFleetRunner.stats()`` reports both.
+``record_kernel_grid(kernel, grid, block, tile)`` records, when a fleet
+kernel's wrapper traces, the grid, input block and in-kernel tile it chose
+(``kernel_grid_counts()``).
 """
 from __future__ import annotations
 
@@ -112,3 +115,26 @@ count_operand_cache = _operand_cache.add
 def operand_cache_counts() -> Dict[str, int]:
     """Snapshot of the episode operand cache's builds and reuses."""
     return _operand_cache.snapshot()
+
+
+# Fleet kernel tilings as traced: kernel -> {(grid, input block, tile):
+# traces}.  The tile is the (rows, columns) one step of the kernel body
+# works on, halo included.
+Tiling = Tuple[Tuple[int, ...], Tuple[int, ...], Tuple[int, ...]]
+_kernel_grids: Dict[str, Dict[Tiling, int]] = {}
+
+
+def record_kernel_grid(kernel: str, grid: Tuple[int, ...],
+                       block: Tuple[int, ...], tile: Tuple[int, ...]
+                       ) -> None:
+    """Count one trace of ``kernel`` launched over ``grid`` with input
+    blocks of shape ``block``, its body working on ``tile``s."""
+    seen = _kernel_grids.setdefault(kernel, {})
+    key = tuple(tuple(int(d) for d in x) for x in (grid, block, tile))
+    seen[key] = seen.get(key, 0) + 1
+
+
+def kernel_grid_counts() -> Dict[str, Dict[Tiling, int]]:
+    """Snapshot of every (grid, input block, tile) each fleet kernel was
+    traced with, and how many times."""
+    return {k: dict(v) for k, v in _kernel_grids.items()}

@@ -7,9 +7,9 @@ min-label propagation** (each active cell takes the min label of its
 4-neighbourhood until fixpoint, O(component diameter) sweeps, all-vector
 ops).  Outputs are identical components; DESIGN.md records the divergence.
 
-The grid is small (H/bs x W/bs, e.g. 68x120 for 1080p @ 16px blocks) so the
-whole thing lives in registers/VMEM and box extraction is a segment-min/max
-over at most M*N segments.
+The grid is small (H/bs x W/bs: 12x20 at 96x160, 135x240 at 1080p with
+8-px blocks) and box extraction is a segment-min/max over at most M*N
+segments.
 """
 from __future__ import annotations
 
@@ -25,6 +25,37 @@ import numpy as np
 # program is lowered — a device-to-host transfer the episode's transfer
 # guard rejects on a TPU
 INF = np.int32(2 ** 30)
+
+
+def _sweep(mask: jax.Array, labels: jax.Array) -> jax.Array:
+    """One propagation sweep: each active cell takes the min label of itself
+    and its 4-neighbourhood."""
+    p = jnp.pad(labels, 1, constant_values=INF)
+    neigh = jnp.minimum(
+        jnp.minimum(p[:-2, 1:-1], p[2:, 1:-1]),
+        jnp.minimum(p[1:-1, :-2], p[1:-1, 2:]))
+    return jnp.where(mask, jnp.minimum(labels, neigh), INF)
+
+
+def _fixpoint(mask: jax.Array, labels: jax.Array, bounded: bool
+              ) -> jax.Array:
+    """Sweeps until no label changes (at most M*N of them)."""
+    M, N = mask.shape
+    if bounded:
+        return jax.lax.fori_loop(0, M * N, lambda _, l: _sweep(mask, l),
+                                 _sweep(mask, labels))
+
+    def cond(state):
+        labels, prev, it = state
+        return jnp.logical_and(jnp.any(labels != prev), it < M * N)
+
+    def body(state):
+        labels, _, it = state
+        return _sweep(mask, labels), labels, it + 1
+
+    labels, _, _ = jax.lax.while_loop(
+        cond, body, (_sweep(mask, labels), labels, jnp.int32(0)))
+    return labels
 
 
 @functools.partial(jax.jit, static_argnames=("max_boxes", "bounded"))
@@ -44,29 +75,7 @@ def label_and_boxes(mask: jax.Array, max_boxes: int = 16,
     cleanly.  Keep it off on hot paths."""
     M, N = mask.shape
     idx = jnp.arange(M * N, dtype=jnp.int32).reshape(M, N)
-    labels = jnp.where(mask, idx, INF)
-
-    def propagate(labels):
-        p = jnp.pad(labels, 1, constant_values=INF)
-        neigh = jnp.minimum(
-            jnp.minimum(p[:-2, 1:-1], p[2:, 1:-1]),
-            jnp.minimum(p[1:-1, :-2], p[1:-1, 2:]))
-        return jnp.where(mask, jnp.minimum(labels, neigh), INF)
-
-    if bounded:
-        labels = jax.lax.fori_loop(0, M * N, lambda _, l: propagate(l),
-                                   propagate(labels))
-    else:
-        def cond(state):
-            labels, prev, it = state
-            return jnp.logical_and(jnp.any(labels != prev), it < M * N)
-
-        def body(state):
-            labels, _, it = state
-            return propagate(labels), labels, it + 1
-
-        labels, _, _ = jax.lax.while_loop(
-            cond, body, (propagate(labels), labels, jnp.int32(0)))
+    labels = _fixpoint(mask, jnp.where(mask, idx, INF), bounded)
 
     # box extraction: segment min/max of row/col per root label
     flat = labels.reshape(-1)

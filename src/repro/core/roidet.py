@@ -68,24 +68,20 @@ def _boxes_to_mask(boxes: jax.Array, valid: jax.Array, M: int, N: int,
                              jnp.zeros((M, N), bool))
 
 
-def _roi_union(D: jax.Array, dboxes: jax.Array, dvalid: jax.Array, M: int,
-               N: int, block_size: int, max_boxes: int,
-               bounded_cc: bool = False):
-    """One camera's ROI tail (Alg.1 l.11-12), shared by the single-camera and
-    fleet paths: connected components of the motion matrix, union with the
-    detector boxes, one-block dilation (box-boundary pixels carry the
-    object's edges — without the halo, cropped encodes clip object borders
-    and detection recall drops at high bitrates).
-    Returns (mask, area_ratio, motion_boxes, motion_valid)."""
-    mboxes, mvalid, _ = cc.label_and_boxes(D, max_boxes=max_boxes,
-                                           bounded=bounded_cc)
+def _roi_mask(mboxes: jax.Array, mvalid: jax.Array, dboxes: jax.Array,
+              dvalid: jax.Array, M: int, N: int, block_size: int):
+    """One camera's ROI from its motion components' boxes and the detector
+    boxes (Alg.1 l.12): their union on the block grid, dilated by one block
+    (box-boundary pixels carry the object's edges — without the halo,
+    cropped encodes clip object borders and detection recall drops at high
+    bitrates).  Returns (mask, area_ratio)."""
     motion_mask = _boxes_to_mask(mboxes, mvalid, M, N, scale=1.0)
     det_mask = _boxes_to_mask(dboxes, dvalid, M, N, scale=1.0 / block_size)
     mask = motion_mask | det_mask
     p = jnp.pad(mask, 1)
     mask = (p[1:-1, 1:-1] | p[:-2, 1:-1] | p[2:, 1:-1]
             | p[1:-1, :-2] | p[1:-1, 2:])
-    return mask, jnp.mean(mask.astype(jnp.float32)), mboxes, mvalid
+    return mask, jnp.mean(mask.astype(jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -119,8 +115,9 @@ def roidet(frames: jax.Array, det_params: Any, *, block_size: int = 8,
     D = jnp.any(scores > motion_thresh, axis=0)             # (M, N) bool
 
     # ---- connected components + union ROI (Alg.1 l.11-12)
-    mask, area, mboxes, mvalid = _roi_union(D, dboxes, dvalid, M, N,
-                                            block_size, max_boxes)
+    with jax.named_scope("cc"):
+        mboxes, mvalid, _ = cc.label_and_boxes(D, max_boxes=max_boxes)
+    mask, area = _roi_mask(mboxes, mvalid, dboxes, dvalid, M, N, block_size)
     return ROIResult(mask=mask, area_ratio=area, confidence=conf,
                      motion_boxes=mboxes, motion_valid=mvalid,
                      det_boxes=dboxes, det_valid=dvalid)
@@ -150,11 +147,14 @@ def _roidet_fleet_impl(frames: jax.Array, det_params: Any, *, block_size: int,
                                          use_kernel=use_kernel)  # (C,N-1,M,N)
     D = jnp.any(scores > motion_thresh, axis=1)                  # (C,M,N)
 
-    mask, area, mboxes, mvalid = jax.vmap(
-        lambda D_i, db_i, dv_i: _roi_union(D_i, db_i, dv_i, M, N,
-                                           block_size, max_boxes,
-                                           bounded_cc=bounded_cc)
-    )(D, dboxes, dvalid)
+    # ---- connected components (scope ``cc``, opened outside the camera
+    # vmap so the profile names it) + union ROI (Alg.1 l.11-12)
+    with jax.named_scope("cc"):
+        mboxes, mvalid, _ = jax.vmap(functools.partial(
+            cc.label_and_boxes, max_boxes=max_boxes, bounded=bounded_cc))(D)
+    mask, area = jax.vmap(
+        lambda mb, mv, db, dv: _roi_mask(mb, mv, db, dv, M, N, block_size)
+    )(mboxes, mvalid, dboxes, dvalid)
     return ROIResult(mask=mask, area_ratio=area, confidence=conf,
                      motion_boxes=mboxes, motion_valid=mvalid,
                      det_boxes=dboxes, det_valid=dvalid)

@@ -154,6 +154,7 @@ def test_flash_decode_with_new_token(rng):
 # tx_codec (fused transmission/codec kernel)
 # ---------------------------------------------------------------------------
 
+from repro.core import codec as codec_mod
 from repro.core.codec import CodecConfig
 from repro.kernels.tx_codec import ops as tx_ops
 from repro.kernels.tx_codec import ref as tx_ref
@@ -265,3 +266,74 @@ def test_tx_codec_oracle_is_scalar_codec(rng):
                                           res[i], keys[i])
         np.testing.assert_array_equal(np.asarray(dr[i]), np.asarray(d1))
         np.testing.assert_array_equal(np.asarray(sr[i]), np.asarray(s1))
+
+
+# ---------------------------------------------------------------------------
+# frame tiling: row bands and lane tiles, at a height 32 does not divide
+# ---------------------------------------------------------------------------
+
+from repro import kernels
+from repro.common import obs
+from repro.kernels.tx_codec import tx_codec as tx_kernel
+
+# 72 rows: three bands of 24; 256 columns: two lane tiles of 128
+_TILED_H, _TILED_W = 72, 256
+
+
+def _new_tilings(kernel, before):
+    """The (grid, input block, tile)s ``kernel`` was traced with since
+    ``before`` (an ``obs.kernel_grid_counts()`` snapshot)."""
+    seen = before.get(kernel, {})
+    return {k for k, n in obs.kernel_grid_counts().get(kernel, {}).items()
+            if n > seen.get(k, 0)}
+
+
+def test_default_tiling_at_cityflow_size():
+    assert em_ops.band_rows(1080, 8) == 120 and em_ops.lane_cols(1920, 8) == 128
+    assert em_ops.band_rows(96, 8) == 32 and em_ops.lane_cols(160, 8) == 160
+    assert em_ops.band_rows(_TILED_H, 8) == 24
+    assert em_ops.lane_cols(_TILED_W, 8) == 128
+    res = CodecConfig().resolutions
+    assert tx_kernel.tiling(10, 1080, 1920, res) == (1, 120, 128)
+    # the 96x160 segment stays one block per camera
+    assert tx_kernel.tiling(10, 96, 160, res) == (10, 96, 160)
+
+
+def test_edge_motion_bands_and_lane_tiles_match_oracle(rng):
+    C, N = 2, 4
+    frames = jnp.asarray(
+        rng.uniform(0, 1, (C, N, _TILED_H, _TILED_W)).astype(np.float32))
+    before = obs.kernel_grid_counts()
+    got = em_ops.segment_motion_fleet(frames, block_size=8)
+    want = jax.vmap(lambda f: em_ref.segment_motion_ref(f, block_size=8))(
+        frames)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # (pairs, bands) grid; whole-width haloed bands in, 24+2 x 128+2 tiles
+    assert _new_tilings("edge_motion", before) == {
+        ((C * (N - 1), 3), (1, 1, 26, _TILED_W + 2), (26, 130))}
+
+
+@pytest.mark.parametrize("res", [1.0, 0.75, 0.5, 0.25])
+def test_tx_codec_tiles_are_the_frame_blur(res, rng, monkeypatch):
+    """Bands of 24 rows and lane tiles of 128 columns blur exactly as
+    ``codec._resolution_blur`` blurs the whole frame: 2**40 quantization
+    levels and no noise leave the blurred value as it is (frames >= 0.25
+    times 2**40 are whole numbers), so the output is the blur, bitwise.
+    The block budget is cut to one 24-row band of one frame, so the small
+    frame is tiled as a 1080p one is."""
+    C, N = 2, 3
+    resolutions = (1.0, 0.75, 0.5, 0.25)
+    monkeypatch.setattr(tx_kernel, "BLOCK_BYTES", 24 * _TILED_W * 4)
+    assert tx_kernel.tiling(N, _TILED_H, _TILED_W, resolutions) \
+        == (1, 24, 128)
+    frames = jnp.asarray(rng.uniform(
+        0.25, 1.0, (C, N, _TILED_H, _TILED_W)).astype(np.float32))
+    before = obs.kernel_grid_counts()
+    out = tx_kernel.tx_codec_pallas(
+        frames, jnp.zeros_like(frames), jnp.full((C,), 2.0 ** 40),
+        jnp.zeros((C,)), jnp.full((C,), resolutions.index(res), jnp.int32),
+        resolutions=resolutions, interpret=kernels.pallas_interpret())
+    want = jax.vmap(lambda f: codec_mod._resolution_blur(f, res))(frames)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    assert _new_tilings("tx_codec", before) == {
+        ((C, N, 3), (1, 1, 24, _TILED_W), (24, 128))}

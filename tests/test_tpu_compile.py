@@ -111,3 +111,24 @@ def test_episode_b8_compiles(one_chip, compiled_mode, monkeypatch, method):
              if p.name == f"episode/{method}/b8")
     compiled = prog.fn.lower(*_on(one_chip, prog.abs_args)).compile()
     _assert_kernel_inside(compiled)
+
+
+# CityFlow S01 at its published frame size: 1080 rows (not a multiple of
+# 32) and 1920 columns, in row bands and lane tiles
+HD = (5, 10, 1080, 1920)
+
+
+def test_edge_motion_compiles_at_1080p(one_chip, compiled_mode):
+    frames = _on(one_chip, programs._sds(HD, jnp.float32))
+    fn = jax.jit(lambda f: em_ops.segment_motion_fleet(f, block_size=8))
+    _assert_kernel_inside(fn.lower(frames).compile())
+
+
+def test_tx_codec_compiles_at_1080p(one_chip, compiled_mode):
+    f32 = jnp.float32
+    C = HD[0]
+    args = _on(one_chip, (programs._sds(HD, f32), programs._sds((C,), f32),
+                          programs._sds((C,), f32), programs._sds((C,), f32),
+                          programs._sds((C, 2), jnp.uint32)))
+    compiled = tx_ops.encode_fleet.lower(CodecConfig(), *args).compile()
+    _assert_kernel_inside(compiled)
