@@ -19,7 +19,9 @@ counts host-to-device puts per category (``H2D_CATEGORIES``) and
 (``OPERAND_CACHE_EVENTS``); ``StreamingFleetRunner.stats()`` reports both.
 ``record_kernel_grid(kernel, grid, block, tile)`` records, when a fleet
 kernel's wrapper traces, the grid, input block and in-kernel tile it chose
-(``kernel_grid_counts()``).
+(``kernel_grid_counts()``), and ``record_detector_stem(path, fold, shape)``
+which path the detector's stem traced on which frames
+(``detector_stem_counts()``).
 """
 from __future__ import annotations
 
@@ -138,3 +140,24 @@ def kernel_grid_counts() -> Dict[str, Dict[Tiling, int]]:
     """Snapshot of every (grid, input block, tile) each fleet kernel was
     traced with, and how many times."""
     return {k: dict(v) for k, v in _kernel_grids.items()}
+
+
+# Detector stem paths as traced: (path, fold, frames shape) -> traces.  The
+# path is 'folded' (the stem on frames folded fold x fold into channels) or
+# 'plain' (fold 1).
+Stem = Tuple[str, int, Tuple[int, ...]]
+_detector_stems: Dict[Stem, int] = {}
+
+
+def record_detector_stem(path: str, fold: int, shape: Tuple[int, ...]
+                         ) -> None:
+    """Count one trace of the detector stem on ``path`` over frames of
+    ``shape``."""
+    key = (path, int(fold), tuple(int(d) for d in shape))
+    _detector_stems[key] = _detector_stems.get(key, 0) + 1
+
+
+def detector_stem_counts() -> Dict[Stem, int]:
+    """Snapshot of every (path, fold, frames shape) the detector stem was
+    traced with, and how many times."""
+    return dict(_detector_stems)

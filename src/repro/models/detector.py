@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common import obs
 from repro.common.params import ParamDef, init_params
 
 STRIDE = 16
@@ -49,13 +50,83 @@ def _conv(x, w, b, stride=2):
     return jax.nn.relu(y + b)
 
 
+# The stem: the STEM_CONVS = log2(STEM_FOLD) stride-2 convs that open the
+# detector.  On frames whose sides the fold divides, each fold x fold block of
+# pixels becomes fold*fold channels and the stem's convs run stride-1 on
+# that grid, so they read and write lane-dense activations instead of
+# 1-32 channels; the stem's last conv gives the plain activations back.
+# On a TPU v5e the folded stem is the slower one at 96x160 and the faster
+# from 192x320 up (the size sweep in PERF.md section 6), so frames below
+# STEM_MIN_PIXELS keep the plain stem.
+STEM_FOLD = 8
+STEM_CONVS = 3
+STEM_MIN_PIXELS = 192 * 320
+
+
+def stem_fold(shape: Tuple[int, ...]) -> int:
+    """The fold the stem runs with on frames of ``shape`` (B, H, W): 1 (the
+    plain convs) unless both sides are multiples of ``STEM_FOLD`` and a
+    frame holds at least ``STEM_MIN_PIXELS``."""
+    H, W = shape[-2:]
+    if H % STEM_FOLD or W % STEM_FOLD or H * W < STEM_MIN_PIXELS:
+        return 1
+    return STEM_FOLD
+
+
+def _fold(x: jax.Array, f: int) -> jax.Array:
+    """(B, H, W, C) -> (B, H/f, W/f, f*f*C): each f x f block as channels,
+    in (row, column, channel) order."""
+    B, H, W, C = x.shape
+    x = x.reshape(B, H // f, f, W // f, f, C)
+    return x.transpose(0, 1, 3, 2, 4, 5).reshape(B, H // f, W // f, f * f * C)
+
+
+def _folded_kernel(w: jax.Array, fold_in: int) -> jax.Array:
+    """3x3 HWIO weights of a stride-2 SAME conv -> the 2x2 stride-1 HWIO
+    kernel of the same conv on its input folded by ``fold_in``, giving its
+    output folded by ``fold_in // 2``.
+
+    Output phase q of a folded cell reads input phases fold_in*a + p - 2q
+    (a the cell tap, p the input phase) of the 3x3 kernel; every weight
+    lands in one place per output phase, every other entry is an exact
+    zero."""
+    fo = fold_in // 2
+    r = (fold_in * np.arange(2)[:, None, None] + np.arange(fold_in)[:, None]
+         - 2 * np.arange(fo))                              # (tap, p, q)
+    r = np.where((r >= 0) & (r <= 2), r, 3)                # 3: a zero row
+    wz = jnp.pad(w, ((0, 1), (0, 1), (0, 0), (0, 0)))
+    k = wz[r[:, None, :, None, :, None], r[None, :, None, :, None, :]]
+    ci, co = w.shape[2:]
+    # (ty, tx, py, px, qy, qx, ci, co) -> HWIO: (py, px, ci), (qy, qx, co)
+    return k.transpose(0, 1, 2, 3, 6, 4, 5, 7).reshape(
+        2, 2, fold_in * fold_in * ci, fo * fo * co)
+
+
+def _stem(params, frames: jax.Array) -> jax.Array:
+    """The stem on (B, H, W) frames -> the plain output of its last conv."""
+    f = stem_fold(frames.shape)
+    obs.record_detector_stem("folded" if f > 1 else "plain", f, frames.shape)
+    x = frames[..., None]
+    if f == 1:
+        for i in range(1, STEM_CONVS + 1):
+            x = _conv(x, params[f"c{i}"], params[f"b{i}"])
+        return x
+    x = _fold(x, f)
+    for i in range(1, STEM_CONVS + 1):
+        y = jax.lax.conv_general_dilated(
+            x, _folded_kernel(params[f"c{i}"], f), (1, 1), ((0, 1), (0, 1)),
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        f //= 2
+        x = jax.nn.relu(y + jnp.tile(params[f"b{i}"], f * f))
+    return x
+
+
 def forward(params, frames: jax.Array) -> jax.Array:
     """frames: (B, H, W) in [0,1] -> raw grid (B, H/16, W/16, 5)."""
-    x = frames[..., None]
-    x = _conv(x, params["c1"], params["b1"])
-    x = _conv(x, params["c2"], params["b2"])
-    x = _conv(x, params["c3"], params["b3"])
-    x = _conv(x, params["c4"], params["b4"])
+    with jax.named_scope("stem"):
+        x = _stem(params, frames)
+    for i in range(STEM_CONVS + 1, 5):
+        x = _conv(x, params[f"c{i}"], params[f"b{i}"])
     y = jax.lax.conv_general_dilated(
         x, params["head"], (1, 1), "SAME",
         dimension_numbers=("NHWC", "HWIO", "NHWC")) + params["bh"]
