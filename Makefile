@@ -1,12 +1,7 @@
-# Tier-1 tests + quick perf smoke — run `make ci` per PR so batched-path
-# regressions (correctness or slot-step latency) are caught early.
-# `ci-sharded` replays the tier-1 suite + the quick latency bench under 8
-# fake XLA host devices, exercising the camera-mesh shard_map fleet paths.
-# `ci-guard` is the transfer-guard lane: the device-resident control loop's
-# timed slot loop runs under jax.transfer_guard_device_to_host("disallow")
-# (apart from the scoped per-slot log harvest) on the 8-device mesh, and the
-# D2H fetch counters prove zero per-slot control syncs on the CPU backend,
-# where the guard itself is zero-copy-inert.
+# Tier-1 tests + quick allocator smoke — run `make ci` per PR so
+# regressions (correctness or allocation optimality) are caught early.
+# `ci-sharded` replays the tier-1 suite under 8 fake XLA host devices,
+# exercising the camera-mesh shard_map fleet paths.
 # `ci-episode` is the whole-trace lane: episode runs execute under
 # jax.transfer_guard("disallow") in BOTH directions with NO scoped per-slot
 # exemptions (the guard wraps the entire timed episode inside
@@ -18,10 +13,11 @@
 # harness, trace-length bucketing, golden logs) on the 8-device mesh in the
 # harness's quick mode (reduced family set).
 # `ci-faults` is the fault-tolerance lane: traced camera-churn/link-fault
-# episodes (the dead==absent differential across all methods and runner
-# modes), the checkify-guarded diagnostics runs (SystemConfig.checked on,
-# invariant violations must raise), and the watchdog/supervisor recovery
-# ladder.  Runs WITHOUT fake devices: the checked lane forces shard off.
+# episodes (the dead==absent differential across all methods, runner
+# modes and the stream runner), the checkify-guarded diagnostics runs
+# (SystemConfig.checked on, invariant violations must raise), and the
+# watchdog unit tests.  Runs WITHOUT fake devices: the checked lane forces
+# shard off.
 # `ci-serve` is the continuous-serving lane: the windowed stream runner's
 # kill-and-resume differential (SIGTERM + injected exception, all methods,
 # zero recompiles after restore), the SLO watchdog ladder, checkpoint
@@ -58,21 +54,17 @@
 # shell out to it instead of duplicating test lists here.
 PY := PYTHONPATH=src python
 
-.PHONY: test bench-quick ci ci-sharded ci-guard ci-episode ci-scenarios \
+.PHONY: test bench-quick ci ci-sharded ci-episode ci-scenarios \
 	ci-faults ci-serve ci-audit ci-chaos ci-pipeline
 
 test:
 	$(PY) -m pytest -q
 
 bench-quick:
-	$(PY) -m benchmarks.run --quick --only bench_allocation bench_latency
+	$(PY) -m benchmarks.run --quick --only bench_allocation
 
 ci-sharded:
 	REPRO_FAKE_DEVICES=8 $(PY) -m pytest -q
-	REPRO_FAKE_DEVICES=8 $(PY) -m benchmarks.run --quick --only bench_latency
-
-ci-guard:
-	REPRO_FAKE_DEVICES=8 $(PY) -m pytest -q tests/test_control_device.py
 
 ci-episode:
 	REPRO_FAKE_DEVICES=8 $(PY) tests/harness.py --lane episode
@@ -98,5 +90,5 @@ ci-audit:
 ci-chaos:
 	REPRO_CHAOS_HEADLINE_SLOTS=1000 $(PY) tests/harness.py --lane chaos
 
-ci: test bench-quick ci-sharded ci-guard ci-episode ci-scenarios ci-faults \
+ci: test bench-quick ci-sharded ci-episode ci-scenarios ci-faults \
 	ci-serve ci-audit ci-chaos ci-pipeline

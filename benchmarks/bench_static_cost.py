@@ -1,7 +1,7 @@
 """Static per-executable cost table for the (method x bucket) matrix.
 
-NOT a timing bench: nothing executes.  Every audited episode/slot-step/
-control program (``repro.analysis.programs``) is lowered and compiled
+NOT a timing bench: nothing executes.  Every audited episode/slot-step
+program (``repro.analysis.programs``) is lowered and compiled
 once, and the table reports XLA's static ``cost_analysis()`` flops /
 bytes-accessed and ``memory_analysis()`` peak estimate per executable —
 the compile-time view of how episode cost scales with trace bucket and
@@ -20,7 +20,7 @@ method.  Cross-checks:
 A ``trajectory`` entry lands in ``artifacts/bench/BENCH_trajectory.json``
 so per-PR growth of episode flops/bytes/peak is visible next to the
 measured ms/slot trajectory.  Quick mode keeps only the bucket-8 episode
-row per method (plus slot-step + ctrl), full mode compiles all 21.
+row per method (plus the slot-step), full mode compiles all 13.
 """
 from __future__ import annotations
 

@@ -5,7 +5,7 @@
 Prints ``name,us_per_call,derived`` CSV rows plus per-benchmark result tables,
 and writes JSON artifacts to ``artifacts/bench/``.  Each artifact records the
 execution environment (host device count, platform, fake-device override,
-fleet sharding/donation modes) so sharded and single-device runs are
+fleet sharding and run mode) so sharded and single-device runs are
 distinguishable after the fact.  Set ``REPRO_FAKE_DEVICES=8`` to fan the CPU
 host out into 8 XLA devices (the `make ci-sharded` lane).
 """
@@ -23,7 +23,6 @@ BENCHES = [
     "bench_roidet",       # Fig. 4 + Fig. 5
     "bench_allocation",   # section 5.2 optimality + scaling
     "bench_e2e_utility",  # Fig. 3
-    "bench_latency",      # Fig. 6
     "bench_kernels",      # kernel vs oracle timings
     "bench_serve",        # continuous-serving SLO (window p50/p99, slots/s)
     "bench_roofline",     # dry-run roofline table (reads artifacts/dryrun)
@@ -38,9 +37,7 @@ def _env_metadata() -> dict:
     Imported lazily so REPRO_FAKE_DEVICES can take effect first.
 
     ``system_defaults`` records the SystemConfig defaults a bench inherits
-    when it does not override them — benches that deliberately sweep modes
-    (bench_latency's sequential/batched/sharded comparison) record the
-    per-mode configs in their own result dict."""
+    when it does not override them."""
     import jax
     from repro.core.scheduler import SystemConfig
     cfg = SystemConfig()
@@ -49,10 +46,8 @@ def _env_metadata() -> dict:
         "device_count": jax.device_count(),   # what actually ran
         "platform": jax.default_backend(),
         "requested_fake_devices": int(fake) if fake else None,
-        "system_defaults": {"shard": cfg.shard, "donate": cfg.donate,
-                            "pipeline": cfg.pipeline,
-                            "batched": cfg.batched,
-                            "alloc": cfg.alloc},
+        "system_defaults": {"shard": cfg.shard, "batched": cfg.batched,
+                            "episode": cfg.episode},
     }
 
 
@@ -102,8 +97,8 @@ def main() -> None:
 
 def _append_trajectory(entry: dict, env_meta: dict, quick: bool) -> None:
     """Append a perf-trajectory datapoint to BENCH_trajectory.json — the
-    committed per-PR record of the headline comparisons (episode vs
-    pipelined ms/slot), so regressions are visible across PRs.  Quick runs
+    committed per-PR record of the headline numbers (serving SLOs, static
+    executable cost), so regressions are visible across PRs.  Quick runs
     are stamped ``quick=True`` (fewer slots/reps — not comparable to full
     datapoints)."""
     path = ART / "BENCH_trajectory.json"

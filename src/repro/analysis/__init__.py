@@ -21,8 +21,8 @@ Three passes
       primitives (the static form of the runtime transfer guard);
     * **donation** — the unified slot-step's lowering marks exactly the
       frames/gt_boxes/gt_valid argument leaves as donated
-      (``lowered.args_info``), and episode/control programs donate
-      nothing (their carries are reused across windows);
+      (``lowered.args_info``), and episode programs donate nothing
+      (their carries are reused across windows);
     * **two-harvest** — each episode jaxpr emits exactly TWO
       slot-stacked outputs (the (T, 2, C) log pack + the (T, 4) control
       pack): the "exactly 2 harvest fetches per run, slot-count

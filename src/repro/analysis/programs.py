@@ -15,9 +15,8 @@ The registry enumerates:
   (method, trace-length bucket): exactly ``len(METHODS) x
   len(fleet.EPISODE_BUCKETS)`` entries, the matrix whose recompile-free
   serving the harness asserts at runtime;
-* ``slot_step/unified`` — the donated unified fleet slot-step;
-* ``ctrl/<method>`` / ``ctrl_scan/<method>`` — the per-slot and
-  scanned control programs.
+* ``slot_step/unified`` — the donated unified fleet slot-step the
+  host-control reference loop dispatches.
 """
 from __future__ import annotations
 
@@ -36,9 +35,6 @@ W_CAP_KBPS = 8000.0
 # harness systems score 3 frames per segment (tests/harness.py build_system)
 EVAL_FRAMES = 3
 
-CTRL_SCAN_T = 8          # trace length for the scanned control program
-
-
 @dataclasses.dataclass(frozen=True)
 class Program:
     """One audited executable: the cached jitted callable plus the
@@ -47,7 +43,7 @@ class Program:
     report); ``timed`` marks programs whose body runs inside a
     transfer-guarded timed region (the no-host-callback rules apply)."""
     name: str
-    kind: str                      # "episode" | "slot_step" | "ctrl" | "ctrl_scan"
+    kind: str                      # "episode" | "slot_step"
     fn: Callable
     abs_args: Tuple[Any, ...]
     donated: Tuple[int, ...] = ()
@@ -164,41 +160,6 @@ class Canonical:
             _sds((C, N, G, 4), f32), _sds((C, N, G), b),      # gt boxes/valid
             _sds((C,), b))                                    # live
 
-    def ctrl_statics(self, method: str) -> Dict[str, Any]:
-        return dict(
-            method=method, ecfg=self.ecfg, bitrates=self.bitrates,
-            resolutions=self.resolutions,
-            slot_seconds=float(self.ccfg.slot_seconds),
-            use_elastic=method == "deepstream", use_kernel=True,
-            w_cap=int(self.w_cap), num_cams=self.C, checked=False)
-
-    def ctrl_args(self, method: str) -> Tuple[Any, ...]:
-        f32, b = self._f32, self._bool
-        C = self.C
-        deep = method == "deepstream"
-        ac = _sds((C,), f32) if deep else None
-        return (
-            self.mlp if deep else None,
-            _sds((C, self.J), f32), _sds((C, self.J), f32),
-            _sds((C,), f32),                                  # lam
-            ac, ac,                                           # a, c
-            _sds((), f32),                                    # W_t
-            self.est0, _sds((), f32), _sds((), f32),          # est, taus
-            _sds((C,), b), _sds((), b))                       # live, reconnect
-
-    def ctrl_scan_args(self, method: str) -> Tuple[Any, ...]:
-        f32, b = self._f32, self._bool
-        C, T = self.C, CTRL_SCAN_T
-        deep = method == "deepstream"
-        return (
-            self.mlp if deep else None,
-            _sds((C, self.J), f32), _sds((C, self.J), f32),
-            _sds((C,), f32),
-            _sds((T, C), f32), _sds((T, C), f32),             # a/c traces
-            _sds((T,), f32),                                  # W trace
-            self.est0, _sds((), f32), _sds((), f32),
-            _sds((T, C), b), _sds((T,), b))                   # live/reconnect
-
 
 def _donated_leaf_indices(abs_args: Sequence[Any],
                           donate_argnums: Sequence[int]) -> Tuple[int, ...]:
@@ -249,22 +210,8 @@ def get_programs(kinds: Optional[Sequence[str]] = None,
         args = canon.slot_step_args()
         fn = fleet_mod._get_executable(
             None, canon.ccfg, EVAL_FRAMES, canon.block_size,
-            canon.conf_thresh, True, True, True, False)
+            canon.conf_thresh, True, True, False)
         progs.append(Program(
             name="slot_step/unified", kind="slot_step", fn=fn, abs_args=args,
             donated=_donated_leaf_indices(args, SLOT_STEP_DONATE_ARGNUMS)))
-    if take("ctrl"):
-        for method in METHODS:
-            fn = fleet_mod._get_control_executable(
-                "ctrl", **canon.ctrl_statics(method))
-            progs.append(Program(
-                name=f"ctrl/{method}", kind="ctrl", fn=fn,
-                abs_args=canon.ctrl_args(method)))
-    if take("ctrl_scan"):
-        for method in METHODS:
-            fn = fleet_mod._get_control_executable(
-                "ctrl_scan", **canon.ctrl_statics(method))
-            progs.append(Program(
-                name=f"ctrl_scan/{method}", kind="ctrl_scan", fn=fn,
-                abs_args=canon.ctrl_scan_args(method)))
     return tuple(progs)

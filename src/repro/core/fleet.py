@@ -32,35 +32,19 @@ Method routing is pure data, no Python branches in the hot loop:
 Device-resident control loop
 ----------------------------
 The server-side control loop (paper sections 5.2 + 5.3 — elastic adjustment,
-utility table, knapsack allocation) runs as ONE traced program per method
-(``fleet_control_step``): slot t's per-camera (b, r) assignment is computed
-on device from the fleet ROIDet's (a, c) feature vectors, a prefetched
-bandwidth-trace device array, and an ``ElasticStateJax`` of device scalars
-threaded slot to slot.  What runs on device: the elastic EMA/variance/debt
-update, the fused utility-MLP table, the knapsack sweep at ONE static
-bucketed capacity (``allocation.dp_capacity``) with a traced backtrack, the
-traced fair/static pick, and the (extra, area, alloc_kbps, feasible) log
-pack.  Reducto's keep-flag decision is traced too (``reducto_keep_step``:
-motion -> cross-slot-reference keep mask, consumed by ``keep_selection``
-INSIDE the slot-step), so in the pipelined loop the host only does segment
-generation + upload and the deferred per-slot log harvest — slot t's
-(F1, sizes) ``host_pack`` plus the (4,) control pack, fetched while slot
-t+1 is in flight.
-Transfer-guard guarantee: with ``SystemConfig.alloc="device"`` the timed
-slot loop runs clean under ``jax.transfer_guard_device_to_host("disallow")``
-apart from those explicitly-scoped harvest fetches — the per-slot (a, c)
-and keep-flag host syncs of the pre-episode paths are gone.  (On the CPU
-backend D2H is zero-copy and the guard never fires; there the checkable
-proof is ``scheduler.d2h_fetch_counts()``, through which every loop fetch
-is routed: device-alloc runs perform ZERO 'control' and ZERO 'keep'
-fetches.)
-The allocator runs on ONE device outside the camera mesh — the knapsack DP
-is a sequential cross-camera recurrence with nothing to shard — so
-camera-sharded (a, c) cross the shard boundary through
-``sharding.rules.unshard`` (one device-to-device gather) and GSPMD reshards
-the resulting (b, r) into the sharded slot-step.  ``fleet_control_scan`` is
-the lax.scan-over-slots variant: a whole short trace's control trajectory
-in one dispatch.
+utility table, knapsack allocation) is ONE traced step per method
+(``_control_impl``) that the episode scan body calls every slot: slot t's
+per-camera (b, r) assignment is computed on device from the fleet ROIDet's
+(a, c) feature vectors, the window's bandwidth trace and an
+``ElasticStateJax`` of device scalars threaded slot to slot.  What runs on
+device: the elastic EMA/variance/debt update, the fused utility-MLP table,
+the knapsack sweep at ONE static bucketed capacity
+(``allocation.dp_capacity``) with a traced backtrack, the traced
+fair/static pick, and the (extra, area, alloc_kbps, feasible) log pack.
+Reducto's keep-flag decision is traced too (``reducto_keep_step``: motion
+-> cross-slot-reference keep mask, consumed by ``keep_selection`` INSIDE
+the slot-step).  The independent reference for this control is the host
+numpy path of ``scheduler._slot_allocation``.
 
 Whole-trace episodes
 --------------------
@@ -69,7 +53,8 @@ executes as ONE compiled program per method — ``lax.scan`` over the trace
 of segment generation (``data.synthetic.segments_device``, a traced seeded
 generator: slot t's frames + padded GT are a pure function of (scene
 params, base key, t) via ``jax.random.fold_in``), fleet ROIDet, the control
-step, the traced reducto keep decision and the unified slot-step.  Carry:
+step (``_control_impl``), the traced reducto keep decision and the
+unified slot-step.  Carry:
 the codec PRNG key chain + ``ElasticStateJax`` + reducto's cross-slot
 reference frames.  Per-slot logs are STACKED on device — (T, 2, C) F1/size
 packs and (T, 4) control packs — and harvested with one fetch at episode
@@ -82,9 +67,9 @@ shard_map'd whole: per-camera stages run on camera shards, the control
 stage ``all_gather``s (a, c) and runs replicated with the pure-jnp DP (one
 redundant small sweep per device instead of N interpret-mode kernel
 emulations), and each device slices its cameras' (b, r) back out.  The
-pipelined ``run()`` is kept as the ``episode=False`` reference; over the
-same ``DeviceScene`` seeds both modes produce identical logs (the
-equivalence tests assert <= 1e-5; measured diff 0.0).
+host-control batched ``run()`` is kept as the ``episode=False`` reference;
+over the same ``DeviceScene`` seeds both modes produce the same logs (the
+equivalence tests assert <= 1e-5).
 
 Trace lengths are BUCKETED: T is part of the scan's shape, so
 ``fleet_episode`` pads every trace up to a power-of-two bucket
@@ -121,9 +106,10 @@ runner modes.  Slot 0's camera (or any one camera) must stay live per slot:
 the control step needs >= 1 live camera.
 
 ``checked=True`` (diagnostics lane, off by default) threads
-``jax.experimental.checkify`` user checks through the slot-step, control
-step and episode scan — finite logs, allocation <= capacity, keep-mask and
-liveness consistency, elastic debt in [0, budget] — and surfaces them via
+``jax.experimental.checkify`` user checks through the slot-step and the
+episode scan, control step included — finite logs, allocation <=
+capacity, keep-mask and liveness consistency, elastic debt in [0,
+budget] — and surfaces them via
 ``checkify.check``/``err.throw()`` AFTER the transfer-guarded region.
 Unchecked programs contain no checkify code at all (the flag is a trace
 static), so the default lane's overhead is structurally zero.
@@ -137,10 +123,10 @@ each device runs the identical per-camera program on its C/D-camera shard, so
 results are bit-stable vs the single-device path and multi-host scaling is a
 mesh-shape change.  C is padded up to a multiple of the device count
 (``sharding.rules.pad_cameras``) with inert cameras and sliced back off.
-The big per-slot buffers (frames, masks, GT) are donated
-(``donate_argnums``), so slot t's inputs are recycled into slot t+1's
-workspace instead of accumulating; callers keep results on device and fetch
-only ``host_pack``.  On CPU, validate with
+The big per-slot buffers (frames, GT) are donated (``donate_argnums``,
+pinned by ``analysis.programs.SLOT_STEP_DONATE_ARGNUMS``), so slot t's
+inputs are recycled into slot t+1's workspace instead of accumulating;
+callers keep results on device and fetch only ``host_pack``.  On CPU, validate with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 """
 from __future__ import annotations
@@ -169,12 +155,11 @@ from repro.data.synthetic import DeviceSceneParams, SceneConfig
 from repro.kernels.edge_motion import ops as em_ops
 from repro.models import detector as det
 from repro.sharding.rules import (cached_sharded_jit, mesh_cache_key,
-                                  pad_cameras, pad_leading,
-                                  reshard_replicated, sharded_jit, unshard)
+                                  pad_cameras, pad_leading, sharded_jit)
 
 # block-motion mass above which a frame counts as "changed" (reducto keep
-# rule) — shared by the sequential, pipelined-traced and episode paths,
-# which must stay bit-in-sync for the cross-mode equivalence guarantees
+# rule) — shared by the sequential, batched and episode paths, which must
+# stay bit-in-sync for the cross-mode equivalence guarantees
 MOTION_KEEP_THRESH = 25.0
 
 # default trace-length buckets for the episode runner: T is part of the
@@ -223,7 +208,7 @@ def bucket_len(T: int, buckets: Optional[Sequence[int]] = EPISODE_BUCKETS
 def _key_chain(key: jax.Array, n: int) -> Tuple[jax.Array, jax.Array]:
     """n sequential key splits in ONE dispatch.  Bit-identical to repeatedly
     calling ``key, k = jax.random.split(key)`` on the host, so the fleet
-    paths (pipelined loop AND episode scan) draw exactly the keys the
+    paths (batched loop AND episode scan) draw exactly the keys the
     per-camera loop would."""
     def step(k, _):
         k, sub = jax.random.split(k)
@@ -553,7 +538,7 @@ _COMPILE_COUNTS: Dict[Tuple, int] = {}
 
 def _build_executable(cache_key: Tuple, mesh: Optional[Mesh],
                       cfg: CodecConfig, eval_frames: int, block_size: int,
-                      conf_thresh: float, donate: bool, with_reuse: bool,
+                      conf_thresh: float, with_reuse: bool,
                       use_kernel: bool, checked: bool):
     impl = functools.partial(_slot_step, cfg, eval_frames=eval_frames,
                              block_size=block_size, conf_thresh=conf_thresh,
@@ -579,19 +564,18 @@ def _build_executable(cache_key: Tuple, mesh: Optional[Mesh],
     # (server_params, frames, masks, b, r, keys, keep, gt_boxes, gt_valid,
     # live) argument list.  masks stay undonated: callers hold the ROIDet
     # mask for the sequential-equivalence comparisons.
-    donate_argnums = (1, 7, 8) if donate else ()
-    return sharded_jit(counted, mesh, in_specs, out_specs, donate_argnums)
+    return sharded_jit(counted, mesh, in_specs, out_specs, (1, 7, 8))
 
 
 def _get_executable(mesh: Optional[Mesh], cfg: CodecConfig, eval_frames: int,
-                    block_size: int, conf_thresh: float, donate: bool,
-                    with_reuse: bool, use_kernel: bool, checked: bool):
+                    block_size: int, conf_thresh: float, with_reuse: bool,
+                    use_kernel: bool, checked: bool):
     key = (mesh_cache_key(mesh), cfg, eval_frames, block_size, conf_thresh,
-           donate, with_reuse, use_kernel, checked)
+           with_reuse, use_kernel, checked)
     fn = _EXEC_CACHE.get(key)
     if fn is None:
         fn = _EXEC_CACHE[key] = _build_executable(
-            key, mesh, cfg, eval_frames, block_size, conf_thresh, donate,
+            key, mesh, cfg, eval_frames, block_size, conf_thresh,
             with_reuse, use_kernel, checked)
     return fn
 
@@ -691,141 +675,12 @@ def _control_impl(mlp_params, jcab_util, jcab_res, lam, a, c, W_t, est,
     return ControlOut(b=b, r=r, est=est, pack=pack)
 
 
-_CTRL_COMPILE_COUNTS: Dict[Tuple, int] = {}
-
-
-def control_compile_count() -> int:
-    """Traced specializations of the control-step/scan executables (separate
-    from ``compile_count``: each method owns one small control program, so a
-    first run of a new method legitimately adds one)."""
-    return sum(_CTRL_COMPILE_COUNTS.values())
-
-
-def _get_control_executable(kind: str, **statics):
-    key = (kind,) + tuple(sorted(statics.items()))
-    fn = _EXEC_CACHE.get(key)
-    if fn is not None:
-        return fn
-    impl = functools.partial(_control_impl, **statics)
-    checked = statics.get("checked", False)
-    if kind == "ctrl_scan":
-        def scanned(mlp_params, jcab_util, jcab_res, lam, a_tr, c_tr, W_tr,
-                    est, tau_wl, tau_wh, live_tr, rec_tr):
-            _CTRL_COMPILE_COUNTS[key] = _CTRL_COMPILE_COUNTS.get(key, 0) + 1
-            def step(carry, xs):
-                a, c, W, lv, rc = xs
-                out = impl(mlp_params, jcab_util, jcab_res, lam, a, c, W,
-                           carry, tau_wl, tau_wh, lv, rc)
-                return out.est, (out.b, out.r, out.pack)
-            est_f, (b, r, packs) = jax.lax.scan(
-                step, est, (a_tr, c_tr, W_tr, live_tr, rec_tr))
-            return b, r, packs, est_f
-        fn = (jax.jit(checkify.checkify(scanned)) if checked
-              else jax.jit(scanned))
-    else:
-        def counted(*args):
-            _CTRL_COMPILE_COUNTS[key] = _CTRL_COMPILE_COUNTS.get(key, 0) + 1
-            return impl(*args)
-        fn = (jax.jit(checkify.checkify(counted)) if checked
-              else jax.jit(counted))
-    _EXEC_CACHE[key] = fn
-    return fn
-
-
-def fleet_control_step(method: str, mlp_params, jcab_util, jcab_res, lam,
-                       a, c, W_t, est: ElasticStateJax, tau_wl, tau_wh, *,
-                       ecfg: ElasticConfig, bitrates: Sequence[int],
-                       resolutions: Sequence[float], slot_seconds: float,
-                       use_elastic: bool, use_kernel: bool, w_cap: int,
-                       num_cams: int, mesh: Optional[Mesh] = None,
-                       live: Optional[jax.Array] = None, reconnect=None,
-                       checked: bool = False) -> ControlOut:
-    """Dispatch one slot of the device-resident control loop WITHOUT
-    blocking: slot t's (b, r) come back as device arrays ready to feed
-    ``fleet_slot_step``; callers fetch ``pack`` with the deferred log
-    harvest.  ``a``/``c`` may be None for content-agnostic methods.
-    ``live``/``reconnect`` are the slot's fault signals (None = all live,
-    no reconnect — numerically identical to the pre-fault program; they are
-    traced DATA, so faulty and fault-free slots share one executable).
-    Camera-sharded features are gathered onto one device at the shard
-    boundary (the allocator runs outside the camera mesh)."""
-    if a is not None:
-        a = unshard(a, mesh)
-        c = unshard(c, mesh)
-    if live is None:
-        live = jnp.ones((int(num_cams),), bool)
-    if reconnect is None:
-        reconnect = False
-    fn = _get_control_executable(
-        "ctrl", method=method, ecfg=ecfg, bitrates=tuple(bitrates),
-        resolutions=tuple(resolutions), slot_seconds=float(slot_seconds),
-        use_elastic=bool(use_elastic), use_kernel=bool(use_kernel),
-        w_cap=int(w_cap), num_cams=int(num_cams), checked=bool(checked))
-    out = fn(mlp_params, jcab_util, jcab_res, lam, a, c, W_t, est,
-             tau_wl, tau_wh, jnp.asarray(live, bool),
-             jnp.asarray(reconnect, bool))
-    if checked:
-        err, out = out
-        with jax.transfer_guard_device_to_host("allow"):
-            err.throw()
-    if mesh is not None:
-        # (b, r) feed the mesh-committed slot-step; est/pack stay put (est
-        # cycles back into the next control step, pack is harvest-only)
-        out = out._replace(b=reshard_replicated(out.b, mesh),
-                           r=reshard_replicated(out.r, mesh))
-    return out
-
-
-def fleet_control_scan(method: str, mlp_params, jcab_util, jcab_res, lam,
-                       a_trace, c_trace, W_trace, est: ElasticStateJax,
-                       tau_wl, tau_wh, *, ecfg: ElasticConfig,
-                       bitrates: Sequence[int],
-                       resolutions: Sequence[float], slot_seconds: float,
-                       use_elastic: bool, use_kernel: bool, w_cap: int,
-                       num_cams: int, live_trace: Optional[jax.Array] = None,
-                       reconnect_trace: Optional[jax.Array] = None,
-                       checked: bool = False
-                       ) -> Tuple[jax.Array, jax.Array, jax.Array,
-                                  ElasticStateJax]:
-    """``lax.scan``-over-slots variant for short traces: the WHOLE control
-    trajectory — (T, C) features + (T,) bandwidth trace -> (T, C) (b, r)
-    assignments, (T, 4) log packs and the final elastic state — in ONE
-    dispatch.  Slot-equivalent to T ``fleet_control_step`` calls; like the
-    step, ``a_trace``/``c_trace`` may be None for content-agnostic methods
-    (zeros are scanned in their place — those branches never read them).
-    ``live_trace`` (T, C) / ``reconnect_trace`` (T,) are the per-slot fault
-    signals (None = all live / no reconnects)."""
-    W_trace = jnp.asarray(W_trace, jnp.float32)
-    T = int(W_trace.shape[0])
-    if a_trace is None:
-        a_trace = c_trace = jnp.zeros((T, int(num_cams)), jnp.float32)
-    if live_trace is None:
-        live_trace = jnp.ones((T, int(num_cams)), bool)
-    if reconnect_trace is None:
-        reconnect_trace = jnp.zeros((T,), bool)
-    fn = _get_control_executable(
-        "ctrl_scan", method=method, ecfg=ecfg, bitrates=tuple(bitrates),
-        resolutions=tuple(resolutions), slot_seconds=float(slot_seconds),
-        use_elastic=bool(use_elastic), use_kernel=bool(use_kernel),
-        w_cap=int(w_cap), num_cams=int(num_cams), checked=bool(checked))
-    out = fn(mlp_params, jcab_util, jcab_res, lam,
-             jnp.asarray(a_trace, jnp.float32),
-             jnp.asarray(c_trace, jnp.float32), W_trace, est,
-             tau_wl, tau_wh, jnp.asarray(live_trace, bool),
-             jnp.asarray(reconnect_trace, bool))
-    if checked:
-        err, out = out
-        with jax.transfer_guard_device_to_host("allow"):
-            err.throw()
-    return out
-
-
 def fleet_slot_step(cfg: CodecConfig, server_params: Any, frames: jax.Array,
                     masks: jax.Array, b: jax.Array, r: jax.Array,
                     keys: jax.Array, keep: jax.Array, gt_boxes: jax.Array,
                     gt_valid: jax.Array, *, eval_frames: int, block_size: int,
                     conf_thresh: float = 0.4, mesh: Optional[Mesh] = None,
-                    donate: bool = True, with_reuse: bool = True,
+                    with_reuse: bool = True,
                     use_kernel: bool = True,
                     live: Optional[jax.Array] = None, checked: bool = False
                     ) -> FleetSlotOut:
@@ -854,8 +709,7 @@ def fleet_slot_step(cfg: CodecConfig, server_params: Any, frames: jax.Array,
         gt_valid = pad_leading(gt_valid, C_pad)
         live = pad_leading(jnp.asarray(live, bool), C_pad, fill=False)
     fn = _get_executable(mesh, cfg, eval_frames, block_size, conf_thresh,
-                         donate and not checked, with_reuse,
-                         use_kernel and not checked, checked)
+                         with_reuse, use_kernel and not checked, checked)
     with warnings.catch_warnings():
         # donated frame/GT buffers can't alias the (small) outputs; XLA still
         # recycles them for intermediates, which is the point — drop the nag
@@ -1021,7 +875,7 @@ def _episode_impl(server_params, light_params, mlp_params, jcab_util,
             num_cams=num_cams, checked=checked)
         b_l, r_l = scatter(co.b, 1.0), scatter(co.r, 1.0)
         if method == "reducto":
-            # "first slot" is per-RUN (t == t_first), matching the pipelined
+            # "first slot" is per-RUN (t == t_first), matching the batched
             # loop's per-run reference reset — a resumed episode
             # (t_start > 0 on a reused scene) force-keeps frame 0 of ITS
             # first slot, not of global slot 0; a reconnecting camera is

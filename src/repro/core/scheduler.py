@@ -8,31 +8,14 @@ Per time slot:
   sum_i lambda_i F1_i.
 
 Three execution modes (``SystemConfig.batched`` / ``SystemConfig.episode``):
-  * batched (default) — the sharded, sync-free fleet slot-step: ONE compiled
-    encode->detect->score->reuse-mix program over the camera axis
-    (``core.fleet.fleet_slot_step``) shared by ALL methods (deepstream,
-    jcab, reducto, static — method routing is data, not Python branches), so
-    ``run()`` compiles the fleet executable once per (method, config).  The
-    slot loop is pipelined: slot t+1's ROIDet dispatches while slot t's
-    scores are still in flight (``SystemConfig.pipeline``).  With the
-    default ``SystemConfig.alloc="device"`` the control loop itself
-    (elastic + utility table + allocation, ``fleet.fleet_control_step``)
-    is a traced program consuming the ROIDet (a, c) device vectors and a
-    prefetched bandwidth-trace device array, and reducto's keep-flag
-    decision is traced too (``fleet.reducto_keep_step`` + the in-program
-    ``fleet.keep_selection``) — the host harvests ONLY the previous slot's
-    packed (F1, sizes) + (4,) control logs, so the timed loop is clean
-    under ``jax.transfer_guard_device_to_host("disallow")``.
-    ``alloc="host"`` keeps the numpy reference control path (one packed
-    (a, c) D2H fetch per slot).  With >1 device the camera axis is
-    shard_map'd over a ("camera",) mesh and the big per-slot buffers are
-    donated (``SystemConfig.shard`` / ``donate``).
-  * episode (``SystemConfig.episode=True``) — the whole-trace runner
-    (``run_episode``): segment generation moves on device
-    (``data.synthetic.DeviceScene`` / ``segments_device``) and the ENTIRE
-    N-slot trace executes as one ``fleet.fleet_episode`` lax.scan per
-    method, under ``jax.transfer_guard("disallow")`` both directions with
-    no scoped exemptions; stacked logs are harvested once at episode end.
+  * episode (``SystemConfig.episode=True``) — the device-control path and
+    the one the chip serves (``run_episode``): segment generation moves on
+    device (``data.synthetic.DeviceScene`` / ``segments_device``) and the
+    ENTIRE N-slot trace — ROIDet, the traced control step
+    (``fleet._control_impl``), reducto's keep decision and the slot-step —
+    executes as one ``fleet.fleet_episode`` lax.scan per method, under
+    ``jax.transfer_guard("disallow")`` both directions with no scoped
+    exemptions; stacked logs are harvested once at episode end.
     Trace lengths are BUCKETED (``SystemConfig.episode_buckets``): T pads
     up to a power-of-two bucket with masked tail slots so one executable
     per (method, bucket) serves every T.  A padded slot runs the per-slot
@@ -41,12 +24,22 @@ Three execution modes (``SystemConfig.batched`` / ``SystemConfig.episode``):
     slot, its logs are sliced off before harvest, and the DP capacity is
     computed from the active prefix (``allocation.trace_capacity``), so
     bucketing never changes a pick (see ``fleet.bucket_len``).
-  * sequential — the original per-camera Python loop, kept as the
-    equivalence/benchmark baseline.  All modes consume PRNG keys in the
-    same order, so F1/size logs agree within float tolerance — including
-    reducto, whose sequential arm encodes fixed-shape segments with a traced
-    kept-frame count and tracks the same cross-slot reference frame, so
-    every arm draws identical coding noise.
+  * batched (``episode=False``, the default) — the host-control
+    reference: one compiled encode->detect->score->reuse-mix slot-step over
+    the camera axis (``core.fleet.fleet_slot_step``) shared by ALL methods
+    (method routing is data, not Python branches), with the control loop
+    (elastic + utility table + allocation) in host numpy
+    (``_slot_allocation``) — independent of the traced control the episode
+    runs, which is what makes it a reference.  One packed (a, c) fetch and
+    one log harvest per slot; each slot is harvested before the next
+    dispatches.  With >1 device the camera axis is shard_map'd over a
+    ("camera",) mesh (``SystemConfig.shard``).
+  * sequential (``batched=False``) — the original per-camera Python loop,
+    kept as the equivalence/benchmark baseline.  All modes consume PRNG
+    keys in the same order, so F1/size logs agree within float tolerance
+    — including reducto, whose sequential arm encodes fixed-shape segments
+    with a traced kept-frame count and tracks the same cross-slot
+    reference frame, so every arm draws identical coding noise.
 
 Baselines (section 7.2):
   * reducto  — on-camera frame filtering (low-level feature deltas) + fair
@@ -68,13 +61,11 @@ dead camera still COMPUTES (one executable signature, zero recompiles,
 zero extra transfers) but cannot advance any observable state, and a
 camera dead for a whole trace is log-equivalent to a fleet that never had
 it.  ``SystemConfig.checked`` turns on checkify-guarded invariants
-(diagnostics lane), and ``EpisodeSupervisor`` wraps episode dispatch with
-the ``ft.watchdog`` straggler gate, bounded retries and degraded-mode
-fallback.
+(diagnostics lane); retries and degraded-mode fallback live in
+``serve.stream``'s watchdog ladder.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
@@ -95,38 +86,32 @@ from repro.core import utility as util_mod
 from repro.core.codec import CodecConfig
 from repro.core.elastic import ElasticConfig, ElasticState
 from repro.data.synthetic import DeviceScene, MultiCameraScene, SceneConfig
-from repro.ft import watchdog as ft_watchdog
 from repro.kernels.edge_motion import ops as em_ops
 from repro.models import detector as det
 from repro.sharding import rules as shard_rules
 
 
 # block-motion mass above which a frame counts as "changed" (reducto keep
-# rule) — one constant shared by the sequential, pipelined-traced and
-# episode paths (they must stay bit-in-sync for the cross-mode equivalence
-# guarantees); lives in ``fleet`` so traced programs need no import cycle
+# rule) — one constant shared by the sequential, batched and episode paths
+# (they must stay bit-in-sync for the cross-mode equivalence guarantees);
+# lives in ``fleet`` so traced programs need no import cycle
 MOTION_KEEP_THRESH = fleet_mod.MOTION_KEEP_THRESH
 
 
 # -- device-to-host accounting ------------------------------------------------
-# Every D2H fetch the batched loop performs goes through ``_d2h`` so the
-# "zero per-slot sync" guarantee of the device-resident paths is CHECKABLE:
-# on TPU/GPU, running the loop under
-# ``jax.transfer_guard_device_to_host("disallow")`` trips on any fetch not
-# scoped ``exempt`` (the pipelined log harvest; episode mode has NO per-slot
-# exemption at all — its one harvest happens after the trace); on the CPU
-# backend D2H is zero-copy and the guard never fires, so the per-category
-# counters (``common.obs``, categories listed there) are the proof instead.
+# Every D2H fetch the run loops perform goes through ``_d2h`` so the "zero
+# per-slot sync" guarantee of episode mode is CHECKABLE: on TPU/GPU the
+# episode runs under ``jax.transfer_guard("disallow")`` with no exemption
+# (its one harvest happens after the trace); on the CPU backend D2H is
+# zero-copy and the guard never fires, so the per-category counters
+# (``common.obs``, categories listed there) are the proof instead.
 # Episode runs must leave 'keep' and 'control' at zero and add exactly TWO
 # 'harvest' fetches per run (the stacked F1/size pack and the stacked
 # control pack), independent of slot count.
 
 
-def _d2h(x, kind: str, exempt: bool = False) -> np.ndarray:
+def _d2h(x, kind: str) -> np.ndarray:
     obs.count_fetch(kind)
-    if exempt:
-        with jax.transfer_guard_device_to_host("allow"):
-            return np.asarray(x)
     return np.asarray(x)
 
 
@@ -160,9 +145,6 @@ class SystemConfig:
     use_kernels: bool = True
     batched: bool = True                      # fleet slot-step vs Python loop
     shard: str = "auto"                       # "auto": camera mesh if >1 dev
-    pipeline: bool = True                     # deferred-harvest slot loop
-    donate: bool = True                       # donate per-slot fleet buffers
-    alloc: str = "device"                     # control loop: "device" | "host"
     episode: bool = False                     # whole-trace lax.scan episodes
     # software-pipelined episode scan body (2-stage: slot t's detector
     # dispatch overlaps slot t+1's encode; padded slots cond-skipped, dead
@@ -176,8 +158,8 @@ class SystemConfig:
     episode_buckets: Optional[Tuple[int, ...]] = fleet_mod.EPISODE_BUCKETS
     # optional bandwidth ceiling (Kbps) pinning the traced allocator's
     # static DP capacity across runs: without it w_cap derives from each
-    # trace's max and every new trace re-traces the control/episode
-    # programs (w_cap is a jit static).  The scenario harness pins it so a
+    # trace's max and every new trace re-traces the episode programs
+    # (w_cap is a jit static).  The scenario harness pins it so a
     # whole (method x family x T) matrix shares executables.
     w_cap_kbps: Optional[float] = None
     # checkify-guarded invariants (finite logs, allocation <= capacity,
@@ -190,24 +172,11 @@ class SystemConfig:
     checked: bool = False
 
     def __post_init__(self):
-        if self.alloc not in ("device", "host"):
-            raise ValueError(f"alloc must be 'device' or 'host': {self.alloc!r}")
         if self.checked:
             self.shard = "off"
-            self.donate = False
             self.use_kernels = False
-        if self.episode:
-            # the episode scan IS the device control loop — there is no
-            # host-alloc variant of a program the host never re-enters
-            if not self.batched:
-                raise ValueError("episode mode requires batched=True")
-            if self.alloc != "device":
-                raise ValueError("episode mode requires alloc='device' "
-                                 f"(got {self.alloc!r})")
-        # the sequential reference loop has no traced control path; normalize
-        # so the config (and bench metadata stamped from it) states what runs
-        if not self.batched:
-            self.alloc = "host"
+        if self.episode and not self.batched:
+            raise ValueError("episode mode requires batched=True")
 
     def lam(self) -> np.ndarray:
         if self.weights is None:
@@ -259,8 +228,8 @@ class DeepStreamSystem:
         self._key = jax.random.PRNGKey(1234)
         self._reducto_ref: Optional[jax.Array] = None       # batched runs
         self._reducto_ref_host: List[Optional[np.ndarray]] = []  # sequential
-        # post-run serving carry (EpisodeCarry) recorded by run_episode and
-        # the carried pipelined loop — what serve.stream checkpoints
+        # post-run serving carry (EpisodeCarry) recorded by run_episode —
+        # what serve.stream checkpoints
         self.last_carry: Optional[EpisodeCarry] = None
         # method -> (host values, device leaves, EpisodeInvariants) the
         # episode windows reuse (see _episode_invariants)
@@ -291,7 +260,7 @@ class DeepStreamSystem:
     def camera_features(self, frames_c: np.ndarray, block: bool = True):
         """frames_c (C, N, H, W) -> ROIResult batch (fleet ROIDet, sharded
         over the camera mesh when one exists).  ``block=False`` skips the
-        device sync — the pipelined slot loop fetches only the packed (a, c)
+        device sync — the batched slot loop fetches only the packed (a, c)
         scalars it needs."""
         res = roidet_mod.roidet_fleet(
             jnp.asarray(frames_c), self.light, block_size=self.cfg.block_size,
@@ -374,7 +343,7 @@ class DeepStreamSystem:
             jnp.asarray(r, jnp.float32), keys, keep,
             jnp.asarray(gt_boxes), jnp.asarray(gt_valid),
             eval_frames=self.cfg.eval_frames, block_size=self.cfg.block_size,
-            mesh=self.mesh, donate=self.cfg.donate, with_reuse=with_reuse,
+            mesh=self.mesh, with_reuse=with_reuse,
             use_kernel=self.cfg.use_kernels, live=live,
             checked=self.cfg.checked)
         return out
@@ -506,18 +475,16 @@ class DeepStreamSystem:
                               for j in sel]))
 
     def _reducto_keep(self, frames: jax.Array, first_slot: bool,
-                      reconnect: Optional[np.ndarray] = None
-                      ) -> Tuple[jax.Array, None]:
+                      reconnect: Optional[np.ndarray] = None) -> jax.Array:
         """Traced reducto keep decision for the batched loop: motion ->
         keep-flags -> next-slot reference, ONE device dispatch with ZERO
-        host fetches (the pre-episode per-slot 'keep' D2H sync is gone —
-        kept/missed frame selection happens inside the slot-step program
-        via ``fleet.keep_selection``).  The cross-slot reference (last kept
-        frame) is threaded through ``self._reducto_ref``; ``first_slot``
-        marks the first slot of a FRESH stream (no reference yet — a
-        carry-seeded window passes False, its reference is live);
-        ``reconnect`` (C,) bool marks cameras whose reference went stale
-        while dead — they re-seed from frame 0 like a run start."""
+        host fetches (kept/missed frame selection happens inside the
+        slot-step program via ``fleet.keep_selection``).  The cross-slot
+        reference (last kept frame) is threaded through
+        ``self._reducto_ref``; ``first_slot`` marks the run's first slot (no
+        reference yet); ``reconnect`` (C,) bool marks cameras whose
+        reference went stale while dead — they re-seed from frame 0 like a
+        run start."""
         C, H, W = frames.shape[0], frames.shape[2], frames.shape[3]
         if self._reducto_ref is None:
             self._reducto_ref = jnp.zeros((C, H, W), jnp.float32)
@@ -528,7 +495,7 @@ class DeepStreamSystem:
             frames, self._reducto_ref, first,
             block_size=self.cfg.block_size, use_kernel=self.cfg.use_kernels,
             mesh=self.mesh)
-        return keep, None
+        return keep
 
     # -- online loop -------------------------------------------------------------
 
@@ -536,7 +503,7 @@ class DeepStreamSystem:
         """jcab's content-agnostic (util (C, J), best_res (C, J)) tables —
         the same (J, R) profiled table folded and lambda-weighted for every
         camera.  The ONE construction both control paths use: the host
-        allocator calls it per slot, the device context uploads it once."""
+        allocator calls it per slot, the episode invariants upload it once."""
         jt = self.jcab_table                              # (J, R)
         C = self.cfg.scene.num_cameras
         lam = self.cfg.lam()
@@ -588,7 +555,7 @@ class DeepStreamSystem:
         the timed region (dispatch + wait) the host performs ZERO per-slot
         work: no uploads, no fetches, no Python slot loop — callers may wrap
         it in ``jax.transfer_guard("disallow")`` with no scoped exemptions.
-        Log-equivalent to the pipelined ``run()`` over the same
+        Log-equivalent to the host-control batched ``run()`` over the same
         ``DeviceScene`` seeds (<= 1e-5, see tests/test_episode.py), for any
         trace length: T is padded to a ``cfg.episode_buckets`` bucket inside
         ``fleet_episode`` and the harvested logs come back already sliced
@@ -604,9 +571,8 @@ class DeepStreamSystem:
         ``serve.stream`` checkpoints at window boundaries."""
         if use_elastic is None:
             use_elastic = method == "deepstream"
-        if not (self.cfg.batched and self.cfg.alloc == "device"):
-            raise ValueError("episode mode requires batched=True and "
-                             "alloc='device'")
+        if not self.cfg.batched:
+            raise ValueError("episode mode requires batched=True")
         if not isinstance(scene, DeviceScene):
             raise TypeError("run_episode needs a DeviceScene (device-side "
                             f"segment generation), got {type(scene)!r}")
@@ -640,9 +606,9 @@ class DeepStreamSystem:
         with obs.span("serve.device"):
             out = launch()
         with obs.span("serve.harvest"):
-            # advance the scene cursor exactly like T pipelined segment()
+            # advance the scene cursor exactly like T batched segment()
             # calls would — a reused scene continues, matching the
-            # pipelined reference
+            # batched reference
             scene._t += len(trace_kbps)
             self._key = out.key
             self.last_carry = EpisodeCarry(
@@ -686,6 +652,10 @@ class DeepStreamSystem:
         lam = self.cfg.lam()
         C = self.cfg.scene.num_cameras
         bitrates = list(cfgc.bitrates_kbps)
+        if self.cfg.checked and not (np.isfinite(W_t) and W_t >= 0.0):
+            # the host mirror of the traced control step's checkify check
+            raise ValueError("control: bandwidth sample not "
+                             "finite/non-negative")
         if live is None:
             live = np.ones(C, bool)
         masks = None
@@ -694,8 +664,7 @@ class DeepStreamSystem:
         if method in ("deepstream", "deepstream_no_elastic"):
             roi = self.camera_features(frames, block=not self.cfg.batched)
             # the host control path's ONE camera-side sync: packed (a_i, c_i)
-            # scalars — the fetch alloc="device" eliminates (counted, NOT
-            # transfer-guard exempt)
+            # scalars — the fetch the episode scan does not make (counted)
             ac = _d2h(jnp.stack([roi.area_ratio, roi.confidence]), "control")
             a, c = ac[0], ac[1]
             area = float(a[live].sum())
@@ -736,7 +705,7 @@ class DeepStreamSystem:
         ACTIVE (unpadded) trace — episode bucketing appends zero-Kbps tail
         slots after this runs, so a bucketed run solves the exact DP the
         unbucketed program would.  ``cfg.w_cap_kbps`` optionally pins it so
-        different traces share one compiled control program (w_cap is a
+        different traces share one compiled episode program (w_cap is a
         jit static)."""
         cfgc = self.cfg.codec
         borrow = (self.cfg.elastic.budget_kbits / cfgc.slot_seconds
@@ -745,27 +714,6 @@ class DeepStreamSystem:
             tuple(int(b) for b in cfgc.bitrates_kbps), trace_kbps,
             self.cfg.scene.num_cameras, elastic_borrow_kbps=borrow,
             pin_kbps=self.cfg.w_cap_kbps)
-
-    def _control_context(self, method: str, trace_kbps: np.ndarray,
-                         use_elastic: bool) -> Dict[str, Any]:
-        """Per-run device uploads for the pipelined loop's traced control
-        step: the prefetched bandwidth trace (indexed slot by slot), lambda
-        weights, elastic thresholds, (for jcab) the content-agnostic table,
-        the fresh device elastic state, and the static DP capacity
-        (``_w_cap``)."""
-        ctx: Dict[str, Any] = dict(
-            trace=jnp.asarray(np.asarray(trace_kbps, np.float32)),
-            lam=jnp.asarray(self.cfg.lam(), jnp.float32),
-            tau_wl=jnp.float32(self.tau_wl), tau_wh=jnp.float32(self.tau_wh),
-            w_cap=self._w_cap(trace_kbps, use_elastic),
-            est=elastic_mod.init_state_jax(),
-            jcab_util=None, jcab_res=None)
-        if method == "jcab":
-            # the SAME table _slot_allocation builds, uploaded ONCE per run
-            util, best_res = self._jcab_utility_table()
-            ctx["jcab_util"] = jnp.asarray(util)
-            ctx["jcab_res"] = jnp.asarray(best_res)
-        return ctx
 
     def _episode_invariants(self, method: str, scene: DeviceScene
                             ) -> fleet_mod.EpisodeInvariants:
@@ -825,96 +773,23 @@ class DeepStreamSystem:
             live_prev0=None if carry is None else carry.live_prev,
             t_first=None if carry is None else carry.t_first)
 
-    def _slot_control_device(self, method: str, frames: jax.Array, t: int,
-                             ctx: Dict[str, Any], use_elastic: bool,
-                             live: Optional[np.ndarray] = None,
-                             reconnect: bool = False
-                             ) -> Tuple[jax.Array, jax.Array,
-                                        Optional[jax.Array], jax.Array]:
-        """Per-slot method routing, device-resident: ROIDet's (a, c) device
-        vectors feed the traced elastic -> allocation program directly —
-        no host fetch anywhere.  ``live``/``reconnect`` are the slot's
-        fault signals (traced data: no recompile, and their upload is H2D —
-        the loop's zero-D2H guarantee is untouched).  Returns
-        (b, r, masks, ctrl_pack), all device arrays; the elastic state is
-        threaded through ``ctx``."""
-        a = c = masks = None
-        if method in ("deepstream", "deepstream_no_elastic"):
-            roi = self.camera_features(frames, block=False)
-            masks = roi.mask
-            # shard-boundary gather onto the control device
-            a = shard_rules.unshard(roi.area_ratio, self.mesh)
-            c = shard_rules.unshard(roi.confidence, self.mesh)
-        co = fleet_mod.fleet_control_step(
-            method, self.mlp if a is not None else None,
-            ctx["jcab_util"], ctx["jcab_res"], ctx["lam"], a, c,
-            ctx["trace"][t], ctx["est"], ctx["tau_wl"], ctx["tau_wh"],
-            ecfg=self.cfg.elastic,
-            bitrates=tuple(self.cfg.codec.bitrates_kbps),
-            resolutions=tuple(self.cfg.codec.resolutions),
-            slot_seconds=self.cfg.codec.slot_seconds,
-            use_elastic=use_elastic, use_kernel=self.cfg.use_kernels,
-            w_cap=ctx["w_cap"], num_cams=self.cfg.scene.num_cameras,
-            mesh=self.mesh,
-            live=None if live is None else jnp.asarray(live, bool),
-            reconnect=bool(reconnect), checked=self.cfg.checked)
-        ctx["est"] = co.est
-        return co.b, co.r, masks, co.pack
-
     def _run_batched(self, scene: MultiCameraScene, trace_kbps: np.ndarray,
                      method: str, use_elastic: bool,
-                     faults: Optional[np.ndarray] = None,
-                     carry: Optional[EpisodeCarry] = None
+                     faults: Optional[np.ndarray] = None
                      ) -> Dict[str, np.ndarray]:
-        """Pipelined fleet loop: every method routes through ONE compiled
-        slot-step.  With ``alloc="device"`` the control loop runs on device
-        too — the host only harvests slot t's packed (F1, sizes) + control
-        logs while slot t+1 is in flight (those fetches are scoped
-        transfer-guard exemptions; everything else is D2H-free).  With
-        ``alloc="host"`` the numpy reference control path syncs on one
-        packed (a, c) fetch per slot.  ``faults`` (T, C) bool threads the
-        liveness mask through control, keep-flags and the slot-step as
-        traced data (same executables, no extra D2H).
-
-        ``carry`` (device-control only) seeds the same serving carry as
-        ``run_episode`` — ``serve.stream``'s degraded "pipelined" rung
-        stays slot-for-slot identical to the episode rungs — and every
-        device-control run records ``self.last_carry``."""
+        """Host-control fleet loop: every method routes through ONE compiled
+        slot-step, and the numpy reference control path
+        (``_slot_allocation``) syncs on one packed (a, c) fetch per slot.
+        Each slot's packed (F1, sizes) is harvested before the next slot
+        dispatches.  ``faults`` (T, C) bool threads the liveness mask
+        through control, keep-flags and the slot-step (same executables)."""
         lam = self.cfg.lam()
         C = self.cfg.scene.num_cameras
-        device_ctrl = self.cfg.alloc == "device"
-        if carry is not None and not device_ctrl:
-            raise ValueError("carry-seeded runs need alloc='device' (the "
-                             "host control path has no device carry)")
         est = ElasticState()
-        t_begin = getattr(scene, "_t", 0)
-        ctx = (self._control_context(method, trace_kbps, use_elastic)
-               if device_ctrl else None)
-        if carry is not None:
-            ctx["est"] = carry.est
         logs = {k: [] for k in ("utility", "mean_f1", "bytes", "W", "extra",
                                 "alloc_kbps", "area")}
-
-        def harvest(item: Tuple[fleet_mod.FleetSlotOut,
-                                Optional[jax.Array]]) -> None:
-            out, cpack = item
-            # the per-slot log harvest: one (2, C) + one (4,) D2H transfer,
-            # explicitly exempted from the loop's transfer-guard guarantee
-            pack = _d2h(out.host_pack, "harvest", exempt=True)
-            cp = (None if cpack is None
-                  else _d2h(cpack, "harvest", exempt=True))
-            logs["utility"].append(float(np.dot(lam, pack[0])))
-            logs["mean_f1"].append(float(np.mean(pack[0])))
-            logs["bytes"].append(float(np.sum(pack[1])))
-            if cp is not None:
-                logs["extra"].append(float(cp[0]))
-                logs["area"].append(float(cp[1]))
-                logs["alloc_kbps"].append(float(cp[2]))
-
-        self._reducto_ref = None if carry is None else carry.ref
-        live_prev = (np.ones(C, bool) if carry is None
-                     else np.asarray(carry.live_prev, bool))
-        pending: Optional[Tuple] = None
+        self._reducto_ref = None
+        live_prev = np.ones(C, bool)
         for t in range(len(trace_kbps)):
             W_t = float(trace_kbps[t])
             seg = scene.segment()
@@ -937,48 +812,26 @@ class DeepStreamSystem:
                                               np.arange(C))
             live_t = np.ones(C, bool) if faults is None else faults[t]
             reconnect_vec = live_t & ~live_prev
-            if device_ctrl:
-                b, r, masks, cpack = self._slot_control_device(
-                    method, frames, t, ctx, use_elastic,
-                    live=None if faults is None else live_t,
-                    reconnect=bool(reconnect_vec.any()))
-            else:
-                b, r, masks, extra, area, alloc_kbps, est = \
-                    self._slot_allocation(method, frames, W_t, est,
-                                          use_elastic, live=live_t,
-                                          reconnect=bool(reconnect_vec.any()))
-                cpack = None
-                logs["extra"].append(extra)
-                logs["area"].append(area)
-                logs["alloc_kbps"].append(alloc_kbps)
+            b, r, masks, extra, area, alloc_kbps, est = self._slot_allocation(
+                method, frames, W_t, est, use_elastic, live=live_t,
+                reconnect=bool(reconnect_vec.any()))
             keep = None
             if method == "reducto":
-                keep, _ = self._reducto_keep(
-                    frames, t == 0 and carry is None,
+                keep = self._reducto_keep(
+                    frames, t == 0,
                     reconnect=None if faults is None else reconnect_vec)
-
             out = self._slot_dispatch(
                 frames, gts, masks, b, r, keys=keys, keep=keep, gt_dev=gt_dev,
                 live=None if faults is None else jnp.asarray(live_t))
             live_prev = live_t
+            pack = _d2h(out.host_pack, "harvest")
+            logs["utility"].append(float(np.dot(lam, pack[0])))
+            logs["mean_f1"].append(float(np.mean(pack[0])))
+            logs["bytes"].append(float(np.sum(pack[1])))
             logs["W"].append(W_t)
-            if pending is not None:
-                harvest(pending)
-            if self.cfg.pipeline:
-                pending = (out, cpack)
-            else:
-                harvest((out, cpack))
-        if pending is not None:
-            harvest(pending)
-        if device_ctrl:
-            ref = self._reducto_ref
-            if ref is None:      # non-reducto: the reference passes through
-                ref = (carry.ref if carry is not None else jnp.zeros(
-                    (C, self.cfg.scene.height, self.cfg.scene.width),
-                    jnp.float32))
-            self.last_carry = EpisodeCarry(
-                est=ctx["est"], ref=ref, live_prev=np.asarray(live_prev),
-                t_first=(carry.t_first if carry is not None else t_begin))
+            logs["extra"].append(extra)
+            logs["area"].append(area)
+            logs["alloc_kbps"].append(alloc_kbps)
         return {k: np.asarray(v) for k, v in logs.items()}
 
     def _run_sequential(self, scene: MultiCameraScene, trace_kbps: np.ndarray,
@@ -1060,8 +913,7 @@ class DeepStreamSystem:
                 jnp.concatenate([jnp.asarray(ref)[None], jnp.asarray(fr)]),
                 block_size=self.cfg.block_size,
                 use_kernel=self.cfg.use_kernels)             # (N, M, Nb)
-            keep = _motion_keep(_d2h(jnp.sum(sc, axis=(1, 2)), "keep",
-                                     exempt=True), first)
+            keep = _motion_keep(_d2h(jnp.sum(sc, axis=(1, 2)), "keep"), first)
             kept_idx, ev_idx = self._kept_eval_selection(keep)
             self._reducto_ref_host[i] = fr[kept_idx[-1]]
             decoded, size = codec_mod.encode_segment(
@@ -1087,205 +939,3 @@ class DeepStreamSystem:
                 f1 = f1 * w_keep + f1_re * (1 - w_keep)
             f1s.append(f1); sizes.append(float(size))
         return f1s, sizes
-
-
-# -- watchdog-supervised episode execution ------------------------------------
-
-
-@dataclass
-class SupervisorConfig:
-    """Policy knobs for ``EpisodeSupervisor``.
-
-    ``max_retries`` bounds re-dispatches of ONE run at the same mode rung;
-    ``backoff_s`` is the base of an exponential retry backoff (0 = retry
-    immediately — the default, since a failed jit dispatch has no cooldown
-    to wait out); ``degrade`` allows falling down the mode ladder when
-    retries are exhausted or the watchdog escalates; ``recover_after`` is
-    how many consecutive healthy ('ok' verdict) runs at a degraded rung
-    climb back one rung (0 disables recovery — rungs stay sticky);
-    ``watchdog`` parameterizes the EMA+sigma straggler gate
-    (``ft.watchdog``) fed with per-run wall times."""
-    max_retries: int = 2
-    backoff_s: float = 0.0
-    degrade: bool = True
-    recover_after: int = 3
-    watchdog: ft_watchdog.WatchdogConfig = field(
-        default_factory=ft_watchdog.WatchdogConfig)
-
-
-class EpisodeSupervisor:
-    """Host-side supervisor wrapping ``DeepStreamSystem`` episode dispatch
-    with fault tolerance: bounded retry with backoff, an ``ft.watchdog``
-    straggler gate on per-run wall time, and a degraded-mode ladder.
-
-    The ladder (for an episode-mode system):
-
-      ``episode``          whole-trace lax.scan (the fast path)
-      ``episode_chunked``  the SAME episode program dispatched per
-                           next-smaller-bucket chunk of the trace — smaller
-                           programs, more dispatches; elastic/reducto state
-                           re-seeds at chunk boundaries, the documented
-                           degraded-mode approximation
-      ``pipelined``        the per-slot pipelined fleet loop (no episode
-                           scan at all)
-
-    A run that raises is retried up to ``cfg.max_retries`` times at the
-    current rung, then the supervisor degrades one rung (when
-    ``cfg.degrade``) and retries there; a run whose wall time trips the
-    watchdog's ``'replace'`` verdict degrades the NEXT run preemptively.
-    Rungs are sticky across runs (``self._rung``), and a degraded fleet
-    climbs BACK one rung after ``cfg.recover_after`` consecutive healthy
-    runs at the degraded rung (a ``'recover'`` event; 0 disables and makes
-    degradation permanent until the caller resets it).  EVERY rung change
-    — watchdog degrade, retries-exhausted degrade, or recovery —
-    rebaselines the watchdog (``Watchdog.rebaseline``): the step-time
-    distribution shifts wholesale across modes, so the new rung's EMA must
-    never be seeded from the old rung's timings (a recovered runner gated
-    against its degraded-rung baseline would either instantly re-trip or
-    mask real stragglers).  Every decision is appended to ``self.events``
-    for tests and post-mortems.
-
-    ``fault_hook(attempt=, mode=)`` (tests/chaos injection) runs right
-    before each dispatch; raising from it counts as that attempt failing.
-    """
-
-    LADDER_EPISODE = ("episode", "episode_chunked", "pipelined")
-
-    def __init__(self, system: DeepStreamSystem,
-                 cfg: Optional[SupervisorConfig] = None,
-                 fault_hook: Optional[Any] = None):
-        self.system = system
-        self.cfg = cfg if cfg is not None else SupervisorConfig()
-        self.fault_hook = fault_hook
-        self.watchdog = ft_watchdog.Watchdog(self.cfg.watchdog)
-        self.events: List[Dict[str, Any]] = []
-        self._step = 0          # watchdog step counter (successful runs)
-        self._rung = 0          # current position on the mode ladder
-        self._ok_streak = 0     # consecutive healthy runs at a degraded rung
-
-    @property
-    def mode(self) -> str:
-        return self._ladder()[min(self._rung, len(self._ladder()) - 1)]
-
-    def _ladder(self) -> Tuple[str, ...]:
-        if self.system.cfg.episode:
-            return self.LADDER_EPISODE
-        return ("pipelined",)
-
-    def run(self, scene, trace_kbps: np.ndarray, method: str = "deepstream",
-            use_elastic: Optional[bool] = None,
-            faults: Optional[np.ndarray] = None) -> Dict[str, np.ndarray]:
-        """One supervised bandwidth-trace run; same signature and logs as
-        ``DeepStreamSystem.run``."""
-        ladder = self._ladder()
-        last_err: Optional[BaseException] = None
-        for rung in range(min(self._rung, len(ladder) - 1), len(ladder)):
-            mode = ladder[rung]
-            for attempt in range(self.cfg.max_retries + 1):
-                if attempt and self.cfg.backoff_s > 0.0:
-                    time.sleep(self.cfg.backoff_s * (2.0 ** (attempt - 1)))
-                t0 = time.perf_counter()
-                try:
-                    if self.fault_hook is not None:
-                        self.fault_hook(attempt=attempt, mode=mode)
-                    logs = self._dispatch(mode, scene, trace_kbps, method,
-                                          use_elastic, faults)
-                except Exception as e:   # retry-with-backoff boundary
-                    last_err = e
-                    self.events.append({"kind": "retry", "mode": mode,
-                                        "attempt": attempt,
-                                        "error": repr(e)})
-                    continue
-                wall = time.perf_counter() - t0
-                self._step += 1
-                verdict = self.watchdog.record(self._step, wall)
-                self.events.append({"kind": "ok", "mode": mode,
-                                    "attempt": attempt, "wall_s": wall,
-                                    "verdict": verdict})
-                if (verdict == "replace" and self.cfg.degrade
-                        and rung + 1 < len(ladder)):
-                    # persistent straggling at this rung: degrade the NEXT
-                    # run preemptively (this one already succeeded)
-                    self._rung = rung + 1
-                    self._ok_streak = 0
-                    self.watchdog.rebaseline()
-                    self.events.append({"kind": "degrade", "mode": mode,
-                                        "to": ladder[self._rung],
-                                        "cause": "watchdog"})
-                elif (verdict == "ok" and rung > 0
-                        and self.cfg.recover_after > 0):
-                    self._ok_streak += 1
-                    if self._ok_streak >= self.cfg.recover_after:
-                        # sustained health at the degraded rung: climb back
-                        # one rung, gating its first steps against a FRESH
-                        # baseline (not the degraded rung's timings)
-                        self._rung = rung - 1
-                        self._ok_streak = 0
-                        self.watchdog.rebaseline()
-                        self.events.append({"kind": "recover", "mode": mode,
-                                            "to": ladder[self._rung],
-                                            "after_ok":
-                                                self.cfg.recover_after})
-                else:
-                    self._ok_streak = 0
-                return logs
-            if self.cfg.degrade and rung + 1 < len(ladder):
-                self._rung = rung + 1
-                self._ok_streak = 0
-                self.watchdog.rebaseline()
-                self.events.append({"kind": "degrade", "mode": mode,
-                                    "to": ladder[self._rung],
-                                    "cause": "retries_exhausted"})
-            else:
-                break
-        raise RuntimeError(
-            f"supervised run failed at every mode rung (last mode "
-            f"{self.mode!r}, {self.cfg.max_retries} retries each)"
-        ) from last_err
-
-    # -- mode dispatch ---------------------------------------------------------
-
-    def _dispatch(self, mode: str, scene, trace_kbps: np.ndarray, method: str,
-                  use_elastic: Optional[bool],
-                  faults: Optional[np.ndarray]) -> Dict[str, np.ndarray]:
-        if use_elastic is None:
-            use_elastic = method == "deepstream"
-        if mode == "episode":
-            return self.system.run_episode(scene, trace_kbps, method,
-                                           use_elastic, faults=faults)
-        if mode == "episode_chunked":
-            return self._run_chunked(scene, trace_kbps, method, use_elastic,
-                                     faults)
-        if mode == "pipelined":
-            return self.system._run_batched(scene, trace_kbps, method,
-                                            use_elastic, faults=faults)
-        raise ValueError(mode)
-
-    def _chunk_len(self, T: int) -> int:
-        """Degraded chunk size: the bucket BELOW the one a T-slot episode
-        would use (smaller compiled program, already warm from the bucket
-        ladder), floored at the smallest bucket."""
-        buckets = self.system.cfg.episode_buckets
-        if not buckets:
-            return max(1, T // 2)
-        below = [b for b in sorted(buckets)
-                 if b < fleet_mod.bucket_len(T, buckets)]
-        return below[-1] if below else sorted(buckets)[0]
-
-    def _run_chunked(self, scene, trace_kbps: np.ndarray, method: str,
-                     use_elastic: bool, faults: Optional[np.ndarray]
-                     ) -> Dict[str, np.ndarray]:
-        """The episode program dispatched per trace chunk.  Cross-chunk
-        carry (elastic EMA/debt, reducto reference, fault reconnect edges
-        at chunk boundaries) re-seeds fresh each chunk — the documented
-        approximation that buys degraded-mode progress when the whole-trace
-        program is the thing failing."""
-        T = len(trace_kbps)
-        step = self._chunk_len(T)
-        parts: List[Dict[str, np.ndarray]] = []
-        for i0 in range(0, T, step):
-            i1 = min(i0 + step, T)
-            parts.append(self.system.run_episode(
-                scene, np.asarray(trace_kbps)[i0:i1], method, use_elastic,
-                faults=None if faults is None else faults[i0:i1]))
-        return {k: np.concatenate([p[k] for p in parts]) for k in parts[0]}

@@ -187,7 +187,7 @@ class MultiCameraScene:
 #
 # The host ``MultiCameraScene`` is a stateful numpy world simulator — every
 # slot is built on the host and uploaded, which is the dominant H2D term of
-# the pipelined loop.  ``segments_device`` is its device-side counterpart: a
+# the batched loop.  ``segments_device`` is its device-side counterpart: a
 # PURE traced function (slot t's frames + padded GT are a closed-form
 # function of (params, base key, t)), so a whole bandwidth trace can be
 # ``lax.scan``-ed with zero mid-run uploads.  Statelessness is what makes the
@@ -201,7 +201,7 @@ class MultiCameraScene:
 # PRNG fold-in scheme (reproducibility contract): all slot randomness is
 # coding noise drawn from ``fold_in(fold_in(base_key, t), camera_id)`` — the
 # per-slot fold makes slots independent of evaluation ORDER (episode scan,
-# pipelined loop and the host ``DeviceScene.segment()`` adapter generate
+# batched loop and the host ``DeviceScene.segment()`` adapter generate
 # bit-identical content for the same (seed, t)), and the per-camera fold
 # keeps noise distinct across cameras even when the camera axis is sharded
 # over a mesh (every device folds the SAME slot key with DIFFERENT global
@@ -306,7 +306,7 @@ def segments_device(cfg: SceneConfig, params: DeviceSceneParams,
     (frames (C, N, H, W), gt_boxes (C, N, G, 4), gt_valid (C, N, G)).
 
     Pure in (key, t): calling it inside a ``lax.scan`` body, per slot from
-    the pipelined loop, or from the host adapter yields bit-identical
+    the batched loop, or from the host adapter yields bit-identical
     content.  ``gt_pad`` is the fixed GT box capacity G (the fleet's
     jit-signature contract, see ``fleet.gt_capacity``); entries are
     [stationary..., object pool...] with gaps where a pool object is
@@ -435,7 +435,7 @@ class DeviceScene:
     loop is then a no-op: zero uploads) and the host ``boxes`` lists are
     built lazily (the fleet consumes the padded ``gt_dev`` device arrays
     directly).  Content is BIT-IDENTICAL to what ``fleet.fleet_episode``
-    synthesizes on device for the same (seed, slot index) — the pipelined
+    synthesizes on device for the same (seed, slot index) — the batched
     ``run()`` over a ``DeviceScene`` is therefore the episode runner's
     equivalence reference."""
 

@@ -44,7 +44,7 @@ class Watchdog:
 
     def rebaseline(self) -> None:
         """Forget the EMA/variance baseline (fresh warmup) but KEEP the
-        event log.  Call on a mode change: after a supervisor degrades (or
+        event log.  Call on a mode change: after a serving ladder degrades (or
         recovers) the step-time distribution shifts wholesale, and gating
         the new mode's first steps against the old mode's baseline either
         mis-flags every step (degrade to a slower rung) or masks real

@@ -37,12 +37,12 @@ the shape a real fleet service runs:
     recompiles after restore.
 
   * **SLO supervision.**  An ``ft.Watchdog`` over window turnaround times
-    drives a degraded-mode ladder — full-bucket episode windows ->
-    smaller-bucket episode chunks -> the pipelined per-slot loop — and
-    climbs back up after ``recover_after`` consecutive healthy windows.
-    Every rung serves THE SAME carry chain (the smaller rungs are exact,
-    not approximations), so degradation changes latency shape only, never
-    numerics; the watchdog re-baselines on every rung change
+    drives a two-rung degraded-mode ladder — full-bucket episode windows ->
+    smaller-bucket episode chunks — and climbs back up after
+    ``recover_after`` consecutive healthy windows.  Both rungs serve THE
+    SAME carry chain (the chunks thread it, so the small rung is exact,
+    not an approximation), so degradation changes latency shape only,
+    never numerics; the watchdog re-baselines on every rung change
     (``Watchdog.rebaseline``) so the old rung's timing distribution never
     mis-gates the new one.
 
@@ -84,7 +84,7 @@ LOG_KEYS = ("utility", "mean_f1", "bytes", "W", "extra", "area",
 
 # the degraded-mode ladder: every rung serves the same carry chain exactly
 # (see _dispatch_window), so a rung change is a latency decision only
-LADDER = ("episode", "episode_small", "pipelined")
+LADDER = ("episode", "episode_small")
 
 # window turnarounds and events a runner keeps: an always-on stream must not
 # grow per window
@@ -299,16 +299,10 @@ class StreamingFleetRunner:
 
     def _dispatch_window(self, W: np.ndarray, live: np.ndarray
                          ) -> Dict[str, np.ndarray]:
-        """One window at the current ladder rung.  Every rung threads the
+        """One window at the current ladder rung.  Both rungs thread the
         SAME carry chain — ``episode_small`` chains the carry through each
-        smaller-bucket chunk and ``pipelined`` seeds the per-slot loop from
-        it — so rung changes are numerically invisible."""
-        mode = LADDER[self.rung]
-        if mode == "pipelined":
-            return self.system._run_batched(
-                self.scene, W, self.method, self.use_elastic, faults=live,
-                carry=self.carry)
-        step = len(W) if mode == "episode" else self._small_len()
+        smaller-bucket chunk — so rung changes are numerically invisible."""
+        step = len(W) if LADDER[self.rung] == "episode" else self._small_len()
         parts = []
         for i0 in range(0, len(W), step):
             i1 = min(i0 + step, len(W))
@@ -414,7 +408,9 @@ class StreamingFleetRunner:
         generation history to the newest checkpoint that verifies.
         Rebuilds the full serving state: device carry, codec run key, scene
         cursor (the scene is pure in (seed, t) — no frames are stored),
-        accumulated logs and counters, ladder rung.  The caller then
+        accumulated logs and counters, ladder rung (clamped to
+        ``LADDER``: an older generation may name a rung the ladder no
+        longer has, which resumes at its bottom rung).  The caller then
         re-offers the stream from ``t_next``; zero recompiles — the
         restored carry re-enters the exact executables the pre-crash
         process compiled."""
@@ -438,7 +434,7 @@ class StreamingFleetRunner:
             t_first=int(meta["t_first"]))
         self.scene._t = int(meta["t_next"])
         self.window = int(meta["window"])
-        self.rung = int(meta["rung"])
+        self.rung = min(int(meta["rung"]), len(LADDER) - 1)
         self.ok_streak = int(meta["ok_streak"])
         self.dropped_slots = int(meta["dropped_slots"])
         self.quarantined = {str(k): int(v) for k, v in

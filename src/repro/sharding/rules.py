@@ -84,44 +84,6 @@ def cached_sharded_jit(fn, statics: dict, mesh: Optional[Mesh], in_specs,
     return got
 
 
-def unshard(x, mesh: Optional[Mesh]) -> jax.Array:
-    """Gather a camera-sharded device array onto the mesh's FIRST device.
-
-    The control loop (elastic controller + bandwidth allocator) runs outside
-    the camera mesh — the knapsack DP is a sequential cross-camera
-    recurrence with nothing to shard — so its (C,) feature inputs cross the
-    shard boundary here as ONE device-to-device gather, never a host
-    round-trip (transfer-guard safe); ``reshard_replicated`` broadcasts the
-    resulting (b, r) back onto the mesh for the sharded slot-step.
-    Single-device placement rather than mesh-wide replication on purpose: a
-    replicated control program executes its interpret-mode Pallas DP once
-    PER device (N x GIL-bound python emulation on fake CPU devices —
-    measured 10x slower at C=16); one replica computes the identical
-    result.  No-op when unsharded or already resident on that device (so
-    wrapper-level and caller-level gathers compose without a second
-    device_put)."""
-    if mesh is None:
-        return x
-    dev = mesh.devices.flat[0]
-    try:
-        if x.devices() == {dev}:
-            return x
-    except (AttributeError, TypeError):
-        pass
-    return jax.device_put(x, dev)
-
-
-def reshard_replicated(x, mesh: Optional[Mesh]) -> jax.Array:
-    """Broadcast a single-device array to mesh-wide replication — the
-    return leg of ``unshard``: committed single-device arrays can't feed a
-    jit whose other operands are mesh-committed (jit only auto-moves
-    UNcommitted data), so the control step's (b, r) outputs cross back
-    through this tiny device-to-device broadcast.  No-op when unsharded."""
-    if mesh is None:
-        return x
-    return jax.device_put(x, NamedSharding(mesh, P()))
-
-
 def pad_leading(x, n: int, fill=0) -> jax.Array:
     """Pad a camera-leading array to n rows with `fill` (inert cameras the
     sharded executables compute and the wrappers slice back off)."""

@@ -4,7 +4,7 @@ One place owns three things the scenario tests, the Makefile CI lanes and
 the golden fixture all need:
 
   * **cell runners** — build canonical ``DeepStreamSystem``s per runner
-    mode (sequential / batched / pipelined / episode) over a named scene
+    mode (sequential / batched / episode) over a named scene
     family, run one (method, trace-family, T) cell with a fixed PRNG
     stream, and assert cross-mode log equivalence.  All modes share ONE
     pinned DP capacity (``W_CAP_KBPS``) so every cell of the matrix — any
@@ -18,7 +18,7 @@ the golden fixture all need:
     shrinks the family matrix (``default_families``).
   * **the golden-log writer** — ``python tests/harness.py --write-golden``
     regenerates ``tests/golden/golden_logs.json`` (per-method
-    utility/bytes/alloc logs of the pipelined reference on one fixed
+    utility/bytes/alloc logs of the host-control reference on one fixed
     (scene seed, trace seed)); ``tests/test_scenarios.py`` asserts today's
     code still reproduces it to <= 1e-5.  Regenerate ONLY on an
     intentional numerics change, and say so in the PR.
@@ -71,17 +71,15 @@ LANES = {
 
 METHODS = ("deepstream", "jcab", "reducto", "static")
 
-# runner modes under differential test.  "batched" is the PR 1 shape (one
-# fleet program per slot, blocking, host allocator); "pipelined" the
-# deferred-harvest device-alloc loop; "episode" the whole-trace scan.
-# "sequential" (the per-camera Python reference) is run on a reduced slice
-# — it is ~10x slower per slot and its equivalence vs "batched" is already
-# pinned by tests/test_fleet.py on several seeds.
+# runner modes under differential test.  "batched" is the host-control
+# reference (one fleet program per slot, numpy allocator); "episode" the
+# whole-trace scan, the only device-control path.  "sequential" (the
+# per-camera Python reference) is run on a reduced slice — it is ~10x
+# slower per slot and its equivalence vs "batched" is already pinned by
+# tests/test_fleet.py on several seeds.
 MODES = {
     "sequential": dict(batched=False),
-    "batched": dict(batched=True, shard="off", pipeline=False, donate=False,
-                    alloc="host"),
-    "pipelined": dict(batched=True, episode=False),
+    "batched": dict(batched=True, shard="off"),
     "episode": dict(batched=True, episode=True),
 }
 
@@ -180,14 +178,15 @@ def assert_logs_match(ref: dict, got: dict, *, tol: float = 1e-5,
 # -- golden fixture -----------------------------------------------------------
 
 def golden_reference_logs(detectors) -> dict:
-    """Per-method pipelined-reference logs for the golden (scene, trace)."""
+    """Per-method host-control reference logs for the golden (scene,
+    trace)."""
     from repro.data.scenarios import make_scene
 
     fam_s, seed_s = GOLDEN_SCENE
     fam_t, T, seed_t = GOLDEN_TRACE
     out = {}
     for method in METHODS:
-        s = build_system(detectors, "pipelined", make_scene(fam_s, seed_s))
+        s = build_system(detectors, "batched", make_scene(fam_s, seed_s))
         logs = run_cell(s, method, fam_t, T,
                         scene_seed=seed_s, trace_seed=seed_t)
         out[method] = {k: [float(v) for v in logs[k]] for k in LOG_KEYS}
@@ -197,7 +196,7 @@ def golden_reference_logs(detectors) -> dict:
 def write_golden(path: Path = GOLDEN_PATH) -> Path:
     light, server = train_default_detectors()
     doc = {
-        "comment": ("Pipelined-reference logs pinning today's numerics; "
+        "comment": ("Host-control reference logs pinning today's numerics; "
                     "regenerate with `python tests/harness.py "
                     "--write-golden` only on an INTENTIONAL numerics "
                     "change and call it out in the PR"),

@@ -278,9 +278,9 @@ def test_manifest_covers_the_matrix():
     names = list(_golden()["executables"])
     episodes = [n for n in names if n.startswith("episode/")]
     assert len(episodes) == len(METHODS) * len(EPISODE_BUCKETS), episodes
-    assert "slot_step/unified" in names
-    for m in METHODS:
-        assert f"ctrl/{m}" in names and f"ctrl_scan/{m}" in names
+    # the episode matrix plus the host-control loop's slot-step: the
+    # episode scan is the only device-control program
+    assert sorted(names) == sorted(episodes + ["slot_step/unified"]), names
 
 
 @no_fake_devices

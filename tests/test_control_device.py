@@ -1,7 +1,7 @@
-"""Device-resident control loop: traced elastic/allocation equivalence vs the
-numpy reference, full-loop device-vs-host log equivalence for all four
-methods, and the zero-per-slot-sync (transfer-guard / fetch-counter)
-guarantee."""
+"""Device-resident control: traced elastic/allocation equivalence vs the
+numpy reference, and full-loop device-vs-host log equivalence (the episode
+scan against the host-control batched loop) for all four methods under
+camera churn."""
 import functools
 
 import jax
@@ -12,13 +12,12 @@ import pytest
 from repro.core import allocation as alloc
 from repro.core import codec as codec_mod
 from repro.core import elastic as elastic_mod
-from repro.core import fleet as fleet_mod
-from repro.core import scheduler as sched_mod
-from repro.core import utility as util_mod
 from repro.core.codec import CodecConfig
 from repro.core.elastic import ElasticConfig, ElasticState
 from repro.core.scheduler import DeepStreamSystem, SystemConfig
-from repro.data.synthetic import MultiCameraScene, SceneConfig, bandwidth_trace
+from repro.data.scenarios import make_faults
+from repro.data.synthetic import (DeviceScene, MultiCameraScene, SceneConfig,
+                                  bandwidth_trace)
 
 
 # ---------------------------------------------------------------------------
@@ -195,20 +194,20 @@ def test_greedy_crosses_zero_gain_plateaus():
 
 
 # ---------------------------------------------------------------------------
-# full-loop device-vs-host equivalence + the zero-sync guarantee
+# full-loop device-vs-host equivalence under camera churn
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
-def alloc_pair(detectors):
-    """Two batched systems over the same trained artifacts: host-numpy
-    control loop vs the device-resident one."""
+def control_pair(detectors):
+    """Two systems over the same trained artifacts: the host-numpy control
+    loop (batched) and the device-resident one (the episode scan)."""
     light, server = detectors
     pair = {}
-    for mode in ("host", "device"):
+    for episode in (False, True):
         cfg = SystemConfig(scene=SceneConfig(seed=5, num_cameras=3),
-                           eval_frames=3, batched=True, alloc=mode)
-        pair[mode] = DeepStreamSystem(cfg, light, server)
-    host, dev = pair["host"], pair["device"]
+                           eval_frames=3, batched=True, episode=episode)
+        pair[episode] = DeepStreamSystem(cfg, light, server)
+    host, dev = pair[False], pair[True]
     prof = MultiCameraScene(SceneConfig(seed=42, num_cameras=3))
     host.profile(prof, num_slots=2, mlp_steps=120)
     dev.mlp, dev.tau_wl, dev.tau_wh = host.mlp, host.tau_wl, host.tau_wh
@@ -218,87 +217,23 @@ def alloc_pair(detectors):
 
 @pytest.mark.parametrize("method", ["deepstream", "jcab", "static",
                                     "reducto"])
-def test_run_device_control_matches_host(alloc_pair, method):
+def test_run_device_control_matches_host(control_pair, method):
     """Acceptance: the on-device control loop reproduces the host path's
-    utility (and control) logs to <= 1e-5 for every method."""
+    utility (and control) logs to <= 1e-5 for every method, with cameras
+    leaving and rejoining (the liveness mask and the reconnect clamp run
+    through both control paths)."""
+    trace = bandwidth_trace("medium", 4, seed=8) * 3 / 5
+    faults = make_faults("camera_churn", 4, 3, seed=4)
+    assert not faults.all()
     logs = {}
-    for name, s in zip(("host", "device"), alloc_pair):
+    for name, s in zip(("host", "device"), control_pair):
         s._key = jax.random.PRNGKey(1234)
-        scene = MultiCameraScene(SceneConfig(seed=33, num_cameras=3))
-        trace = bandwidth_trace("medium", 3, seed=8) * 3 / 5
-        logs[name] = s.run(scene, trace, method=method)
+        scene = DeviceScene(SceneConfig(seed=33, num_cameras=3))
+        logs[name] = s.run(scene, trace, method=method, faults=faults)
     for k, tol in (("utility", 1e-5), ("bytes", 1e-3), ("alloc_kbps", 1e-3),
                    ("extra", 1e-3), ("area", 1e-4)):
         np.testing.assert_allclose(logs["device"][k], logs["host"][k],
                                    atol=tol, err_msg=(method, k))
-
-
-def test_device_loop_zero_control_syncs(alloc_pair):
-    """The device-resident loop performs ZERO per-slot (a, c) control
-    fetches (the CPU-checkable transfer-guard analogue) and stays clean
-    under the real device-to-host transfer guard; the host loop performs
-    one control fetch per slot."""
-    host, dev = alloc_pair
-    scene = MultiCameraScene(SceneConfig(seed=7, num_cameras=3))
-    trace = bandwidth_trace("medium", 3, seed=4) * 3 / 5
-    n0 = sched_mod.d2h_fetch_counts().get("control", 0)
-    with jax.transfer_guard_device_to_host("disallow"):
-        for method in ("deepstream", "jcab", "static", "reducto"):
-            dev.run(MultiCameraScene(SceneConfig(seed=7, num_cameras=3)),
-                    trace, method=method)
-    assert sched_mod.d2h_fetch_counts().get("control", 0) == n0
-    host.run(scene, trace, method="deepstream")
-    assert sched_mod.d2h_fetch_counts()["control"] == n0 + len(trace)
-
-
-def test_control_step_compiles_once_per_method(alloc_pair):
-    """Re-running a method must not re-trace its control program (the trace
-    capacity is bucketed, so same-bucket traces share one executable)."""
-    _, dev = alloc_pair
-    trace = bandwidth_trace("medium", 2, seed=3) * 3 / 5
-    dev.run(MultiCameraScene(SceneConfig(seed=11, num_cameras=3)), trace,
-            method="deepstream")
-    n0 = fleet_mod.control_compile_count()
-    dev.run(MultiCameraScene(SceneConfig(seed=12, num_cameras=3)), trace,
-            method="deepstream")
-    assert fleet_mod.control_compile_count() == n0
-
-
-def test_control_scan_matches_step_loop():
-    """The lax.scan-over-slots control variant == per-slot control steps."""
-    rng = np.random.default_rng(0)
-    bitr, res = (50, 100, 200, 400, 800, 1000), (1.0, 0.75, 0.5)
-    ecfg = ElasticConfig()
-    params = util_mod.init_utility_mlp(jax.random.PRNGKey(0))
-    C, T = 4, 5
-    lam = jnp.ones(C, jnp.float32)
-    a_tr = rng.uniform(0, 1, (T, C)).astype(np.float32)
-    c_tr = rng.uniform(0, 1, (T, C)).astype(np.float32)
-    W_tr = rng.uniform(200, 2500, T).astype(np.float32)
-    statics = dict(ecfg=ecfg, bitrates=bitr, resolutions=res,
-                   slot_seconds=1.0, use_elastic=True, use_kernel=True,
-                   w_cap=alloc.dp_capacity(bitr, float(W_tr.max())
-                                           + ecfg.budget_kbits),
-                   num_cams=C)
-    est = elastic_mod.init_state_jax()
-    step_b, step_packs = [], []
-    for t in range(T):
-        co = fleet_mod.fleet_control_step(
-            "deepstream", params, None, None, lam, jnp.asarray(a_tr[t]),
-            jnp.asarray(c_tr[t]), jnp.float32(W_tr[t]), est,
-            jnp.float32(700.0), jnp.float32(1000.0), **statics)
-        est = co.est
-        step_b.append(np.asarray(co.b))
-        step_packs.append(np.asarray(co.pack))
-    b_s, r_s, packs, est_f = fleet_mod.fleet_control_scan(
-        "deepstream", params, None, None, lam, a_tr, c_tr, W_tr,
-        elastic_mod.init_state_jax(), jnp.float32(700.0),
-        jnp.float32(1000.0), **statics)
-    np.testing.assert_array_equal(np.asarray(b_s), np.stack(step_b))
-    np.testing.assert_allclose(np.asarray(packs), np.stack(step_packs),
-                               atol=1e-5)
-    assert float(est_f.debt_kbits) == pytest.approx(float(est.debt_kbits),
-                                                    abs=1e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,5 @@
-"""Whole-trace device-resident episodes: episode-vs-pipelined equivalence
-for every method, the zero-per-slot-transfer guarantee (fetch counters +
+"""Whole-trace device-resident episodes: equivalence with the host-control
+batched loop for every method, the zero-per-slot-transfer guarantee (fetch counters +
 transfer guard, no scoped exemptions), the episode operands (cached
 invariants plus one batched put) against the eager construction they
 replace, traced keep-selection math vs the host mirror, and device-side
@@ -44,15 +44,17 @@ def _system(detectors, episode: bool) -> DeepStreamSystem:
 
 @pytest.fixture(scope="module")
 def episode_pair(detectors):
-    """(pipelined reference, episode) systems over shared artifacts."""
+    """(host-control batched reference, episode) systems over shared
+    artifacts."""
     return _system(detectors, episode=False), _system(detectors, episode=True)
 
 
 @pytest.mark.parametrize("method", METHODS)
 def test_run_episode_matches_pipelined(episode_pair, method):
-    """Acceptance: one lax.scan episode reproduces the pipelined loop's
-    utility/bytes/alloc logs (<= 1e-5) for all four methods — identical
-    device-generated segments, keys, keep-flags and control trajectory."""
+    """Acceptance: one lax.scan episode reproduces the host-control batched
+    loop's utility/bytes/alloc logs (<= 1e-5) for all four methods —
+    identical device-generated segments, keys and keep-flags, and a traced
+    control trajectory equal to the numpy one."""
     logs = {}
     for s in episode_pair:
         s._key = jax.random.PRNGKey(1234)

@@ -1,5 +1,6 @@
-"""Fault-tolerance tests: traced camera churn, link-fault injection,
-checkify-guarded invariants and the watchdog-supervised recovery ladder.
+"""Fault-tolerance tests: traced camera churn, link-fault injection and
+checkify-guarded invariants.  (The watchdog recovery ladder is the stream
+runner's, covered in tests/test_serve_stream.py.)
 
 The contract under test (``fleet.fleet_episode`` / ``scheduler.run``
 docstrings): a dead (camera, slot) cell is an *inert camera* — zero bits and
@@ -10,7 +11,8 @@ recompiles) and keep the episode path's zero-per-slot-transfer guarantee.
 
 Headline differential: a fleet with one camera dead for the WHOLE trace must
 log identically (<= 1e-5) to a fleet that never had that camera — across all
-four methods and all three fault-capable runner modes.  The absent fleet's
+four methods, both fault-capable runner modes and the windowed stream
+runner.  The absent fleet's
 scene params are ROW-SLICED from the full fleet's (not re-drawn at C-1:
 ``init_device_scene`` consumes rng per camera, so a fresh (C-1)-camera scene
 has different geometry).
@@ -29,11 +31,12 @@ from repro.core import scheduler as sched_mod
 from repro.data import scenarios
 from repro.data.scenarios import make_faults, make_trace
 from repro.data.synthetic import DeviceScene, DeviceSceneParams, SceneConfig
+from repro.serve.stream import StreamConfig, StreamingFleetRunner
 
 C = 3          # full fleet size (absent fleet = C - 1)
 T = 4          # fits the first episode bucket
 
-FAULT_MODES = ("batched", "pipelined", "episode")
+FAULT_MODES = ("batched", "episode")
 
 
 def _scene_cfg(num_cameras: int = C, seed: int = 33) -> SceneConfig:
@@ -120,7 +123,7 @@ def test_hard_outage_trace_has_true_zero_slots():
 # ---------------------------------------------------------------------------
 
 def test_run_rejects_malformed_faults(systems, detectors):
-    s = systems["pipelined"]
+    s = systems["batched"]
     scene = DeviceScene(_scene_cfg())
     trace = make_trace("fcc_medium", 3, seed=8, num_cams=C)
     with pytest.raises(ValueError, match="must be"):
@@ -149,16 +152,32 @@ def test_slot_camera_keys_fleet_size_independent():
 # the headline differential: dead camera == fleet that never had it
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("mode", FAULT_MODES)
+def _stream(system, scene, trace, method, faults=None):
+    """Serve ``trace`` through the windowed stream runner over two
+    windows, the device carry handed across the boundary."""
+    system._key = jax.random.PRNGKey(1234)
+    runner = StreamingFleetRunner(system, scene, method=method,
+                                  cfg=StreamConfig(window_slots=T // 2))
+    runner.offer(trace, faults=faults)
+    assert runner.serve() == 2
+    return {k: np.asarray(v) for k, v in runner.logs.items()}
+
+
+@pytest.mark.parametrize("mode", FAULT_MODES + ("stream",))
 @pytest.mark.parametrize("method", harness.METHODS)
 def test_dead_camera_equals_absent(systems, absent_systems, mode, method):
     full_scene, absent_scene = _paired_scenes()
     trace = make_trace("fcc_medium", T, seed=8, num_cams=C)
     faults = np.ones((T, C), bool)
     faults[:, C - 1] = False
-    got = _run(systems[mode], full_scene, trace, method=method,
-               faults=faults)
-    ref = _run(absent_systems[mode], absent_scene, trace, method=method)
+    if mode == "stream":
+        got = _stream(systems["episode"], full_scene, trace, method,
+                      faults=faults)
+        ref = _stream(absent_systems["episode"], absent_scene, trace, method)
+    else:
+        got = _run(systems[mode], full_scene, trace, method=method,
+                   faults=faults)
+        ref = _run(absent_systems[mode], absent_scene, trace, method=method)
     harness.assert_logs_match(ref, got, tol=1e-5,
                               ctx=f"dead!=absent mode={mode} {method}")
 
@@ -175,9 +194,8 @@ def test_fault_cross_mode_equivalence(systems, family):
     logs = {m: _run(systems[m], DeviceScene(_scene_cfg()), trace,
                     faults=faults)
             for m in FAULT_MODES}
-    for mode in ("pipelined", "episode"):
-        harness.assert_logs_match(logs["batched"], logs[mode],
-                                  ctx=f"{family} batched-vs-{mode}")
+    harness.assert_logs_match(logs["batched"], logs["episode"],
+                              ctx=f"{family} batched-vs-episode")
 
 
 def test_fault_episode_stays_device_resident(systems):
@@ -189,15 +207,14 @@ def test_fault_episode_stays_device_resident(systems):
     _run(s, DeviceScene(_scene_cfg()), trace,
          faults=make_faults("camera_churn", T, C, seed=2))      # warm
     before = sched_mod.d2h_fetch_counts()
-    compiles = (fleet_mod.compile_count(), fleet_mod.control_compile_count(),
-                fleet_mod.episode_compile_count())
+    compiles = (fleet_mod.compile_count(), fleet_mod.episode_compile_count())
     _run(s, DeviceScene(_scene_cfg()), trace,
          faults=make_faults("camera_churn", T, C, seed=9))
     after = sched_mod.d2h_fetch_counts()
     assert after["keep"] == before["keep"]
     assert after["control"] == before["control"]
     assert after["harvest"] - before["harvest"] == 2
-    assert (fleet_mod.compile_count(), fleet_mod.control_compile_count(),
+    assert (fleet_mod.compile_count(),
             fleet_mod.episode_compile_count()) == compiles
 
 
@@ -238,7 +255,7 @@ def test_allocators_zero_capacity_all_zero_infeasible():
 def test_zero_capacity_slot_sends_nothing(systems):
     trace = make_trace("fcc_medium", T, seed=8, num_cams=C).copy()
     trace[1] = 0.0          # one hard_outage-style slot mid-trace
-    for mode in ("pipelined", "episode"):
+    for mode in FAULT_MODES:
         # elastic off: WITH elastic a hard-outage slot may legitimately
         # borrow against the debt budget (W_eff = W + extra > 0) — the
         # zero-capacity clamp is about true zero effective capacity
@@ -287,7 +304,7 @@ def test_elastic_reset_debt_host_and_jax_agree():
 @pytest.fixture(scope="module")
 def checked_systems(detectors):
     out = {}
-    for mode in ("pipelined", "episode"):
+    for mode in FAULT_MODES:
         s = harness.build_system(detectors, mode, _scene_cfg())
         s.cfg.checked = True
         s.cfg.__post_init__()       # re-derive the forced-off knobs
@@ -296,7 +313,7 @@ def checked_systems(detectors):
     return out
 
 
-@pytest.mark.parametrize("mode", ("pipelined", "episode"))
+@pytest.mark.parametrize("mode", FAULT_MODES)
 def test_checked_run_matches_unchecked(systems, checked_systems, mode):
     trace = make_trace("fcc_medium", T, seed=8, num_cams=C)
     faults = make_faults("camera_churn", T, C, seed=4)
@@ -307,94 +324,9 @@ def test_checked_run_matches_unchecked(systems, checked_systems, mode):
     harness.assert_logs_match(ref, got, ctx=f"checked {mode}")
 
 
-@pytest.mark.parametrize("mode", ("pipelined", "episode"))
+@pytest.mark.parametrize("mode", FAULT_MODES)
 def test_checked_run_catches_nonfinite_bandwidth(checked_systems, mode):
     trace = make_trace("fcc_medium", T, seed=8, num_cams=C).copy()
     trace[2] = np.nan
     with pytest.raises(Exception, match="(?i)finite|bandwidth"):
         _run(checked_systems[mode], DeviceScene(_scene_cfg()), trace)
-
-
-# ---------------------------------------------------------------------------
-# watchdog-supervised recovery
-# ---------------------------------------------------------------------------
-
-def test_supervisor_retries_then_degrades_to_chunked(systems):
-    calls = []
-
-    def hook(attempt, mode):
-        calls.append((attempt, mode))
-        if mode == "episode":
-            raise RuntimeError("injected dispatch failure")
-
-    sup = sched_mod.EpisodeSupervisor(
-        systems["episode"], sched_mod.SupervisorConfig(max_retries=1),
-        fault_hook=hook)
-    trace = make_trace("fcc_medium", T, seed=8, num_cams=C)
-    logs = sup.run(DeviceScene(_scene_cfg()), trace, method="static")
-    assert len(logs["utility"]) == T
-    assert [(e["kind"], e["mode"]) for e in sup.events] == [
-        ("retry", "episode"), ("retry", "episode"),
-        ("degrade", "episode"), ("ok", "episode_chunked")]
-    assert sup.mode == "episode_chunked"        # rung is sticky
-    # and the NEXT run goes straight to the degraded rung
-    sup.run(DeviceScene(_scene_cfg()), trace, method="static")
-    assert sup.events[-1]["kind"] == "ok"
-    assert sup.events[-1]["mode"] == "episode_chunked"
-    assert all(m == "episode" for _, m in calls[:2])
-
-
-def test_supervisor_chunked_matches_episode_for_stateless_method(systems):
-    # 'static' threads no cross-slot carry (no elastic, no reducto), so the
-    # degraded chunked dispatch is exact, not an approximation.  T=12 spans
-    # two bucket-8 chunks (a T that fits one chunk would test nothing).
-    T12 = 12
-    trace = make_trace("fcc_medium", T12, seed=8, num_cams=C)
-    faults = make_faults("sensor_corrupt", T12, C, seed=1)
-    ref = _run(systems["episode"], DeviceScene(_scene_cfg()), trace,
-               method="static", faults=faults)
-    sup = sched_mod.EpisodeSupervisor(systems["episode"])
-    sup._rung = 1                                # force episode_chunked
-    assert sup._chunk_len(T12) == 8
-    systems["episode"]._key = jax.random.PRNGKey(1234)
-    got = sup.run(DeviceScene(_scene_cfg()), trace, method="static",
-                  faults=faults)
-    harness.assert_logs_match(ref, got, ctx="chunked-vs-episode static")
-
-
-def test_supervisor_watchdog_replace_degrades_next_run(systems):
-    class _AlwaysReplace:
-        rebaselines = 0
-
-        def record(self, step, t):
-            return "replace"
-
-        def rebaseline(self):
-            self.rebaselines += 1
-
-    sup = sched_mod.EpisodeSupervisor(systems["episode"])
-    sup.watchdog = _AlwaysReplace()
-    trace = make_trace("fcc_medium", T, seed=8, num_cams=C)
-    sup.run(DeviceScene(_scene_cfg()), trace, method="static")
-    # the straggling run itself succeeded at the fast rung...
-    ok = [e for e in sup.events if e["kind"] == "ok"]
-    assert ok[0]["mode"] == "episode"
-    # ...but the verdict degraded the NEXT run preemptively
-    deg = [e for e in sup.events if e["kind"] == "degrade"]
-    assert deg and deg[0]["cause"] == "watchdog"
-    assert sup.mode == "episode_chunked"
-    # the degraded rung starts from a fresh watchdog baseline
-    assert sup.watchdog.rebaselines == 1
-
-
-def test_supervisor_exhausts_ladder_and_raises(systems):
-    def hook(attempt, mode):
-        raise RuntimeError("chaos: everything fails")
-
-    sup = sched_mod.EpisodeSupervisor(
-        systems["pipelined"], sched_mod.SupervisorConfig(max_retries=0),
-        fault_hook=hook)
-    trace = make_trace("fcc_medium", 2, seed=8, num_cams=C)
-    with pytest.raises(RuntimeError, match="every mode rung"):
-        sup.run(DeviceScene(_scene_cfg()), trace)
-    assert [e["kind"] for e in sup.events] == ["retry"]   # one-rung ladder

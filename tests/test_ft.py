@@ -1,7 +1,7 @@
 """Unit tests for ``repro.ft.watchdog``: the EMA+sigma straggler gate, the
 simulated fleet it is exercised against, and the preemption-aware
-checkpointer.  (The gate's integration with episode dispatch is covered by
-tests/test_faults.py's EpisodeSupervisor tests.)"""
+checkpointer.  (The gate's integration with episode dispatch, the stream
+runner's degraded-mode ladder, is covered by tests/test_serve_stream.py.)"""
 import signal
 
 import numpy as np

@@ -1,8 +1,8 @@
 """Scenario matrix + cross-mode differential tests.
 
-The correctness story before this suite: the four runner modes (sequential /
-batched / pipelined / episode) were proven equivalent on a handful of
-hand-picked FCC-like seeds.  Here every (method x trace-family x runner-mode)
+The correctness story before this suite: the runner modes (sequential /
+batched / episode) were proven equivalent on a handful of hand-picked
+FCC-like seeds.  Here every (method x trace-family x runner-mode)
 cell of the scenario matrix is run on small shapes and cross-checked:
 
   * utility/bytes/alloc log equivalence across modes per cell;
@@ -13,7 +13,7 @@ cell of the scenario matrix is run on small shapes and cross-checked:
     mixed-(family, seed, T) matrix shares compiled programs;
   * one episode executable per (method, bucket) serves every T (bucket
     padding diffs <= 1e-5 vs the unbucketed program);
-  * golden-log regression: the pipelined reference must keep reproducing
+  * golden-log regression: the host-control reference must keep reproducing
     the committed per-method logs, so numerics can't silently shift;
   * trace-family properties (floor, paper stats, autocorrelation,
     determinism — including cross-process determinism, the
@@ -52,7 +52,7 @@ def mx(detectors):
     cell reuses the same compiled programs)."""
     scene_cfg = make_scene("urban_mid", 5)
     return {mode: harness.build_system(detectors, mode, scene_cfg)
-            for mode in ("batched", "pipelined", "episode")}
+            for mode in ("batched", "episode")}
 
 
 # ---------------------------------------------------------------------------
@@ -70,7 +70,7 @@ def test_cross_mode_matrix(mx, method):
         n_slot0 = fleet_mod.compile_count()
         n_ep0 = fleet_mod.episode_compile_count()
         logs = {}
-        for mode in ("batched", "pipelined", "episode"):
+        for mode in ("batched", "episode"):
             d0 = sched_mod.d2h_fetch_counts()
             logs[mode] = harness.run_cell(mx[mode], method, family, T,
                                           trace_seed=17 + i)
@@ -85,10 +85,8 @@ def test_cross_mode_matrix(mx, method):
             # suite must never trace another fleet program
             assert fleet_mod.compile_count() == n_slot0, ctx
             assert fleet_mod.episode_compile_count() == n_ep0, ctx
-        harness.assert_logs_match(logs["pipelined"], logs["batched"],
-                                  ctx=ctx + " batched-vs-pipelined")
-        harness.assert_logs_match(logs["pipelined"], logs["episode"],
-                                  ctx=ctx + " episode-vs-pipelined")
+        harness.assert_logs_match(logs["batched"], logs["episode"],
+                                  ctx=ctx + " episode-vs-batched")
 
 
 def test_sequential_cross_mode_slice(mx, detectors):
@@ -100,7 +98,7 @@ def test_sequential_cross_mode_slice(mx, detectors):
                                make_scene("urban_mid", 5))
     for method in METHODS:
         ref = harness.run_cell(seq, method, FAMILIES[0], 2)
-        for mode in ("batched", "pipelined", "episode"):
+        for mode in ("batched", "episode"):
             got = harness.run_cell(mx[mode], method, FAMILIES[0], 2)
             # the sequential control path is float64 numpy — equivalence to
             # the f32 device programs is to rounding (1e-3, the test_fleet
@@ -172,12 +170,12 @@ def test_bucketed_matches_unbucketed(detectors):
 
 def test_bucketed_episode_resume(mx, detectors):
     """Back-to-back episodes on ONE reused scene (the second run resumes at
-    t_start=3; both pad to bucket 8) reproduce a pipelined run over the same
+    t_start=3; both pad to bucket 8) reproduce a batched run over the same
     slots split the same way — t_start stays a data value under bucketing
     and the sliced key chain threads runs together correctly."""
     import dataclasses
     ep = mx["episode"]
-    pi = mx["pipelined"]
+    pi = mx["batched"]
     tr = make_trace("step_drop", 6, seed=3, num_cams=3)
     for method in ("deepstream", "reducto"):
         logs = {}
@@ -215,7 +213,7 @@ def test_bucketed_episode_fetch_counts(mx):
 # ---------------------------------------------------------------------------
 
 def test_golden_logs_regression(detectors):
-    """The committed pipelined-reference logs must keep reproducing: any
+    """The committed host-control reference logs must keep reproducing: any
     future PR that shifts numerics now fails loudly instead of silently
     re-baselining itself through the cross-mode equivalence tests (which
     compare modes only against each other)."""
@@ -263,29 +261,30 @@ def test_scene_family_motion_energy_ordering():
 
 @pytest.mark.parametrize("family", _scene_family_subset())
 def test_scene_family_differential(detectors, family):
-    """Cross-mode equivalence holds on every scene family too (batched vs
-    pipelined, deepstream — the content-dependent route: ROI masks, (a, c)
-    features and elastic state all vary with the scene)."""
+    """Cross-mode equivalence holds on every scene family too (episode vs
+    the host-control batched loop, deepstream — the content-dependent
+    route: ROI masks, (a, c) features and elastic state all vary with the
+    scene)."""
     scene_cfg = make_scene(family, 5)
     logs = {}
-    for mode in ("batched", "pipelined"):
+    for mode in ("batched", "episode"):
         s = harness.build_system(detectors, mode, scene_cfg)
         logs[mode] = harness.run_cell(s, "deepstream", "fcc_medium", 2,
                                       scene_seed=41)
-    harness.assert_logs_match(logs["pipelined"], logs["batched"],
+    harness.assert_logs_match(logs["batched"], logs["episode"],
                               ctx=f"scene family={family}")
 
 
 def test_scene_family_episode_small_fleet(detectors):
-    """The episode runner holds its pipelined equivalence off the default
+    """The episode runner holds its batched equivalence off the default
     camera count too (C=2, the smallest fleet the allocator sees)."""
     scene_cfg = make_scene("cam_pair", 5)
     logs = {}
-    for mode in ("pipelined", "episode"):
+    for mode in ("batched", "episode"):
         s = harness.build_system(detectors, mode, scene_cfg)
         logs[mode] = harness.run_cell(s, "deepstream", "step_drop", 3,
                                       scene_seed=23)
-    harness.assert_logs_match(logs["pipelined"], logs["episode"],
+    harness.assert_logs_match(logs["batched"], logs["episode"],
                               ctx="scene family=cam_pair episode")
 
 

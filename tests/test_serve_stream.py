@@ -13,8 +13,8 @@ pre-crash process compiled) and the episode-mode D2H contract intact
 Also here: windowed == continuous (no crash at all), a warm window's
 operands arriving in one put with the cached invariants (and the cache
 rebuilt when a threshold changes), the SLO watchdog
-ladder (degrade under injected stragglers -> pipelined, recover, logs STILL
-exact — every rung serves the same carry chain), bounded-queue load
+ladder (degrade under injected stragglers -> episode_small, recover, logs
+STILL exact — every rung serves the same carry chain), bounded-queue load
 shedding with drop accounting that survives restore, a small soak, and the
 ServeEngine drain-budget starvation regression.
 """
@@ -304,37 +304,36 @@ def test_restore_without_checkpoint_is_fresh_start(detectors, tmp_path):
 
 # -- SLO watchdog ladder -------------------------------------------------------
 
-def test_watchdog_ladder_degrades_recovers_exactly(detectors):
-    """Injected straggler walls drive the ladder episode ->
-    episode_small -> pipelined; healthy walls climb it back.  Every rung
-    threads the SAME carry chain, so the mixed-rung stream's logs STILL
-    match the uninterrupted episode reference."""
-    T, WIN = 40, 4
+@pytest.mark.parametrize("method", harness.METHODS)
+def test_watchdog_ladder_degrades_recovers_exactly(detectors, method):
+    """An injected straggler wall drives the ladder episode ->
+    episode_small; healthy walls climb it back.  A 16-slot window at the
+    small rung is served as two 8-slot chunks with the carry (elastic
+    state, reducto reference, liveness row) threaded between them, so the
+    mixed-rung stream's logs STILL match the uninterrupted episode
+    reference for every method."""
+    T, WIN = 96, 16
     scfg, trace, faults = _stream_inputs(T, "camera_flap")
-    ref = _continuous_reference(detectors, scfg, trace, faults, "deepstream")
-
-    # synthetic turnaround schedule (seconds), indexed by window: healthy
-    # baseline 1.0 with straggler spikes at windows 2 and 4
-    walls = {2: 6.0, 4: 6.0}
+    ref = _continuous_reference(detectors, scfg, trace, faults, method)
 
     cfg = StreamConfig(
         window_slots=WIN, queue_slots=T, recover_after=2,
         watchdog=WatchdogConfig(warmup_steps=1, escalate_after=1))
-    runner = _runner(detectors, scfg, "deepstream", cfg,
-                     wall_hook=lambda w, wall: walls.get(w, 1.0))
+    runner = _runner(detectors, scfg, method, cfg,
+                     wall_hook=lambda w, wall: 6.0 if w == 2 else 1.0)
+    assert runner._small_len() == WIN // 2
     runner.offer(trace, faults=faults)
-    runner.serve(flush=True)
+    assert runner.serve() == T // WIN
 
     kinds = [(e["kind"], e.get("to")) for e in runner.events
              if e["kind"] in ("degrade", "recover")]
-    assert kinds == [("degrade", "episode_small"),
-                     ("degrade", "pipelined"),
-                     ("recover", "episode_small"),
-                     ("recover", "episode")]
+    assert kinds == [("degrade", "episode_small"), ("recover", "episode")]
+    rungs = [e["rung"] for e in runner.events if e["kind"] == "window"]
+    assert rungs == ["episode"] * 3 + ["episode_small"] * 2 + ["episode"]
     assert runner.rung == 0 and runner.stats()["rung"] == LADDER[0]
     # ladder exactness: rung changes are numerically invisible
     harness.assert_logs_match(ref, _logs(runner), keys=STREAM_KEYS,
-                              ctx="ladder")
+                              ctx=f"ladder {method}")
 
 
 def test_watchdog_rebaseline_on_rung_change(detectors):
@@ -358,44 +357,92 @@ def test_watchdog_rebaseline_on_rung_change(detectors):
     assert len(degrades) == 1 and runner.rung == 1
 
 
+def test_straggler_at_bottom_rung_stays_there(detectors):
+    """A straggler verdict at the ladder's bottom rung has nowhere to go:
+    the runner keeps serving at ``episode_small`` and logs the verdict,
+    and the stream stays exact."""
+    T, WIN = 24, 4
+    scfg, trace, faults = _stream_inputs(T, "camera_flap")
+    ref = _continuous_reference(detectors, scfg, trace, faults, "static")
+    cfg = StreamConfig(
+        window_slots=WIN, queue_slots=T, recover_after=100,
+        watchdog=WatchdogConfig(warmup_steps=1, escalate_after=1))
+    runner = _runner(detectors, scfg, "static", cfg,
+                     wall_hook=lambda w, wall: 6.0 if w in (2, 4) else 1.0)
+    runner.offer(trace, faults=faults)
+    assert runner.serve() == T // WIN
+    windows = [e for e in runner.events if e["kind"] == "window"]
+    assert windows[4]["rung"] == LADDER[-1]
+    assert windows[4]["verdict"] == "replace"
+    assert [e["to"] for e in runner.events
+            if e["kind"] == "degrade"] == [LADDER[-1]]
+    assert runner.rung == len(LADDER) - 1
+    harness.assert_logs_match(ref, _logs(runner), keys=STREAM_KEYS,
+                              ctx="bottom rung")
+
+
+class _ScriptedDog:
+    """Scripted verdicts + a rebaseline call counter."""
+
+    def __init__(self, verdicts):
+        self.verdicts = list(verdicts)
+        self.rebaselines = 0
+
+    def record(self, step, t):
+        return self.verdicts.pop(0)
+
+    def rebaseline(self):
+        self.rebaselines += 1
+
+
 def test_supervisor_recovers_and_rebaselines(detectors):
-    """Regression (ladder recovery): ``EpisodeSupervisor`` must climb BACK
-    one rung after ``recover_after`` consecutive healthy runs at a degraded
+    """Regression (ladder recovery): the stream runner must climb BACK one
+    rung after ``recover_after`` consecutive healthy windows at a degraded
     rung, and EVERY rung change — degrade or recover — must rebaseline the
     watchdog so the new rung's EMA is never seeded from the other rung's
-    wall times.  Pre-fix the supervisor never recovered and never
-    rebaselined."""
-    class _ScriptedDog:
-        """Scripted verdicts + a rebaseline call counter."""
-        def __init__(self, verdicts):
-            self.verdicts = list(verdicts)
-            self.rebaselines = 0
-
-        def record(self, step, t):
-            return self.verdicts.pop(0)
-
-        def rebaseline(self):
-            self.rebaselines += 1
-
-    scfg, trace, faults = _stream_inputs(2, "none")
-    s = harness.build_system(detectors, "episode", scfg)
-    s._key = jax.random.PRNGKey(1234)
-    sup = sched_mod.EpisodeSupervisor(
-        s, sched_mod.SupervisorConfig(recover_after=2))
+    wall times."""
+    T, WIN = 16, 4
+    scfg, trace, faults = _stream_inputs(T, "none")
+    runner = _runner(detectors, scfg, "static",
+                     StreamConfig(window_slots=WIN, recover_after=2))
     dog = _ScriptedDog(["replace", "ok", "ok", "ok"])
-    sup.watchdog = dog
-    scene = DeviceScene(scfg)
-    for _ in range(4):
-        sup.run(scene, trace, method="static", faults=faults)
+    runner.watchdog = dog
+    runner.offer(trace, faults=faults)
+    assert runner.serve() == T // WIN
 
-    kinds = [(e["kind"], e.get("to")) for e in sup.events
+    kinds = [(e["kind"], e.get("to")) for e in runner.events
              if e["kind"] in ("degrade", "recover")]
-    assert kinds == [("degrade", "episode_chunked"), ("recover", "episode")]
-    assert sup.mode == "episode"             # climbed back to the fast rung
+    assert kinds == [("degrade", "episode_small"), ("recover", "episode")]
+    assert runner.rung == 0             # climbed back to the fast rung
     # one rebaseline per rung change: the watchdog degrade + the recovery
     assert dog.rebaselines == 2
-    # run 4 happened back at the fast rung with a FRESH streak
-    assert sup._ok_streak == 0 or sup._rung == 0
+    # the last window ran back at the fast rung with a FRESH streak
+    assert runner.ok_streak == 0
+
+
+def test_restore_clamps_a_retired_rung(detectors, tmp_path):
+    """A generation that names a rung the ladder no longer has (a third
+    rung, index 2, in checkpoints of older builds) resumes at the bottom
+    rung, ``episode_small``, and serves on exactly."""
+    T, WIN = 16, 8
+    scfg, trace, faults = _stream_inputs(T, "camera_flap")
+    ref = _continuous_reference(detectors, scfg, trace, faults, "reducto")
+    cfg = StreamConfig(window_slots=WIN, ckpt_dir=str(tmp_path),
+                       ckpt_every=100)
+    rA = _runner(detectors, scfg, "reducto", cfg)
+    rA.offer(trace[:WIN], faults=faults[:WIN])
+    assert rA.serve() == 1
+    rA.rung = 2                  # what an older build checkpointed
+    rA._checkpoint(rA.window)
+    rA.close()
+
+    rB = _runner(detectors, scfg, "reducto", cfg)
+    assert rB.restore()
+    assert rB.rung == 1 and rB.stats()["rung"] == "episode_small"
+    rB.offer(trace[WIN:], faults=faults[WIN:])
+    assert rB.serve() == 1
+    harness.assert_logs_match(ref, _logs(rB), keys=STREAM_KEYS,
+                              ctx="restored retired rung")
 
 
 def test_recovered_watchdog_baseline_not_seeded_from_degraded_rung():

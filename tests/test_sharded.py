@@ -99,7 +99,7 @@ def build(episode, shard):
 
 for method in ("deepstream", "reducto"):
     logs = {}
-    for name, (episode, shard) in (("pipe", (False, "off")),
+    for name, (episode, shard) in (("host", (False, "off")),
                                    ("ep", (True, "auto"))):
         s = build(episode, shard)
         assert (s.mesh is not None) == (shard == "auto")
@@ -113,8 +113,8 @@ for method in ("deepstream", "reducto"):
             assert n1["keep"] == n0["keep"], method
             assert n1["control"] == n0["control"], method
     for k in ("utility", "bytes", "alloc_kbps"):
-        scale = max(1.0, float(np.max(np.abs(logs["pipe"][k]))))
-        d = float(np.max(np.abs(logs["pipe"][k] - logs["ep"][k])))
+        scale = max(1.0, float(np.max(np.abs(logs["host"][k]))))
+        d = float(np.max(np.abs(logs["host"][k] - logs["ep"][k])))
         assert d <= 1e-5 * scale, (method, k, d)
         print(f"OK {method} {k} max|diff|={d:.3e}")
 print("EPISODE-SHARDED-PASS")
@@ -123,6 +123,6 @@ print("EPISODE-SHARDED-PASS")
 
 def test_episode_sharded_matches_pipelined(detectors):
     """The 4-device shard_map episode (C=5 padded to 8) reproduces the
-    single-device pipelined logs for the deepstream and reducto routes,
-    with zero per-slot keep/control fetches."""
+    single-device host-control batched logs for the deepstream and reducto
+    routes, with zero per-slot keep/control fetches."""
     _run_subprocess(_EPISODE_SCRIPT, "EPISODE-SHARDED-PASS")
